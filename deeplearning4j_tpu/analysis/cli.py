@@ -126,10 +126,12 @@ def _build_parser():
                    help="populate the AOT executable cache "
                         "(runtime.aot, docs/COMPILE.md) for SUBJECT "
                         "(lenet, resnet_block, or 'all') and print "
-                        "per-key compile seconds; persists to "
-                        "--cache-dir (or $DL4J_TPU_AOT_CACHE) so later "
-                        "processes — trainers, serving, --attribution "
-                        "reruns — warm-start")
+                        "per-key compile seconds; the executables "
+                        "persist in JAX's compilation cache "
+                        "($JAX_COMPILATION_CACHE_DIR, else "
+                        "<checkout>/.jax_cache) so later processes — "
+                        "trainers, serving, --attribution reruns — "
+                        "warm-start")
     p.add_argument("--autotune", nargs="?", const="all",
                    metavar="SUBJECT",
                    help="run the autotune arbiter (runtime/autotune.py, "
@@ -146,11 +148,9 @@ def _build_parser():
                    help="with --autotune: re-sweep even when a "
                         "persisted record exists")
     p.add_argument("--cache-dir", default=None, metavar="DIR",
-                   help="executable-cache directory for --precompile/"
-                        "--attribution/--autotune (default: "
-                        "$DL4J_TPU_AOT_CACHE, else memory-only; "
-                        "--autotune stores its .tune.json records in "
-                        "the same directory)")
+                   help="directory for --autotune's .tune.json records "
+                        "(default: $DL4J_TPU_AUTOTUNE_CACHE, else "
+                        "memory-only)")
     return p
 
 
@@ -294,16 +294,17 @@ def main(argv=None):
         return 2
 
     aot_cache = None
-    if args.cache_dir or args.precompile or args.attribution \
-            or args.autotune:
-        # an explicit dir (or the env var) turns on the persistent tier
-        # for every compile this command pays; the handle is kept so
-        # the --precompile report works even when the session cache is
-        # vetoed (DL4J_TPU_AOT=off / multihost make session_cache()
-        # return None — an explicitly-passed cache still functions)
-        from deeplearning4j_tpu.runtime import aot
+    jax_cache_dir = None
+    if args.precompile or args.attribution or args.autotune:
+        # every compile this command pays lands in JAX's persistent
+        # cache; the in-process handle is kept so the --precompile
+        # report works even when the session cache is vetoed
+        # (DL4J_TPU_AOT=off / multihost make session_cache() return
+        # None — an explicitly-passed cache still functions)
+        from deeplearning4j_tpu.runtime import aot, compile_cache
 
-        aot_cache = aot.enable(args.cache_dir)
+        jax_cache_dir = compile_cache.configure()
+        aot_cache = aot.enable()
 
     if args.concurrency:
         import os as _os
@@ -433,7 +434,7 @@ def main(argv=None):
         cache = aot_cache
         if args.as_json:
             print(_json.dumps({"subjects": records,
-                               "cache_dir": cache.directory,
+                               "cache_dir": jax_cache_dir,
                                "stats": cache.stats}, indent=2))
         else:
             for s, rep in records.items():
@@ -443,10 +444,8 @@ def main(argv=None):
                           f"{r['seconds']:>8.3f} s  {r['key'][:16]}")
             total = sum(r["seconds"] for rep in records.values()
                         for r in rep.values())
-            where = cache.directory or "memory only (set --cache-dir or "\
-                                       "$DL4J_TPU_AOT_CACHE to persist)"
             print(f"\n{sum(len(r) for r in records.values())} key(s), "
-                  f"{total:.1f} s total; cache: {where}")
+                  f"{total:.1f} s total; cache: {jax_cache_dir}")
         return 0
 
     if args.attribution:

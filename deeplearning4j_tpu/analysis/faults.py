@@ -536,7 +536,7 @@ def _lint_tree(tree, findings):
                     "fault_point(): this read/write failure path can "
                     "never be exercised by a ChaosPlan",
                     hint="wire a fault_point(<seam>) around the I/O "
-                         "(aot.disk_read-style), or suppress with "
+                         "(checkpoint.write-style), or suppress with "
                          "the reason the persistence is best-effort "
                          "and failure-tolerant by design"))
 

@@ -122,8 +122,7 @@ def compile_train_step(net, x_shape, n_classes=10, cache=None,
     executable cache when one is active (runtime.aot) — a second
     ``--attribution`` run (or the bytes-gate tests after the CLI) gets
     the executable warm instead of re-paying the subject's XLA compile.
-    The lowering here carries no donation, so the cached artifact is
-    the serialization-safe form. Pass `lowered` when the caller already
+    Pass `lowered` when the caller already
     lowered (e.g. for the pre-opt dtype audit) — this is the ONE
     definition of the subject key/entry, so every compile of a subject
     lands on the same cache slot."""

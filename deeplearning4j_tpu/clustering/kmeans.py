@@ -162,7 +162,7 @@ def _lloyd_sharded(Xc, first_idx, k, maxIter, mesh):
     candidates all-gathered, then re-topped)."""
     from deeplearning4j_tpu.linalg import DistributedMatrix, ROW_AXIS
     from deeplearning4j_tpu.linalg.distributed import _entry
-    from deeplearning4j_tpu.parallel._compat import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     dX = DistributedMatrix(np.asarray(Xc, np.float32), mesh,

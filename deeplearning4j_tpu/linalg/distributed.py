@@ -41,7 +41,7 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from deeplearning4j_tpu.parallel._compat import shard_map
+from jax import shard_map
 from deeplearning4j_tpu.parallel.mesh import DATA_AXIS, MODEL_AXIS
 
 #: canonical linalg placement axes — rows of a data matrix shard over

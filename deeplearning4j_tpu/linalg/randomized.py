@@ -27,7 +27,7 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
-from deeplearning4j_tpu.parallel._compat import shard_map
+from jax import shard_map
 from deeplearning4j_tpu.linalg.distributed import (
     DistributedMatrix, _entry, _gather_cols,
 )

@@ -31,7 +31,7 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from deeplearning4j_tpu.parallel._compat import shard_map
+from jax import shard_map
 from deeplearning4j_tpu.linalg.distributed import (
     DistributedMatrix, ROW_AXIS, _check_divisible, _entry, _gather_cols,
 )

@@ -288,8 +288,7 @@ class NeuralNetConfiguration:
               backward residuals; recompute the elementwise tails
               (BN/activation/add) from them during the backward pass.
               On bandwidth-bound steps this trades cheap recompute FLOPs
-              for the write+read of every elementwise intermediate —
-              the remaining HBM lever named in BENCH_NOTES.md round 4.
+              for the write+read of every elementwise intermediate.
             - None: store whatever autodiff needs (default).
 
             Differs from activationCheckpointing (per-layer remat, a

@@ -476,7 +476,7 @@ class ComputationGraph:
         intermediates are recomputed during the backward. On
         bandwidth-bound steps that removes the write+read of every
         elementwise intermediate at the cost of re-reading the saved
-        conv outputs — the BENCH_NOTES.md round-4 HBM lever."""
+        conv outputs."""
         def base(p, s, i, l, k, fm, lm):
             return self._loss_fn(p, s, i, l, k, fm, lm, use_carries,
                                  canonical)

@@ -24,10 +24,10 @@ _ARGMAX_BWD_MAX_WINDOW = 36
 
 # Backward implementation switch. The argmax rewrite was built for TPU,
 # where XLA's select-and-scatter materializes a single 206 MB op in the
-# ResNet stem (BENCH_NOTES.md) — but the live-TPU A/B landed the OTHER
-# way: on TPU v5e the stock gradient measures ~1.9x faster than the
-# argmax form (8.99 vs 15.60 ms fwd+bwd at the stem-pool shape,
-# BENCH_LIVE_r04.json), and on CPU it is ~5x faster (XLA-CPU rewrites
+# ResNet stem — but a pre-PR-1 builder capture on a TPU v5e had the
+# stock gradient ~1.9x faster than the argmax form at the stem-pool
+# shape (not re-measured on the current code; ROADMAP D3 owns the
+# verdict), and on CPU it is ~5x faster (XLA-CPU rewrites
 # select-and-scatter into a vectorized scatter). Stock is therefore the
 # default on every backend; the argmax path stays available
 # (DL4J_TPU_MAXPOOL_BWD=argmax) and gradient-parity-pinned for backends

@@ -200,9 +200,9 @@ def resnet50_scaling(step_time_s: float = 0.0546,
                      compression: float = 1.0) -> dict:
     """The SURVEY §6 proof obligation: flagship ResNet-50 DP scaling.
 
-    Defaults are the round-3 measured step time (BENCH_NOTES.md, batch
-    128 bf16 on the real v5e-class chip) and the bf16 gradient size the
-    trainer all-reduces.
+    Defaults are a pre-PR-1 builder capture of the step time (batch
+    128 bf16 on a v5e; not re-measured on the current code) and the
+    bf16 gradient size the trainer all-reduces.
     """
     m = DataParallelModel(step_time_s=step_time_s,
                           grad_bytes=param_count * grad_dtype_bytes,

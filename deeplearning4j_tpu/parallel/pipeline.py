@@ -287,7 +287,7 @@ class PipelineParallel:
 
         def step(pro, body, epi, pro_u, body_u, epi_u, it, x_mb, y_mb, key):
             def loss_fn(pro, body, epi):
-                from deeplearning4j_tpu.parallel._compat import shard_map
+                from jax import shard_map
 
                 return shard_map(
                     self._pipe_loss, mesh=mesh,

@@ -23,11 +23,8 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from deeplearning4j_tpu.parallel._compat import (
-    axis_size as _compat_axis_size, shard_map,
-)
 
 from deeplearning4j_tpu.ops.attention import _block_attn
 from deeplearning4j_tpu.parallel.mesh import SEQ_AXIS
@@ -35,7 +32,7 @@ from deeplearning4j_tpu.parallel.mesh import SEQ_AXIS
 
 def _ring_attention_local(q, k, v, axis_name, causal, chunk_index_fn=None):
     """Per-shard body: q,k,v are the local [B,H,Tl,D] slices."""
-    n = _compat_axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     my = lax.axis_index(axis_name)
     B, H, Tl, D = q.shape
 

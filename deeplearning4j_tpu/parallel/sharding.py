@@ -829,7 +829,7 @@ def dp_weight_update_bytes(grad_bytes, dp, master_bytes=None,
     (fp32) grads. Returns the terms plus `sharding_saves_bytes` — the
     per-replica HBM cut the sharded update offers; compare it against
     the attribution's measured weight_update collective rows before
-    spending a live window on the rewrite.
+    spending chip time on the rewrite.
 
     sharded=True returns the ZeRO bill of the IMPLEMENTED scheme
     (ZeroShardedUpdate) — the analytic yardstick its measured

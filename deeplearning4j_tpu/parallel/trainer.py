@@ -609,7 +609,7 @@ class ParallelWrapper:
         INSIDE the weight-update hook (ManualZeroUpdate): a QUANTIZED
         reduce-scatter feeds the local 1/dp shard update and the fresh
         shards are all-gathered — compression and ZeRO stack."""
-        from deeplearning4j_tpu.parallel._compat import shard_map
+        from jax import shard_map
         from deeplearning4j_tpu.parallel.sharding import \
             quantized_psum_mean
 
@@ -670,7 +670,7 @@ class ParallelWrapper:
         its (index, +-tau) pairs and scatter-adds the dp messages into
         the dense mean — bytes-on-wire scale with the capacity, not the
         model (parallel.sharding.compressed_wire_bytes bills it)."""
-        from deeplearning4j_tpu.parallel._compat import shard_map
+        from jax import shard_map
         from deeplearning4j_tpu.ndarray.compression import (
             threshold_cap, threshold_encode_fixed,
         )
@@ -777,7 +777,7 @@ class ParallelWrapper:
         and ResilientFit resume. Wire bytes scale with
         capacity x n_groups (not capacity x dp) — bills in
         parallel.sharding.compressed_wire_bytes."""
-        from deeplearning4j_tpu.parallel._compat import shard_map
+        from jax import shard_map
         from deeplearning4j_tpu.parallel.sharding import \
             hierarchical_grad_exchange
 
@@ -1251,7 +1251,7 @@ class ParameterAveragingTrainingMaster(ParallelWrapper):
                          stack(n._states))
 
     def _build_jit(self):
-        from deeplearning4j_tpu.parallel._compat import shard_map
+        from jax import shard_map
 
         n, mesh, ax = self.net, self.mesh, self.batch_axis
 

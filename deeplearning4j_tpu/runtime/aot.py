@@ -1,45 +1,35 @@
-"""AOT compilation + persistent executable cache.
+"""AOT compilation + in-process executable cache.
 
-The suite and production cold-start are COMPILE-dominated: every
-process (and every fresh network instance) pays XLA seconds-to-minutes
-re-compiling programs that are byte-identical to ones already compiled.
-jax's own answer (``jax_compilation_cache_dir``) segfaults on this
-jaxlib 0.4.36 deserializing donated-buffer executables (see
-tests/conftest.py), so this module is our own layer, in the spirit of
+Every train/inference program is ONE XLA executable; this module keeps
+those executables addressable inside a process, in the spirit of
 whole-program compilation (arXiv:1810.09868 — compile the WHOLE step
-once, then reuse the executable):
+once, then reuse the executable). What survives the process is JAX's
+own persistent compilation cache, placed by
+``runtime/compile_cache.configure()``: it round-trips DONATED
+executables on jaxlib 0.9.0 (CPU tier-1 and the TPU v5e smoke both
+exercise it), so this layer keeps nothing on disk and cached
+executables are the donated ones.
 
-* ``ExecutableCache`` — a two-level (in-memory + on-disk) store of
-  compiled XLA executables keyed by a content hash of everything that
-  shapes the traced program: the network configuration JSON, the entry
-  point, the abstract call signature (shapes/dtypes/shardings), the
-  dtype-policy toggles, the weight-update/sharding mode, and the
-  jax/jaxlib/package versions (a version bump invalidates stale
-  artifacts; a corrupted or stale file falls back to a fresh compile).
-
-* the donation-segfault workaround — cached executables are compiled
-  with donation STRIPPED (``donate_argnums=()``), which is the form
-  jaxlib 0.4.36 round-trips safely, and donation is re-applied at call
-  time by the wrapper: after the executable returns, the buffers at the
-  donated positions are explicitly deleted (guarded against
-  input-to-output aliasing), so the caller-visible contract — donated
-  inputs are invalid after the call, memory is released promptly — is
-  preserved. Stripping donation cannot change math (aliasing is a
-  buffer-assignment concern), which is why a warm-started fit is
-  bitwise-identical to a cold one.
+* ``ExecutableCache`` — an in-memory store of compiled XLA executables
+  keyed by a content hash of everything that shapes the traced
+  program: the network configuration JSON, the entry point, the
+  abstract call signature (shapes/dtypes/shardings), the dtype-policy
+  toggles, the weight-update/sharding mode, and the jax/jaxlib/package
+  versions. Two networks with equal configs share ONE executable.
 
 * ``cached_jit`` — a drop-in ``jax.jit`` replacement the network
   classes build their train/forward/loss steps with. With no cache
-  enabled it IS the plain donated jit (zero behavior change); with a
-  session cache enabled every first call per signature goes
-  key-lookup → deserialize-or-compile, so two networks with equal
-  configs share ONE executable instead of compiling twice.
+  enabled it IS the plain donated jit; with a session cache enabled
+  every first call per signature goes key-lookup → hit-or-compile
+  (``jit.lower().compile()``, donation included) and later calls
+  dispatch straight to the compiled executable.
 
 * ``precompile`` warm-start — ``network.precompile(...)`` (all three
   network types), ``ParallelWrapper.precompile(...)`` and
   ``ParallelInference.precompile(...)`` drive ``CachedJit.warm`` with
   example abstract arguments so serving processes and trainers hit the
-  first real batch with a hot executable.
+  first real batch with a hot executable; ``CompileWatch`` proves a
+  window compiled nothing.
 
 * shape-bucket canonicalization — ``bucket_batch`` rounds request
   batch sizes up to a small fixed set of buckets so a serving tier
@@ -48,24 +38,18 @@ once, then reuse the executable):
   (``sentinel_budget``).
 
 Scope: single-process jax only (``jax.process_count() > 1`` disables
-the cache — multihost executables embed device assignments that do not
-round-trip across launches).
+the cache).
 """
 
 from __future__ import annotations
 
 import hashlib
 import os
-import pickle
-import tempfile
 import threading
 import time
 
 import jax
 import numpy as np
-
-from deeplearning4j_tpu.runtime.chaos import \
-    fault_point as _chaos_fault_point
 
 __all__ = [
     "ExecutableCache", "CachedJit", "cached_jit", "compile_lowered",
@@ -75,15 +59,7 @@ __all__ = [
     "DEFAULT_BATCH_BUCKETS", "CompileWatch",
 ]
 
-#: bump when the on-disk artifact layout changes — old files become
-#: stale (fresh compile + overwrite), never a crash
-CACHE_FORMAT = 1
-
-#: env var naming a directory for the persistent tier; unset = the
-#: session cache (when enabled) is memory-only
-CACHE_DIR_ENV = "DL4J_TPU_AOT_CACHE"
-
-#: kill switch: DL4J_TPU_AOT=off ignores enable()/env-dir entirely
+#: kill switch: DL4J_TPU_AOT=off ignores enable() entirely
 AOT_ENV = "DL4J_TPU_AOT"
 
 
@@ -97,9 +73,9 @@ _TM = None
 
 
 def _tm():
-    """Lazily-resolved AOT telemetry handles (runtime.telemetry): the
-    compile-vs-load split as registry instruments + trace spans, on top
-    of the per-cache ``stats``/``seconds`` dicts the CLI reports."""
+    """Lazily-resolved AOT telemetry handles (runtime.telemetry): hits,
+    misses and compile wall as registry instruments + trace spans, on
+    top of the per-cache ``stats``/``seconds`` dicts the CLI reports."""
     global _TM
     if _TM is None:
         from deeplearning4j_tpu.runtime import telemetry
@@ -111,19 +87,12 @@ def _tm():
                 "dl4j_aot_cache_hits_total",
                 "executable-cache hits by tier",
                 labels=("tier",)).labels(tier="memory"),
-            "hits_disk": reg.counter(
-                "dl4j_aot_cache_hits_total",
-                "executable-cache hits by tier",
-                labels=("tier",)).labels(tier="disk"),
             "misses": reg.counter(
                 "dl4j_aot_cache_misses_total",
                 "executable-cache misses (XLA compiles paid)"),
             "compile_s": reg.histogram(
                 "dl4j_aot_compile_seconds",
                 "XLA compile wall on a cache miss"),
-            "load_s": reg.histogram(
-                "dl4j_aot_load_seconds",
-                "disk-tier deserialize wall on a disk hit"),
         }
     return _TM
 
@@ -146,8 +115,8 @@ def _tm_compile(t0, key=None, entry=None):
 
 def ambient_fingerprint():
     """Process-level facts that change the compiled program without
-    appearing in any argument: versions (stale-cache invalidation),
-    backend, device count, x64 mode, and the module-global A/B toggles
+    appearing in any argument: versions, backend, device count, x64
+    mode, and the module-global A/B toggles
     (loss/BN tail modes, pooling backward impl, attention windows) the
     bench flips — a cache hit across two of THESE states would replay
     the wrong program."""
@@ -158,7 +127,6 @@ def ambient_fingerprint():
     from deeplearning4j_tpu.ops import pooling as _pooling
 
     return {
-        "format": CACHE_FORMAT,
         "package": _package_version(),
         "jax": jax.__version__,
         "jaxlib": __import__("jaxlib").__version__,
@@ -276,10 +244,9 @@ def abstract_signature(args):
 
 
 def _sig_repr(sig):
-    """Stable string form of a signature for the sha256 disk key —
+    """Stable string form of a signature for the sha256 cache key —
     computed once per first-seen signature, never on the dispatch hot
-    path. Aval/sharding objects repr deterministically across
-    processes (device ids, mesh axes, dtype names)."""
+    path."""
     if isinstance(sig, str):
         return sig
     treedef, leaf_sigs = sig
@@ -290,7 +257,7 @@ def _sig_repr(sig):
 
 
 def cache_key(base_fp, entry, sig, ambient=None):
-    """The on-disk cache key: sha256 over (ambient fingerprint, program
+    """The cache key: sha256 over (ambient fingerprint, program
     fingerprint, entry-point name, abstract signature)."""
     amb = ambient if ambient is not None else ambient_fingerprint()
     return _sha("|".join([repr(sorted(amb.items())), base_fp, entry,
@@ -302,44 +269,20 @@ def cache_key(base_fp, entry, sig, ambient=None):
 # ----------------------------------------------------------------------
 
 class ExecutableCache:
-    """Two-level executable store.
+    """In-memory executable store: key -> jax.stages.Compiled, shared by
+    every network in the process (N identical configs, 1 compile)."""
 
-    Memory tier: key -> jax.stages.Compiled, shared by every network in
-    the process (the tier-1 win: N identical configs, 1 compile).
-    Disk tier (optional ``directory``): pickled
-    (meta, payload, in_tree, out_tree) per key, written atomically
-    (tmp + rename); ``meta`` embeds the ambient fingerprint so a
-    package/jax/jaxlib version bump or toggle flip makes the artifact
-    stale (removed + recompiled) instead of silently wrong. A file that
-    fails to unpickle or deserialize is removed and treated as a miss —
-    a corrupted cache can cost a compile, never correctness.
-    """
-
-    #: per-artifact disk ceiling: a single serialized executable larger
-    #: than this stays memory-only (keeps a shared cache dir bounded;
-    #: the XLA:CPU artifacts measured so far are ~0.05-1 MB)
-    max_artifact_bytes = 64 * 1024 * 1024
-
-    def __init__(self, directory=None):
-        self.directory = os.path.expanduser(str(directory)) \
-            if directory else None
-        if self.directory:
-            # artifacts are pickles: loading one executes whatever it
-            # encodes, so the directory must be writable ONLY by the
-            # trusting user — created 0700, files land 0600 (mkstemp)
-            os.makedirs(self.directory, mode=0o700, exist_ok=True)
+    def __init__(self):
         # serving threads drive get/put concurrently (every BATCHED
         # dispatch and every handler-thread first request lands here);
-        # the stats counters are read-modify-write and the memory tier
-        # is check-then-insert, so both live under one lock (the THR01
+        # the stats counters are read-modify-write and the store is
+        # check-then-insert, so both live under one lock (the THR01
         # audit, ISSUE 14). Reentrant: note_miss can fire under get.
         self._lock = threading.RLock()
         self._mem = {}
-        self.stats = {"mem_hits": 0, "disk_hits": 0, "misses": 0,
-                      "puts": 0, "stale": 0, "corrupt": 0,
-                      "oversize": 0, "store_errors": 0}
-        #: key -> seconds of the compile (miss) or load (disk hit);
-        #: the CLI --precompile report reads this
+        self.stats = {"mem_hits": 0, "misses": 0, "puts": 0}
+        #: key -> seconds of the compile; the CLI --precompile report
+        #: reads this
         self.seconds = {}
 
     def note_miss(self, key=None, seconds=None):
@@ -353,138 +296,20 @@ class ExecutableCache:
             if key is not None and seconds is not None:
                 self.seconds[key] = float(seconds)
 
-    # -- paths ----------------------------------------------------------
-    def _path(self, key):
-        return os.path.join(self.directory, key + ".aotx")
-
-    def __contains__(self, key):
-        with self._lock:
-            if key in self._mem:
-                return True
-        return self.directory is not None \
-            and os.path.exists(self._path(key))
-
-    # -- read -----------------------------------------------------------
-    def get(self, key, ambient=None):
-        """-> Compiled or None. Memory first; then disk (deserialize +
-        promote to memory). Stale/corrupted disk entries are removed.
-        The disk load itself runs unlocked — two threads racing the
-        same cold key can both deserialize (a benign duplicate load);
-        the memory tier and counters stay consistent either way."""
+    def get(self, key):
+        """-> Compiled or None."""
         with self._lock:
             hit = self._mem.get(key)
             if hit is not None:
                 self.stats["mem_hits"] += 1
         if hit is not None:
             _tm()["hits_mem"].inc()
-            return hit
-        if self.directory is None:
-            return None
-        path = self._path(key)
-        if not os.path.exists(path):
-            return None
-        t0 = time.perf_counter()
-        try:
-            # chaos seam INSIDE the corrupt-handling try: an injected
-            # raise or a corrupted path must be absorbed exactly like
-            # organic disk rot — a miss, never an error
-            # (runtime/chaos.py, seam aot.disk_read)
-            path = _chaos_fault_point("aot.disk_read", path)
-            with open(path, "rb") as fh:
-                meta, payload, in_tree, out_tree = pickle.load(fh)
-        except Exception:
-            with self._lock:
-                self.stats["corrupt"] += 1
-            self._remove(path)
-            return None
-        amb = ambient if ambient is not None else ambient_fingerprint()
-        if meta.get("ambient") != amb:
-            with self._lock:
-                self.stats["stale"] += 1
-            self._remove(path)
-            return None
-        try:
-            from jax.experimental import serialize_executable as _se
+        return hit
 
-            compiled = _se.deserialize_and_load(payload, in_tree, out_tree)
-        except Exception:
-            with self._lock:
-                self.stats["corrupt"] += 1
-            self._remove(path)
-            return None
-        dt = time.perf_counter() - t0
-        with self._lock:
-            self.seconds[key] = dt
-            self.stats["disk_hits"] += 1
-            self._mem[key] = compiled
-        tm = _tm()
-        tm["hits_disk"].inc()
-        tm["load_s"].observe(dt)
-        tm["reg"].trace.add("aot.deserialize", "compile", t0, dt,
-                            {"key": key[:16]})
-        return compiled
-
-    @staticmethod
-    def _remove(path):
-        try:
-            os.remove(path)
-        except OSError:
-            pass
-
-    # -- write ----------------------------------------------------------
-    def put(self, key, compiled, ambient=None, entry=None):
-        """Store in memory and (when a directory is configured)
-        serialize to disk atomically. Serialization failures are
-        swallowed — the memory tier still works and the next process
-        simply recompiles."""
+    def put(self, key, compiled):
         with self._lock:
             self._mem[key] = compiled
             self.stats["puts"] += 1
-        if self.directory is None:
-            return
-        try:
-            from jax.experimental import serialize_executable as _se
-
-            # chaos seam inside the swallow-everything try: an injected
-            # disk-write fault costs the artifact, never the process
-            # (runtime/chaos.py, seam aot.disk_write)
-            _chaos_fault_point("aot.disk_write")
-            payload, in_tree, out_tree = _se.serialize(compiled)
-            if len(payload) > self.max_artifact_bytes:
-                with self._lock:
-                    self.stats["oversize"] += 1
-                return
-            meta = {"ambient":
-                    ambient if ambient is not None else ambient_fingerprint(),
-                    "entry": entry}
-            fd, tmp = tempfile.mkstemp(dir=self.directory, suffix=".tmp")
-            try:
-                with os.fdopen(fd, "wb") as fh:
-                    pickle.dump((meta, payload, in_tree, out_tree), fh)
-                os.replace(tmp, self._path(key))
-            except BaseException:
-                self._remove(tmp)
-                raise
-        except Exception:
-            # disk store is best-effort (the memory tier already holds
-            # the executable), but a silently failing store looks like
-            # a working cache that never warms across processes — count
-            # it so operators can tell "cold by design" from "broken"
-            with self._lock:
-                self.stats["store_errors"] += 1
-
-    def clear_memory(self):
-        """Drop the in-process tier (tests simulate a second process by
-        clearing memory and re-reading disk)."""
-        with self._lock:
-            self._mem.clear()
-
-    def clear(self):
-        self.clear_memory()
-        if self.directory:
-            for name in os.listdir(self.directory):
-                if name.endswith(".aotx"):
-                    self._remove(os.path.join(self.directory, name))
 
 
 # ----------------------------------------------------------------------
@@ -492,114 +317,47 @@ class ExecutableCache:
 # ----------------------------------------------------------------------
 
 _SESSION = None
-_SESSION_INIT = False
 
 
-def enable(directory=None):
-    """Turn on the process-wide session cache. directory=None falls
-    back to $DL4J_TPU_AOT_CACHE (memory-only if unset); directory=False
-    forces memory-only even when the env var is set (the test suite
-    uses this — see tests/conftest.py on why the suite must never
-    deserialize). Idempotent — re-enabling with the same directory
+def enable():
+    """Turn on the process-wide session cache. Idempotent — re-enabling
     keeps the existing cache. Returns the ExecutableCache."""
-    global _SESSION, _SESSION_INIT
-    if directory is False:
-        directory = None
-    else:
-        directory = directory or os.environ.get(CACHE_DIR_ENV) or None
-    # compare in the same (expanduser'd) form ExecutableCache stores,
-    # or re-enabling with a '~' path would discard the live cache
-    norm = os.path.expanduser(str(directory)) if directory else None
-    if _SESSION is not None and _SESSION.directory == norm:
-        _SESSION_INIT = True
-        return _SESSION
-    _SESSION = ExecutableCache(directory)
-    _SESSION_INIT = True
+    global _SESSION
+    if _SESSION is None:
+        _SESSION = ExecutableCache()
     return _SESSION
 
 
 def disable():
     """Turn the session cache off (networks fall back to plain jit)."""
-    global _SESSION, _SESSION_INIT
+    global _SESSION
     _SESSION = None
-    _SESSION_INIT = True
 
 
 def session_cache():
-    """The active session cache or None. First call auto-enables a
-    disk-backed cache iff DL4J_TPU_AOT_CACHE is set (so a warm-started
-    process needs no code change); DL4J_TPU_AOT=off vetoes everything;
-    multihost always disables (device assignments in serialized
-    executables do not survive across launches)."""
-    global _SESSION_INIT
+    """The active session cache or None. DL4J_TPU_AOT=off vetoes
+    everything; multihost always disables (one process cannot speak
+    for the others' executables)."""
     if os.environ.get(AOT_ENV, "").lower() in ("off", "0", "false"):
         return None
-    if not _SESSION_INIT:
-        _SESSION_INIT = True
-        if os.environ.get(CACHE_DIR_ENV):
-            enable()
     if _SESSION is not None and jax.process_count() > 1:
         return None
     return _SESSION
 
 
-# ----------------------------------------------------------------------
-# donation emulation
-# ----------------------------------------------------------------------
-
-class _AotCall:
-    """A cached (donation-stripped) executable + call-time re-donation:
-    after the call, delete the array leaves at the donated argument
-    positions — the same "this buffer is dead now" contract the donated
-    jit gives callers, minus XLA's in-place aliasing (peak memory
-    during the step is higher; see docs/COMPILE.md). Leaves that alias
-    an output object are skipped, and deletion failures are ignored —
-    deletion is a memory hint, never a correctness step."""
-
-    __slots__ = ("compiled", "donate_argnums")
-
-    def __init__(self, compiled, donate_argnums=()):
-        self.compiled = compiled
-        self.donate_argnums = tuple(donate_argnums)
-
-    def __call__(self, *args):
-        out = self.compiled(*args)
-        if self.donate_argnums:
-            out_ids = {id(leaf) for leaf in jax.tree_util.tree_leaves(out)}
-            for i in self.donate_argnums:
-                if i >= len(args):
-                    continue
-                for leaf in jax.tree_util.tree_leaves(args[i]):
-                    if isinstance(leaf, jax.Array) \
-                            and id(leaf) not in out_ids:
-                        try:
-                            if not leaf.is_deleted():
-                                leaf.delete()
-                        except Exception:  # fault-ok[FLT01]: deletion is a memory hint, never a correctness step (class docstring) — there is nothing to classify when the runtime declines it
-                            pass
-        return out
-
-
-def compile_lowered(lowered, key=None, cache=None, entry=None,
-                    donate_argnums=()):
-    """Compile a jax.stages.Lowered through a cache: warm hit returns
-    the deserialized executable (wrapped for re-donation when
-    donate_argnums is given), miss pays lowered.compile() and stores
-    it. With no cache this is exactly ``lowered.compile()``. The
-    lowering itself must have donation STRIPPED — a donated lowering
-    would produce the artifact class jaxlib 0.4.36 cannot deserialize."""
+def compile_lowered(lowered, key=None, cache=None, entry=None):
+    """Compile a jax.stages.Lowered through a cache: a hit returns the
+    stored executable, a miss pays lowered.compile() and stores it.
+    With no cache this is exactly ``lowered.compile()``."""
     cache = cache if cache is not None else session_cache()
     if cache is None or key is None:
+        return lowered.compile()
+    compiled = cache.get(key)
+    if compiled is None:
+        t0 = time.perf_counter()
         compiled = lowered.compile()
-    else:
-        compiled = cache.get(key)
-        if compiled is None:
-            t0 = time.perf_counter()
-            compiled = lowered.compile()
-            cache.note_miss(key, _tm_compile(t0, key, entry))
-            cache.put(key, compiled, entry=entry)
-    if donate_argnums:
-        return _AotCall(compiled, donate_argnums)
+        cache.note_miss(key, _tm_compile(t0, key, entry))
+        cache.put(key, compiled)
     return compiled
 
 
@@ -621,9 +379,8 @@ class CachedJit:
         and all (exactly the pre-AOT behavior);
       * cache active -> signature lookup in the per-instance table; a
         first-seen signature computes the content key and goes through
-        the cache (deserialize or compile-without-donation + store),
-        then dispatches to the cached executable with call-time
-        re-donation.
+        the cache (hit, or lower+compile of the same donated jit +
+        store), then dispatches to the compiled executable.
 
     ``owner`` supplies the program fingerprint lazily (the conf JSON
     hash); ``extra`` folds caller context the fingerprint cannot see
@@ -640,10 +397,6 @@ class CachedJit:
         self._jit_kwargs = dict(jit_kwargs)
         self._fallback = jax.jit(fn, donate_argnums=self._donate,
                                  **jit_kwargs)
-        # donation-stripped twin: the ONLY jit the AOT path lowers
-        # through, so every cached artifact is the serialization-safe
-        # form (the conftest segfault workaround)
-        self._bare = jax.jit(fn, **jit_kwargs)
         self._table = {}
         self._fingerprint = fingerprint  # explicit > owner-derived
         self._fp_failed = False
@@ -746,10 +499,10 @@ class CachedJit:
             compiled = cache.get(key)
             if compiled is None:
                 t0 = time.perf_counter()
-                compiled = self._bare.lower(*args).compile()
+                compiled = self._fallback.lower(*args).compile()
                 cache.note_miss(key, _tm_compile(t0, key, self._entry))
-                cache.put(key, compiled, entry=self._entry)
-            ent = (_AotCall(compiled, self._donate), key)
+                cache.put(key, compiled)
+            ent = (compiled, key)
             with self._lock:
                 if self._table.get(sig) is marker:
                     self._table[sig] = ent

@@ -28,11 +28,6 @@ seam                      dispatch boundary
 ``server.request``        the HTTP GET/POST handlers (serving/
                           server.py; ordinals interleave in request
                           order)
-``aot.disk_read``         ExecutableCache disk-tier load (runtime/
-                          aot.py; payload is the artifact path — a
-                          corrupt rule makes the open fail, which the
-                          cache must absorb as a miss)
-``aot.disk_write``        ExecutableCache disk-tier store
 ``checkpoint.write``      ResilientFit._save (runtime/resilience.py,
                           inside the retry() lambda)
 ``checkpoint.restore``    ResilientFit._maybe_resume
@@ -76,8 +71,7 @@ __all__ = ["ChaosError", "ChaosPlan", "SEAMS", "arm", "armed_plan",
 #: is rejected (a typo'd seam would otherwise silently never fire)
 SEAMS = ("host.submit", "host.submit_sequence", "queue.dispatch",
          "sequence.step", "fleet.dispatch", "server.request",
-         "aot.disk_read", "aot.disk_write", "checkpoint.write",
-         "checkpoint.restore")
+         "checkpoint.write", "checkpoint.restore")
 
 #: seams registered at runtime beyond the built-in inventory
 _EXTRA_SEAMS = set()

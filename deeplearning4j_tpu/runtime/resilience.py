@@ -330,11 +330,9 @@ class ResilientFit:
                  maxConsecutiveBadSteps: int = 3,
                  retryPolicy: RetryPolicy = None,
                  injector: FaultInjector = None):
-        try:
-            from deeplearning4j_tpu.parallel.trainer import ParallelWrapper
-        except ImportError:  # parallel layer unavailable (jax too old)
-            ParallelWrapper = ()
-        if ParallelWrapper and isinstance(net, ParallelWrapper):
+        from deeplearning4j_tpu.parallel.trainer import ParallelWrapper
+
+        if isinstance(net, ParallelWrapper):
             self.wrapper, self.net = net, net.net
         else:
             self.wrapper, self.net = None, net

@@ -5,11 +5,15 @@ front, so HBM residency is paid for context nobody is using; the
 vLLM/PagedAttention shape bounds it by the tokens actually alive:
 the pool is ``num_pages`` fixed-size pages per layer, device-resident
 (``[L, P, page, H, Dh]`` for K and V), and each slot maps logical KV
-block j -> physical page through its **block table** row. The
-attention kernels (ops/pallas_attention.py ``paged_flash_decode`` /
-``paged_flash_prefill``) gather K/V through that table; ``page_size``
-doubles as the kernel block_k so paged attention is bitwise the dense
-flash kernel on the same tokens.
+block j -> physical page through its **block table** row. The serving
+step functions (nn/transformer.py) gather K/V through that table and
+attend with ``ops.pallas_attention.paged_attend`` on EVERY backend,
+the chip included. The pallas kernels beside it
+(``paged_flash_decode`` / ``paged_flash_prefill``, which gather per
+page in VMEM) compile and run on the TPU (chip_smoke.py checks them
+against ``paged_attend``) but no serving path calls them yet — ROADMAP
+S4. ``page_size`` doubles as the kernels' block_k so paged attention
+accumulates in the dense flash kernel's block order.
 
 ``PagedKVCache`` is the HOST-side manager plus the device pools:
 

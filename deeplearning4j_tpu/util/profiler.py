@@ -131,31 +131,32 @@ class OpProfiler:
 # read it from the compiled executable instead of re-deriving per-op)
 # ----------------------------------------------------------------------
 
-# bf16 peak TFLOP/s per chip by device kind substring (public TPU specs)
-_PEAK_BF16_FLOPS = (
-    ("v6", 918e12),        # Trillium / v6e
-    ("v5p", 459e12),
-    ("v5", 197e12),        # v5e / "TPU v5 lite"
-    ("v4", 275e12),
-    ("v3", 123e12),
-    ("v2", 45e12),
-)
+#: bf16 peak FLOP/s per chip, keyed by the EXACT ``device_kind`` JAX
+#: reports, each with its source. A kind that is not here is an error,
+#: not a default: a substring match would price a v5p at the v5e's peak.
+_PEAK_BF16_FLOPS = {
+    # Google Cloud documentation, "TPU v5e": 197 TFLOP/s bf16 per chip;
+    # the kind string is what chip_smoke.py prints on the v5e
+    "TPU v5 lite": 197e12,
+}
 
 
 def device_peak_flops(device=None) -> float:
     """Per-chip peak bf16 FLOP/s for the given (default: first) device.
-    Returns 0.0 when the device kind is unknown (CPU test meshes)."""
+    0.0 on the CPU platform (test meshes have no peak to speak of); an
+    accelerator whose device_kind is not in the table raises."""
     import jax
 
-    try:
-        d = device or jax.devices()[0]
-        kind = d.device_kind.lower()
-    except Exception:  # fault-ok[FLT01]: 0.0 IS the documented answer for "unknown device" (docstring) — the MFU probe degrades to "no peak known", which callers already handle
+    d = device or jax.devices()[0]
+    if d.platform == "cpu":
         return 0.0
-    for sub, peak in _PEAK_BF16_FLOPS:
-        if sub in kind:
-            return peak
-    return 0.0
+    try:
+        return _PEAK_BF16_FLOPS[d.device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no bf16 peak known for device_kind {d.device_kind!r} "
+            f"(platform {d.platform!r}): add it to "
+            "util/profiler.py::_PEAK_BF16_FLOPS with its source") from None
 
 
 def compiled_cost(fn, *args, **kwargs) -> dict:
@@ -165,17 +166,15 @@ def compiled_cost(fn, *args, **kwargs) -> dict:
     import jax
 
     jitted = fn if hasattr(fn, "lower") else jax.jit(fn)
-    ca = jitted.lower(*args, **kwargs).compile().cost_analysis()
-    if isinstance(ca, (list, tuple)):  # older jax returns [dict]
-        ca = ca[0] if ca else {}
-    ca = ca or {}
+    ca = jitted.lower(*args, **kwargs).compile().cost_analysis() or {}
     return {"flops": float(ca.get("flops", 0.0)),
             "bytes_accessed": float(ca.get("bytes accessed", 0.0))}
 
 
 def mfu(flops_per_step: float, step_time_s: float, device=None) -> float:
     """Model FLOP utilization: achieved FLOP/s over the chip's bf16 peak.
-    0.0 when peak is unknown."""
+    0.0 on the CPU platform; raises for an accelerator with no known
+    peak (device_peak_flops)."""
     peak = device_peak_flops(device)
     if not peak or step_time_s <= 0:
         return 0.0

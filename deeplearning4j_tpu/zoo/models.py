@@ -39,7 +39,7 @@ class ZooModel:
         # (C, H, W) triple either way; dataFormat="NHWC" means fit/output
         # receive [B,H,W,C] arrays and the entry transpose disappears —
         # the TPU-preferred host feed (NHWC bf16 binds straight to the
-        # internal conv layout; see BENCH_NOTES.md round-4 input-feed work).
+        # internal conv layout).
         self.dataFormat = str(dataFormat).upper()
 
     @staticmethod

@@ -1,28 +1,25 @@
-"""AOT compilation + persistent executable cache gates (runtime/aot.py,
-docs/COMPILE.md).
+"""AOT compilation + executable cache gates (runtime/aot.py,
+runtime/compile_cache.py, docs/COMPILE.md).
 
 What must hold:
 
 - cache keys: a config change or a dtype-policy change is a MISS (two
   different programs must never share an executable), an equal config
   at an equal signature is a HIT;
-- staleness: a package-version bump invalidates on-disk artifacts, a
-  corrupted file falls back to a fresh compile — a bad cache can cost
-  a compile, never correctness or a crash;
-- parity: a warm-started fit is BITWISE identical to a cold-started
-  one on all three network types (stripping donation from the cached
-  artifact is a buffer-assignment change, not a math change);
-- the donated-buffer segfault documented in tests/conftest.py (jaxlib
-  0.4.36 + jax_compilation_cache_dir) does not reproduce under this
-  cache: >1200 warm dispatches of a deserialized executable with
-  call-time re-donation run clean;
-- warm start: a SECOND process against a populated cache precompiles
-  and takes its first optimizer step on a zoo model in < 1 s on CPU;
+- parity: a fit through precompiled (cached) executables is BITWISE
+  identical to one through the plain donated jit on all three network
+  types;
+- donation: a cached executable is the donated one — its inputs are
+  dead after the step;
+- warm start: a SECOND process finds the first one's executables in
+  JAX's persistent compilation cache, in the directory
+  JAX_COMPILATION_CACHE_DIR names and nowhere else;
 - serving buckets: request batches canonicalise to a fixed bucket set,
   one executable per bucket (the RetraceSentinel budget).
 """
 
 import os
+import re
 import subprocess
 import sys
 import textwrap
@@ -101,11 +98,11 @@ def _batch():
 
 
 @pytest.fixture
-def fresh_cache(tmp_path):
-    """A disk-backed cache installed as THE session cache for the test
-    (the suite-wide memory cache from conftest is restored after)."""
+def fresh_cache():
+    """A fresh cache installed as THE session cache for the test (the
+    suite-wide one from conftest is restored after)."""
     prev = aot._SESSION
-    cache = aot.enable(str(tmp_path / "aotx"))
+    cache = aot._SESSION = aot.ExecutableCache()
     yield cache
     aot._SESSION = prev
 
@@ -205,52 +202,6 @@ class TestKeys:
 
 
 # ----------------------------------------------------------------------
-# staleness / corruption
-# ----------------------------------------------------------------------
-
-class TestInvalidation:
-    def test_version_bump_invalidates_disk(self, fresh_cache,
-                                           monkeypatch):
-        rep = _mln().precompile(batchSize=8)
-        key = rep["train_step"]["key"]
-        assert key in fresh_cache
-        fresh_cache.clear_memory()
-        monkeypatch.setattr(aot, "_package_version", lambda: "999.0")
-        # the key itself embeds the version, so a lookup under the OLD
-        # key must also reject the artifact by its stored meta
-        assert fresh_cache.get(key) is None
-        assert fresh_cache.stats["stale"] == 1
-        assert key not in fresh_cache  # removed from disk
-
-    def test_corrupted_file_falls_back_to_fresh_compile(self,
-                                                        fresh_cache):
-        rep = _mln().precompile(batchSize=8)
-        key = rep["train_step"]["key"]
-        path = fresh_cache._path(key)
-        with open(path, "wb") as fh:
-            fh.write(b"not a pickle")
-        fresh_cache.clear_memory()
-        assert fresh_cache.get(key) is None
-        assert fresh_cache.stats["corrupt"] == 1
-        # and the network recovers by compiling fresh
-        rep2 = _mln().precompile(batchSize=8)
-        assert rep2["train_step"]["status"] == "cold"
-        x, y = _batch()
-        _mln().fit(x, y)  # trains clean through the rebuilt entry
-
-    def test_truncated_payload_is_corrupt_not_crash(self, fresh_cache):
-        rep = _mln().precompile(batchSize=8)
-        key = rep["train_step"]["key"]
-        path = fresh_cache._path(key)
-        data = open(path, "rb").read()
-        with open(path, "wb") as fh:
-            fh.write(data[: len(data) // 2])
-        fresh_cache.clear_memory()
-        assert fresh_cache.get(key) is None
-        assert fresh_cache.stats["corrupt"] >= 1
-
-
-# ----------------------------------------------------------------------
 # parity: warm == cold, bitwise
 # ----------------------------------------------------------------------
 
@@ -263,27 +214,26 @@ def _fit_mln(net, steps=4):
 
 
 class TestWarmColdParity:
-    def test_multilayer_bitwise(self, tmp_path, no_cache):
+    def test_multilayer_bitwise(self, no_cache):
         cold = _fit_mln(_mln())
         prev = aot._SESSION
         try:
-            aot.enable(str(tmp_path / "c1"))
+            aot.enable()
             net = _mln()
             net.precompile(batchSize=8)
             warm_first = _fit_mln(net)
-            # second process simulation: memory dropped, disk only
-            aot.session_cache().clear_memory()
+            # an equal-config network shares the first one's executable
             net2 = _mln()
             rep = net2.precompile(batchSize=8)
             assert rep["train_step"]["status"] == "warm"
-            warm_disk = _fit_mln(net2)
+            warm_shared = _fit_mln(net2)
         finally:
             aot._SESSION = prev
-        for c, w1, w2 in zip(cold, warm_first, warm_disk):
+        for c, w1, w2 in zip(cold, warm_first, warm_shared):
             np.testing.assert_array_equal(c, w1)
             np.testing.assert_array_equal(c, w2)
 
-    def test_multilayer_fit_dataset_bitwise(self, tmp_path, no_cache):
+    def test_multilayer_fit_dataset_bitwise(self, no_cache):
         from deeplearning4j_tpu.data import DataSetIterator
 
         rng = np.random.RandomState(2)
@@ -301,14 +251,14 @@ class TestWarmColdParity:
         cold = run(False)
         prev = aot._SESSION
         try:
-            aot.enable(str(tmp_path / "c2"))
+            aot.enable()
             warm = run(True)
         finally:
             aot._SESSION = prev
         for c, w in zip(cold, warm):
             np.testing.assert_array_equal(c, w)
 
-    def test_graph_bitwise(self, tmp_path, no_cache):
+    def test_graph_bitwise(self, no_cache):
         x, y = _batch()
 
         def run():
@@ -321,16 +271,15 @@ class TestWarmColdParity:
         cold = run()
         prev = aot._SESSION
         try:
-            aot.enable(str(tmp_path / "c3"))
+            aot.enable()
             _graph().precompile(batchSize=8)   # populate
-            aot.session_cache().clear_memory()  # force disk warm path
             warm = run()
         finally:
             aot._SESSION = prev
         for c, w in zip(cold, warm):
             np.testing.assert_array_equal(c, w)
 
-    def test_samediff_bitwise(self, tmp_path, no_cache):
+    def test_samediff_bitwise(self, no_cache):
         rng = np.random.RandomState(1)
         X = rng.rand(8, 5)
         Y = X @ np.ones((5, 1))
@@ -345,41 +294,24 @@ class TestWarmColdParity:
         cold = run(False)
         prev = aot._SESSION
         try:
-            aot.enable(str(tmp_path / "c4"))
+            aot.enable()
             warm = run(True)
-            aot.session_cache().clear_memory()
-            warm_disk = run(True)
+            warm_shared = run(True)
         finally:
             aot._SESSION = prev
         np.testing.assert_array_equal(cold, warm)
-        np.testing.assert_array_equal(cold, warm_disk)
+        np.testing.assert_array_equal(cold, warm_shared)
 
 
 # ----------------------------------------------------------------------
-# the donated-buffer repro (conftest note) under the new cache
+# cached executables are the donated ones
 # ----------------------------------------------------------------------
 
-class TestDonationWorkaround:
-    def test_1200_warm_dispatches_no_segfault(self, fresh_cache):
-        """The documented jaxlib failure mode: warm-cache runs die
-        deserializing donated-buffer executables after ~1200 hits.
-        Under this cache the artifact carries no donation (re-donation
-        happens at call time), so >1200 warm dispatches of a
-        DESERIALIZED executable must run clean."""
-        net = _mln()
-        net.precompile(batchSize=8)
-        fresh_cache.clear_memory()        # force the deserialized path
-        net2 = _mln()
-        rep = net2.precompile(batchSize=8)
-        assert rep["train_step"]["status"] == "warm"
-        x, y = _batch()
-        for _ in range(1250):
-            net2.fit(x, y)
-        assert np.isfinite(net2.score())
-
-    def test_call_time_redonation_invalidates_inputs(self, fresh_cache):
+class TestDonation:
+    def test_cached_step_donates_its_inputs(self, fresh_cache):
         """The donated-jit contract callers rely on — input buffers are
-        dead after the step — is preserved by the call-time deletion."""
+        dead after the step — holds for the cached executable too: it
+        is compiled from the same donated jit."""
         net = _mln()
         net.precompile(batchSize=8)
         old_leaf = net._params[0]["W"]
@@ -407,55 +339,58 @@ class TestDonationWorkaround:
 # ----------------------------------------------------------------------
 
 _CHILD = textwrap.dedent("""
-    import os, sys, time
-    os.environ["JAX_PLATFORMS"] = "cpu"
     import jax
-    jax.config.update("jax_platforms", "cpu")
     jax.config.update("jax_enable_x64", True)
     import numpy as np
+    from deeplearning4j_tpu.runtime import compile_cache
     from deeplearning4j_tpu.zoo import LeNet
-    from deeplearning4j_tpu.runtime import aot
 
-    jax.numpy.zeros((1,)).block_until_ready()   # backend init, not ours
+    print("DIR", compile_cache.configure())
     net = LeNet(numClasses=10, inputShape=(1, 28, 28)).init()
     x = np.zeros((8, 1, 28, 28), np.float32)
     y = np.eye(10, dtype=np.float32)[np.zeros(8, int)]
-    t0 = time.perf_counter()
-    rep = net.precompile(batchSize=8)
-    net.fit(x, y)
-    wall = time.perf_counter() - t0
-    statuses = {k: v["status"] for k, v in rep.items()}
-    print("WALL", wall)
-    print("STATUSES", statuses)
-    sys.exit(0 if (wall < 1.0 and
-                   statuses.get("train_step") == "warm") else 3)
+    with compile_cache.PersistentCacheWatch() as w:
+        rep = net.precompile(batchSize=8, entries=("train",))
+        old = net._params[0]["W"]
+        net.fit(x, y)
+    assert rep["train_step"]["status"] == "cold", rep
+    assert old.is_deleted()          # the donated step, round-tripped
+    assert np.isfinite(net.score())
+    print("HITS", w.hits, "MISSES", w.misses)
 """)
 
 
 class TestSecondProcessWarmStart:
-    def test_zoo_model_warm_start_under_1s(self, tmp_path):
-        """Populate the persistent cache for a zoo model, then a FRESH
-        interpreter precompiles + takes its first optimizer step in
-        < 1 s on CPU (vs multi-second XLA compiles cold)."""
-        cache_dir = str(tmp_path / "zoo_cache")
-        prev = aot._SESSION
-        try:
-            aot.enable(cache_dir)
-            from deeplearning4j_tpu.zoo import LeNet
-
-            net = LeNet(numClasses=10, inputShape=(1, 28, 28)).init()
-            rep = net.precompile(batchSize=8)
-            assert rep["train_step"]["status"] == "cold"
-        finally:
-            aot._SESSION = prev
+    def test_second_process_hits_the_persistent_cache(self, tmp_path):
+        """Two fresh interpreters, one JAX_COMPILATION_CACHE_DIR: the
+        first compiles a zoo model's donated train step and stores it,
+        the second loads it (zero XLA compiles) and steps with it. The
+        entries land in the directory the variable names and
+        compile_cache.configure() chooses no other."""
+        cache_dir = tmp_path / "jaxcc"
         env = dict(os.environ)
-        env["DL4J_TPU_AOT_CACHE"] = cache_dir
+        env["JAX_COMPILATION_CACHE_DIR"] = str(cache_dir)
         env["JAX_PLATFORMS"] = "cpu"
-        out = subprocess.run([sys.executable, "-c", _CHILD], env=env,
-                             capture_output=True, text=True, timeout=300)
-        assert out.returncode == 0, (
-            f"warm second-process start failed:\n{out.stdout}\n"
-            f"{out.stderr[-2000:]}")
+        env.pop("XLA_FLAGS", None)     # one device: the smallest program
+
+        def child():
+            out = subprocess.run([sys.executable, "-c", _CHILD], env=env,
+                                 capture_output=True, text=True,
+                                 timeout=300,
+                                 cwd=os.path.dirname(os.path.dirname(
+                                     os.path.abspath(__file__))))
+            assert out.returncode == 0, (
+                f"child failed:\n{out.stdout}\n{out.stderr[-2000:]}")
+            assert f"DIR {cache_dir}\n" in out.stdout
+            hits, misses = re.search(r"HITS (\d+) MISSES (\d+)",
+                                     out.stdout).groups()
+            return int(hits), int(misses)
+
+        hits1, misses1 = child()
+        assert misses1 > 0 and hits1 == 0
+        assert any(cache_dir.iterdir())
+        hits2, misses2 = child()
+        assert hits2 == misses1 and misses2 == 0
 
 
 # ----------------------------------------------------------------------
@@ -535,8 +470,7 @@ class TestBuckets:
 # ----------------------------------------------------------------------
 
 class TestTrainerPrecompile:
-    def test_parallel_wrapper_warm_matches_cold(self, tmp_path,
-                                                no_cache):
+    def test_parallel_wrapper_warm_matches_cold(self, no_cache):
         from deeplearning4j_tpu.parallel.mesh import build_mesh
         from deeplearning4j_tpu.parallel.trainer import ParallelWrapper
 
@@ -559,7 +493,7 @@ class TestTrainerPrecompile:
             cold = run(False, wu)
             prev = aot._SESSION
             try:
-                aot.enable(str(tmp_path / f"pw_{wu}"))
+                aot.enable()
                 warm = run(True, wu)
             finally:
                 aot._SESSION = prev

@@ -302,6 +302,7 @@ class TestMultiLayerSpace:
         assert np.asarray(net.getParam("0_W")).shape == (6, 9)
         assert np.asarray(net.getParam("1_W")).shape == (9, 2)
 
+    @pytest.mark.slow  # tier-1 budget (PR 21): 3 s on 8 CPU cores
     def test_random_search_over_space_finds_good_model(self):
         space = self._space()
         gen = RandomSearchGenerator(space.parameterSpaces(), seed=4)
@@ -354,6 +355,7 @@ class TestComputationGraphSpace:
         assert set(self._space().parameterSpaces()) == {"learningRate",
                                                         "dense_nOut"}
 
+    @pytest.mark.slow  # tier-1 budget (PR 21): 3 s on 8 CPU cores
     def test_search_over_graph_space(self):
         space = self._space()
         gen = RandomSearchGenerator(space.parameterSpaces(), seed=3)

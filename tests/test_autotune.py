@@ -333,6 +333,7 @@ class TestBnEpilogue:
                 jnp.asarray(rng.randn(C).astype("float32")),
                 jnp.asarray(rng.rand(C).astype("float32") + 0.5))
 
+    @pytest.mark.slow  # tier-1 budget (PR 21): 2 s on 8 CPU cores
     @pytest.mark.parametrize(
         "act", ["identity", "relu", "leakyrelu", "tanh", "sigmoid"])
     def test_train_fwd_bwd_parity(self, act):

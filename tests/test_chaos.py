@@ -66,7 +66,7 @@ def _rows(n, seed=0):
 @pytest.fixture
 def fresh_cache():
     prev = aot._SESSION
-    cache = aot._SESSION = aot.ExecutableCache(None)
+    cache = aot._SESSION = aot.ExecutableCache()
     yield cache
     aot._SESSION = prev
 
@@ -177,16 +177,16 @@ class TestFaultKinds:
         class Boom(OSError):
             pass
 
-        plan = ChaosPlan().raise_n("aot.disk_read", times=2, at=1,
+        plan = ChaosPlan().raise_n("checkpoint.restore", times=2, at=1,
                                    exc=Boom, message="disk gone")
         with plan:
-            fault_point("aot.disk_read")            # ordinal 0: clean
+            fault_point("checkpoint.restore")       # ordinal 0: clean
             for _ in range(2):                      # ordinals 1, 2
                 with pytest.raises(Boom, match="disk gone"):
-                    fault_point("aot.disk_read")
-            fault_point("aot.disk_read")            # ordinal 3: clean
-        assert plan.events == [("aot.disk_read", "raise", 1),
-                               ("aot.disk_read", "raise", 2)]
+                    fault_point("checkpoint.restore")
+            fault_point("checkpoint.restore")       # ordinal 3: clean
+        assert plan.events == [("checkpoint.restore", "raise", 1),
+                               ("checkpoint.restore", "raise", 2)]
 
     def test_slow_and_wedge_use_injected_sleep(self):
         slept = []
@@ -212,13 +212,13 @@ class TestFaultKinds:
     def test_corrupt_default_and_custom_mutate(self):
         plan = (ChaosPlan()
                 .corrupt("host.submit", at=0)
-                .corrupt("aot.disk_read", at=0)
+                .corrupt("checkpoint.restore", at=0)
                 .corrupt("checkpoint.write", at=0,
                          mutate=lambda p: p * 10))
         with plan:
             arr = fault_point("host.submit",
                               np.ones(4, dtype=np.float32))
-            path = fault_point("aot.disk_read", "/tmp/x.bin")
+            path = fault_point("checkpoint.restore", "/tmp/x.bin")
             n = fault_point("checkpoint.write", 4)
         assert np.isnan(arr[0]) and not np.isnan(arr[1:]).any()
         assert path == "/tmp/x.bin.chaos-corrupt"
@@ -597,6 +597,7 @@ class TestChaosSoak:
         finally:
             fleet.close()
 
+    @pytest.mark.slow  # tier-1 budget (PR 21): 2 s on 8 CPU cores
     def test_armed_quiet_harness_overhead_within_3pct(
             self, fresh_cache):
         """The fast-path gate: a plan armed with rules only on an
@@ -670,6 +671,7 @@ class TestCheckpointDigest:
         with open(mpath, "w") as f:
             json.dump(manifest, f)
 
+    @pytest.mark.slow  # tier-1 budget (PR 21): 3 s on 8 CPU cores
     def test_digest_rides_the_commit_and_verifies(self, tmp_path):
         from deeplearning4j_tpu.util import sharded_checkpoint as ck
 

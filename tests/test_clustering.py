@@ -209,6 +209,7 @@ class TestKDTree:
 
 
 class TestRandomProjectionLSH:
+    @pytest.mark.slow  # tier-1 budget (PR 21): 3 s on 8 CPU cores
     def test_recall_on_clustered_data(self):
         X, _, _ = _blobs(n_per=200, k=5, d=16, seed=2, spread=10.0)
         from deeplearning4j_tpu.clustering import RandomProjectionLSH

@@ -29,7 +29,7 @@ from jax.sharding import PartitionSpec as P
 
 from deeplearning4j_tpu.analysis import collectives as colan
 from deeplearning4j_tpu.analysis.diagnostics import ALL_CODES
-from deeplearning4j_tpu.parallel._compat import shard_map
+from jax import shard_map
 from deeplearning4j_tpu.parallel.mesh import build_mesh, DATA_AXIS
 
 DP = 8
@@ -378,6 +378,7 @@ class TestCol03AccDtype:
             contract=colan.compression_contract("int8", 1))
         assert "COL03" in _codes(rep), rep.format()
 
+    @pytest.mark.slow  # tier-1 budget (PR 21): 3 s on 8 CPU cores
     def test_lowered_step_acc_dtype_verified(self, compressed_subjects):
         """The REAL int8 step's integer psum dtype agrees with
         expected_acc_dtype(dp) — checked by verify_program's COL03 leg

@@ -270,6 +270,7 @@ class TestGraphPretrain:
     """ComputationGraph.pretrain/pretrainLayer (reference parity with the
     MultiLayerNetwork VAE pretraining path)."""
 
+    @pytest.mark.slow  # tier-1 budget (PR 21): 3 s on 8 CPU cores
     def test_vae_vertex_pretrains(self):
         from deeplearning4j_tpu.nn import (NeuralNetConfiguration, InputType,
                                            ComputationGraph,
@@ -353,6 +354,7 @@ class TestRound4Vertices:
         net.fit([xa, xb], [y])
         assert np.isfinite(net.score())
 
+    @pytest.mark.slow  # tier-1 budget (PR 21): 3 s on 8 CPU cores
     def test_seq2seq_time_vertices(self):
         from deeplearning4j_tpu.nn import (
             NeuralNetConfiguration, InputType, ComputationGraph, LSTM,

@@ -91,6 +91,7 @@ class TestInt8Quantization:
         qb, fb = quantized_bytes(qp)
         assert qb < 0.3 * fb
 
+    @pytest.mark.slow  # tier-1 budget (PR 21): 3 s on 8 CPU cores
     def test_quantized_network_accuracy_delta(self):
         """Train a classifier to high accuracy, quantize, measure the
         delta — the int8 path must stay within 2 points of fp32 top-1
@@ -128,7 +129,7 @@ class TestInt8Quantization:
 class TestInt8ZooGraph:
     @pytest.mark.slow  # tier-1 budget (round 6): heavy compile-parity leg
     def test_resnet50_graph_int8_logit_parity(self):
-        """VERDICT r4 #7's zoo bar: Int8Inference must wrap a zoo
+        """The zoo bar: Int8Inference must wrap a zoo
         ComputationGraph (ResNet-50) and track its fp32 logits — cosine
         > 0.995 and >=90% top-1 agreement on the synthetic harness."""
         from deeplearning4j_tpu.ndarray import DataType

@@ -96,7 +96,7 @@ class TestDataParallelScaling:
 
 class TestMeasuredOverlap:
     """The overlap constant is measured from the compiled DP schedule
-    (parallel/overlap.py), not assumed (VERDICT r3 weak #3)."""
+    (parallel/overlap.py), not assumed."""
 
     def test_schedule_parser_on_synthetic_hlo(self):
         from deeplearning4j_tpu.parallel.overlap import (

@@ -1246,6 +1246,7 @@ class TestSequenceMultiReader:
         np.testing.assert_allclose(l[0, :, 1], [0, 0, 1])
         np.testing.assert_allclose(l[1, :, 0], [0, 1, 0])
 
+    @pytest.mark.slow  # tier-1 budget (PR 21): 2 s on 8 CPU cores
     def test_mixed_static_and_sequence_trains_graph(self, tmp_path):
         from deeplearning4j_tpu.data import (CSVRecordReader,
                                              RecordReaderMultiDataSetIterator)

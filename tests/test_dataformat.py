@@ -34,6 +34,7 @@ def _small_cnn(fmt):
     return MultiLayerNetwork(conf).init()
 
 
+@pytest.mark.slow  # tier-1 budget (PR 21): 4 s on 8 CPU cores
 def test_nhwc_output_parity_with_nchw():
     rng = np.random.RandomState(0)
     x_nchw = rng.rand(4, 3, 12, 10).astype("float32")
@@ -60,6 +61,7 @@ def test_invalid_format_rejected():
         InputType.convolutional(8, 8, 3, format="CHWN")
 
 
+@pytest.mark.slow  # tier-1 budget (PR 21): 15 s on 8 CPU cores
 def test_resnet50_nhwc_graph_runs():
     net = ResNet50(numClasses=10, inputShape=(3, 32, 32),
                    dataFormat="NHWC").init()
@@ -89,6 +91,7 @@ def _dense_head_cnn(fmt):
     return MultiLayerNetwork(conf).init()
 
 
+@pytest.mark.slow  # tier-1 budget (PR 21): 2 s on 8 CPU cores
 def test_nhwc_dense_head_label_parity():
     rng = np.random.RandomState(4)
     x_nchw = rng.rand(4, 3, 8, 6).astype("float32")

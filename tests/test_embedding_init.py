@@ -37,6 +37,7 @@ def w2v():
 
 
 class TestWeightInitEmbedding:
+    @pytest.mark.slow  # tier-1 budget (PR 21): 2 s on 8 CPU cores
     def test_rows_match_vocab_order(self, w2v):
         V, D = len(w2v.vocab), w2v.layerSize
         conf = (NeuralNetConfiguration.Builder().seed(1).list()

@@ -495,7 +495,7 @@ def fresh_cache():
     from deeplearning4j_tpu.runtime import aot
 
     prev = aot._SESSION
-    cache = aot._SESSION = aot.ExecutableCache(None)
+    cache = aot._SESSION = aot.ExecutableCache()
     yield cache
     aot._SESSION = prev
 
@@ -515,12 +515,11 @@ def _fleet(n_replicas, net, *, router_kw=None, **kw):
 class TestSeamCoverageGate:
     def test_every_registered_seam_fires(self, tmp_path, fresh_cache):
         """The 100% gate: one soak drives fleet traffic, a sequence
-        decode, a paged token generate, live HTTP GET+POST, AOT disk
-        read/write and a checkpointed fit — and EVERY seam in
+        decode, a paged token generate, live HTTP GET+POST and a
+        checkpointed fit — and EVERY seam in
         chaos.registered_seams() fires at least once. A seam this soak
         cannot reach is dead inventory."""
         from deeplearning4j_tpu.nn.transformer import CausalTransformerLM
-        from deeplearning4j_tpu.runtime.aot import ExecutableCache
         from deeplearning4j_tpu.runtime.resilience import (
             ResilientFit, RetryPolicy,
         )
@@ -535,10 +534,6 @@ class TestSeamCoverageGate:
                                      page_size=4, seed=0),
             slotBuckets=(2,), numPages=8)
         srv = InferenceServer(host).start(port=0, warmup=False)
-        disk = ExecutableCache(str(tmp_path / "aot"))
-        junk = disk._path("deadbeef")
-        with open(junk, "wb") as fh:
-            fh.write(b"not a pickle")
         seq = np.random.RandomState(0).randn(3, 4).astype(np.float32)
         fast = RetryPolicy(maxRetries=2, initialDelay=0.001,
                            maxDelay=0.002, sleep=lambda s: None)
@@ -553,10 +548,6 @@ class TestSeamCoverageGate:
             host.generate("g", [1, 2, 3, 4, 5], max_new_tokens=1)
             # server.request — GET and POST both route through it
             _get(base + "/v1/models")
-            # aot.disk_write (serialize of a non-executable fails
-            # AFTER the seam; counted, never raised) + aot.disk_read
-            disk.put("k" * 8, object())
-            assert disk.get("deadbeef") is None
             # checkpoint.write on the first fit, checkpoint.restore
             # on the resuming second fit
             net = _mlp_net()
@@ -715,18 +706,6 @@ class TestHedgeAudit:
 
 
 class TestStoreErrorCounters:
-    def test_aot_disk_store_failure_is_counted(self, tmp_path):
-        """Audit regression: ExecutableCache.put swallowed every disk
-        serialization failure — a broken store looked identical to a
-        cold one. Now it lands in stats["store_errors"]."""
-        from deeplearning4j_tpu.runtime.aot import ExecutableCache
-
-        c = ExecutableCache(str(tmp_path))
-        c.put("k" * 8, object())   # not serializable: store fails
-        assert c.stats["store_errors"] == 1
-        assert c.stats["puts"] == 1          # memory tier still took it
-        assert c.get("k" * 8) is not None    # and still serves it
-
     def test_tuning_store_failure_is_counted(self, tmp_path,
                                              monkeypatch):
         from deeplearning4j_tpu.runtime import autotune as at

@@ -60,6 +60,7 @@ class TestSkipgramSubwords:
                 .tokenizerFactory(DefaultTokenizerFactory())
                 .build().fit())
 
+    @pytest.mark.slow  # tier-1 budget (PR 21): 4 s on 8 CPU cores
     def test_topic_words_cluster(self, model):
         # subword sharing compresses cosine margins relative to plain
         # Word2Vec (every pair shares some hashed n-gram buckets), so
@@ -140,6 +141,7 @@ class TestSupervised:
         with pytest.raises(ValueError, match="__label__"):
             m.fit()
 
+    @pytest.mark.slow  # tier-1 budget (PR 21): 2 s on 8 CPU cores
     def test_unsupervised_model_predict_raises(self):
         m = (FastText.Builder().minCount(1).dim(4).epochs(1)
              .iterate(CollectionSentenceIterator(["a b c d e f g"] * 3))

@@ -1,5 +1,5 @@
 """fitDataSet(iterator, stepsPerSync=k) — the device-staged multi-batch
-epoch loop (VERDICT r5 item #2).
+epoch loop.
 
 The acceptance bar, verified here:
 

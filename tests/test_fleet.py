@@ -59,7 +59,7 @@ def _rows(n, seed=0):
 @pytest.fixture
 def fresh_cache():
     prev = aot._SESSION
-    cache = aot._SESSION = aot.ExecutableCache(None)
+    cache = aot._SESSION = aot.ExecutableCache()
     yield cache
     aot._SESSION = prev
 

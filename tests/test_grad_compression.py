@@ -88,6 +88,7 @@ class TestThresholdEncoder:
         assert threshold_cap(100, 1.0) == 100
         assert threshold_cap(100, 2.0) == 100    # clamped to n
 
+    @pytest.mark.slow  # tier-1 budget (PR 21): 3 s on 8 CPU cores
     def test_invariant_bitwise(self):
         rng = np.random.RandomState(3)
         flat = jnp.asarray(rng.randn(257).astype("float32"))
@@ -258,6 +259,7 @@ class TestThresholdCodec:
 # ----------------------------------------------------------------------
 # subject parity: threshold trains LeNet + resnet_block on the dp8 mesh
 # ----------------------------------------------------------------------
+@pytest.mark.slow  # tier-1 budget (PR 21): 15 s on 8 CPU cores
 @pytest.mark.parametrize("subject", ["lenet", "resnet_block"])
 def test_threshold_trains_subject_to_loss_parity(subject):
     """The acceptance gate: gradient_compression='threshold' trains the
@@ -318,6 +320,7 @@ class TestResilientThreshold:
                                     gradient_compression="threshold",
                                     threshold=1e-2)
 
+    @pytest.mark.slow  # tier-1 budget (PR 21): 8 s on 8 CPU cores
     def test_mid_epoch_resume_bitwise_with_residuals(self, tmp_path):
         from deeplearning4j_tpu.runtime.resilience import (
             FaultInjector, Preemption, ResilientFit)
@@ -391,6 +394,7 @@ class TestResilientThreshold:
 # composition: compressed reduce-scatter x ZeRO sharded update
 # ----------------------------------------------------------------------
 class TestComposedShardedCompression:
+    @pytest.mark.slow  # tier-1 budget (PR 21): 6 s on 8 CPU cores
     @pytest.mark.parametrize("mode", ["int8", "block_int8"])
     def test_parity_with_replicated_compressed_path(self, mode):
         """The quantized psum and the quantized reduce-scatter shard
@@ -490,6 +494,7 @@ class TestMeasuredCollectiveBytes:
         return [int(np.prod(l.shape))
                 for p in net._params for l in jtu.tree_leaves(p)]
 
+    @pytest.mark.slow  # tier-1 budget (PR 21): 4 s on 8 CPU cores
     @pytest.mark.parametrize("mode", ["int8", "block_int8", "threshold"])
     def test_replicated_modes_within_10pct(self, mode,
                                            compiled_compressed_steps):
@@ -663,6 +668,7 @@ class TestThresholdAlgorithmMapping:
                      "TargetSparsityThresholdAlgorithm"):
             assert name in msg
 
+    @pytest.mark.slow  # tier-1 budget (PR 21): 2 s on 8 CPU cores
     def test_residual_clipping_wired_and_applied(self):
         m = SharedTrainingMaster(
             self._net(), thresholdAlgorithm=1e9,

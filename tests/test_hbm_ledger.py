@@ -149,8 +149,8 @@ class TestFloor:
     def test_resnet50_b128_headline_floor(self):
         """Pin the headline floor the bench reports: ResNet-50 b128
         NHWC bf16 + Nesterovs. Recomputed here from the model so the
-        BENCH_NOTES number (11.85 GB/step vs 46.8 measured, ~3.9x
-        headroom) is reproducible by CI, not copied."""
+        analytic floor (11.85 GB/step) is reproducible by CI, not
+        copied."""
         from deeplearning4j_tpu.ndarray import DataType
         from deeplearning4j_tpu.nn import Nesterovs
         from deeplearning4j_tpu.zoo import ResNet50

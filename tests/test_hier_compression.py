@@ -142,6 +142,7 @@ class TestHierarchicalMesh:
 # ----------------------------------------------------------------------
 # subject parity: dp8 training vs the dense psum, one compile
 # ----------------------------------------------------------------------
+@pytest.mark.slow  # tier-1 budget (PR 21): 6 s on 8 CPU cores
 @pytest.mark.parametrize("intra_mode,group", [("block_int8", 4),
                                               (None, 4),
                                               ("block_int8", 2)])
@@ -221,6 +222,7 @@ class TestResilientHierarchical:
                                     threshold=1e-2,
                                     compressionGroupSize=4)
 
+    @pytest.mark.slow  # tier-1 budget (PR 21): 6 s on 8 CPU cores
     def test_mid_epoch_resume_bitwise_with_residuals(self, tmp_path):
         from deeplearning4j_tpu.runtime.resilience import (
             FaultInjector, Preemption, ResilientFit)
@@ -449,6 +451,7 @@ class TestMeasuredHierBytes:
         return [int(np.prod(l.shape))
                 for p in net._params for l in jtu.tree_leaves(p)]
 
+    @pytest.mark.slow  # tier-1 budget (PR 21): 10 s on 8 CPU cores
     @pytest.mark.parametrize("name,imode", [("block_int8", "block_int8"),
                                             ("dense", None)])
     def test_within_10pct(self, name, imode, compiled_hier_steps):

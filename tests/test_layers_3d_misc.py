@@ -56,6 +56,7 @@ class Test3DLayers:
             padded[0, 1:-1, 2:-2, :, :],
             np.asarray(net.feedForward(x)[0].jax())[0])
 
+    @pytest.mark.slow  # tier-1 budget (PR 21): 3 s on 8 CPU cores
     def test_deconv3d_inverts_conv_shape_and_trains(self):
         net = self._net(
             Convolution3D(nOut=4, kernelSize=2, stride=2),
@@ -186,6 +187,7 @@ class TestDeconv2DShapeConsistency:
     padding pairs in conv_transpose, so output shapes disagreed with
     getOutputType for any k != 2*pad + 1. Pin several configs."""
 
+    @pytest.mark.slow  # tier-1 budget (PR 21): 4 s on 8 CPU cores
     @pytest.mark.parametrize("k,s,p", [(2, 2, 0), (3, 2, 0), (3, 1, 1),
                                        (4, 2, 1), (5, 3, 2)])
     def test_forward_matches_shape_inference(self, k, s, p):

@@ -37,6 +37,7 @@ def _net(*layers, inputType, seed=7, updater=None, dtype=DataType.DOUBLE,
 
 
 class TestConv3D:
+    @pytest.mark.slow  # tier-1 budget (PR 21): 2 s on 8 CPU cores
     def test_shapes_and_output(self):
         net = _net(Convolution3D(nOut=4, kernelSize=(2, 2, 2), stride=(1, 1, 1),
                                  activation="relu"),
@@ -65,6 +66,7 @@ class TestConv3D:
                         x[0, d:d + 2, i:i + 2, j:j + 2, 0] * w[..., 0, 0])
         np.testing.assert_allclose(y, ref, rtol=1e-10)
 
+    @pytest.mark.slow  # tier-1 budget (PR 21): 3 s on 8 CPU cores
     def test_gradcheck(self):
         net = _net(Convolution3D(nOut=2, kernelSize=(2, 2, 2), activation="tanh"),
                    GlobalPoolingLayer(poolingType="avg"),
@@ -162,6 +164,7 @@ class TestSpatialReshaping:
 
 
 class TestLocallyConnected:
+    @pytest.mark.slow  # tier-1 budget (PR 21): 2 s on 8 CPU cores
     def test_lc2d_matches_conv_when_weights_shared(self):
         """If every position's weights are set equal, LC2D == conv2d."""
         rng = np.random.RandomState(0)
@@ -357,6 +360,7 @@ class TestConstraints:
 
 
 class TestVAE:
+    @pytest.mark.slow  # tier-1 budget (PR 21): 4 s on 8 CPU cores
     def test_pretrain_improves_elbo_and_reconstruction(self):
         rng = np.random.RandomState(0)
         # two gaussian clusters in 8-d
@@ -378,6 +382,7 @@ class TestVAE:
         base = ((x - x.mean(0)) ** 2).mean()
         assert ((x - rec) ** 2).mean() < base * 0.6
 
+    @pytest.mark.slow  # tier-1 budget (PR 21): 2 s on 8 CPU cores
     def test_vae_as_feature_layer(self):
         net = _net(VariationalAutoencoder(nOut=3, activation="tanh"),
                    OutputLayer(nOut=2, activation="softmax"),
@@ -394,6 +399,7 @@ class TestVAE:
         with pytest.raises(ValueError, match="pretrainable"):
             net.pretrainLayer(0, np.zeros((2, 3), "float32"))
 
+    @pytest.mark.slow  # tier-1 budget (PR 21): 2 s on 8 CPU cores
     def test_bernoulli_reconstruction(self):
         rng = np.random.RandomState(0)
         x = (rng.rand(64, 6) > 0.5).astype("float32")
@@ -543,6 +549,7 @@ class TestCapsNet:
                 .setInputType(InputType.convolutional(20, 20, 1)).build())
         return MultiLayerNetwork(conf).init()
 
+    @pytest.mark.slow  # tier-1 budget (PR 21): 6 s on 8 CPU cores
     def test_shapes_and_squash_bound(self):
         net = self._net()
         x = np.random.RandomState(0).rand(2, 1, 20, 20).astype("float32")
@@ -594,6 +601,7 @@ class TestCapsNet:
              .setInputType(InputType.recurrent(5))  # no length known
              .build())
 
+    @pytest.mark.slow  # tier-1 budget (PR 21): 3 s on 8 CPU cores
     def test_global_weight_init_and_dropout_respected(self):
         import jax.numpy as jnp
         from deeplearning4j_tpu.nn import (NeuralNetConfiguration, InputType,

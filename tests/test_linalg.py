@@ -428,7 +428,7 @@ class TestPlanGate:
         from jax.sharding import PartitionSpec as P
 
         from deeplearning4j_tpu.linalg.distributed import _summa_2d_body
-        from deeplearning4j_tpu.parallel._compat import shard_map
+        from jax import shard_map
 
         A = jnp.asarray(_rand((16, 8)))
         B = jnp.asarray(_rand((8, 4)))
@@ -454,6 +454,7 @@ class TestPlanGate:
 
 
 class TestConsumers:
+    @pytest.mark.slow  # tier-1 budget (PR 21): 2 s on 8 CPU cores
     def test_kmeans_sharded_parity(self, mesh1):
         from deeplearning4j_tpu.clustering import KMeansClustering
 

@@ -80,7 +80,7 @@ def fresh_cache():
     conftest is restored after; serving tests never get a disk tier —
     see the conftest note on deserialization fragility)."""
     prev = aot._SESSION
-    cache = aot._SESSION = aot.ExecutableCache(None)
+    cache = aot._SESSION = aot.ExecutableCache()
     yield cache
     aot._SESSION = prev
 
@@ -756,8 +756,8 @@ class TestThroughputAcceptance:
     def test_microbatching_3x_serial_at_bounded_p99(self, fresh_cache):
         """The serving headline gate (ISSUE 8 acceptance): open-loop
         load, concurrent pooled clients, dispatch-bound regime (the
-        batch-dim-sharded 8-device mesh — on TPU every dispatch pays
-        launch/tunnel latency; this is its CPU rehearsal). Dynamic
+        batch-dim-sharded 8-device mesh — the CPU rehearsal of an
+        expensive dispatch). Dynamic
         micro-batching must sustain >= 3x the serial one-dispatch-per-
         request requests/sec at bounded p99, with zero request-path
         compiles."""

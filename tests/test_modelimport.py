@@ -453,6 +453,7 @@ class TestKerasApplicationsImport:
         ours = np.asarray(net.output(x.transpose(0, 3, 1, 2)).jax())
         np.testing.assert_allclose(ours, golden, rtol=1e-3, atol=1e-4)
 
+    @pytest.mark.slow  # tier-1 budget (PR 21): 8 s on 8 CPU cores
     def test_mobilenet_v1_exact(self):
         # exercises: standalone ReLU(max_value=6), DepthwiseConv2D,
         # GlobalAveragePooling2D(keepdims=True), Reshape, asymmetric pad

@@ -170,6 +170,7 @@ class TestFitSteps:
         np.testing.assert_allclose(a.params().toNumpy(),
                                    b.params().toNumpy(), rtol=2e-6, atol=2e-6)
 
+    @pytest.mark.slow  # tier-1 budget (PR 21): 3 s on 8 CPU cores
     def test_tbptt_window_sweep(self):
         V, B, T, L = 5, 4, 8, 4
 
@@ -397,6 +398,7 @@ class TestGradients:
                     f"array {ai} idx {idx}: fd={fd} bp={bp}"
             net._params = jax.tree_util.tree_unflatten(treedef, flat)
 
+    @pytest.mark.slow  # tier-1 budget (PR 21): 3 s on 8 CPU cores
     def test_dense_gradients(self):
         x, y, _ = _separable_data(n=8)
         conf = (NeuralNetConfiguration.Builder().seed(3)
@@ -407,6 +409,7 @@ class TestGradients:
                 .setInputType(InputType.feedForward(4)).build())
         self._gradcheck(conf, x, y)
 
+    @pytest.mark.slow  # tier-1 budget (PR 21): 3 s on 8 CPU cores
     def test_conv_gradients(self):
         rng = np.random.RandomState(0)
         x = rng.rand(4, 1, 6, 6).astype("float64")
@@ -419,6 +422,7 @@ class TestGradients:
                 .setInputType(InputType.convolutional(6, 6, 1)).build())
         self._gradcheck(conf, x, y)
 
+    @pytest.mark.slow  # tier-1 budget (PR 21): 4 s on 8 CPU cores
     def test_lstm_gradients(self):
         rng = np.random.RandomState(0)
         x = rng.randn(4, 3, 5).astype("float64")
@@ -730,6 +734,7 @@ class TestModelInterfaceParity:
         with pytest.raises(ValueError, match="setParams"):
             net.setParams(flat[:-1])
 
+    @pytest.mark.slow  # tier-1 budget (PR 21): 2 s on 8 CPU cores
     def test_graph_compute_gradient_and_score(self):
         net = self._graph()
         rng = np.random.RandomState(1)
@@ -764,6 +769,7 @@ class TestVAEReconstructionProbability:
         net.pretrainLayer(0, x, epochs=150)
         return net, x, rng
 
+    @pytest.mark.slow  # tier-1 budget (PR 21): 2 s on 8 CPU cores
     def test_in_distribution_scores_higher_than_ood(self):
         net, x, rng = self._pretrained()
         lp_in = np.asarray(
@@ -775,6 +781,7 @@ class TestVAEReconstructionProbability:
         assert lp_in.mean() > lp_out.mean() + 10, (
             lp_in.mean(), lp_out.mean())
 
+    @pytest.mark.slow  # tier-1 budget (PR 21): 4 s on 8 CPU cores
     def test_probability_is_exp_of_log(self):
         import jax
         net, x, _ = self._pretrained()
@@ -786,6 +793,7 @@ class TestVAEReconstructionProbability:
         np.testing.assert_allclose(np.asarray(p), np.exp(np.asarray(lp)),
                                    rtol=1e-5)
 
+    @pytest.mark.slow  # tier-1 budget (PR 21): 3 s on 8 CPU cores
     def test_scores_track_preceding_layer_training(self):
         # the cached jit must see CURRENT weights of preceding layers,
         # not trace-time constants (layerIdx > 0 threads params/states)

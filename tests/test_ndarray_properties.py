@@ -27,12 +27,14 @@ floats = hnp.arrays(np.float32, shapes,
                     elements=st.floats(-100, 100, width=32))
 
 
+@pytest.mark.slow  # tier-1 budget (PR 21): 2 s on 8 CPU cores
 @given(a=floats)
 @settings(**SETTINGS)
 def test_roundtrip(a):
     np.testing.assert_array_equal(INDArray(a).toNumpy(), a)
 
 
+@pytest.mark.slow  # tier-1 budget (PR 21): 2 s on 8 CPU cores
 @given(a=floats, b=st.floats(-10, 10, width=32))
 @settings(**SETTINGS)
 def test_scalar_arithmetic(a, b):

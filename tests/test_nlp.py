@@ -33,6 +33,7 @@ class TestWord2Vec:
                 .tokenizerFactory(DefaultTokenizerFactory())
                 .build().fit())
 
+    @pytest.mark.slow  # tier-1 budget (PR 21): 3 s on 8 CPU cores
     def test_topic_words_cluster(self):
         m = self._fit()
         intra = m.similarity("cat", "dog")
@@ -86,6 +87,7 @@ class TestParagraphVectors:
                 .iterate(CollectionSentenceIterator(_corpus(100)))
                 .build().fit())
 
+    @pytest.mark.slow  # tier-1 budget (PR 21): 4 s on 8 CPU cores
     def test_doc_vectors_cluster_by_topic(self):
         m = self._fit()
         # reconstruct each doc's topic from the corpus generator
@@ -108,6 +110,7 @@ class TestParagraphVectors:
         inter = cos(va, vt).mean()
         assert intra > inter + 0.3, (intra, inter)
 
+    @pytest.mark.slow  # tier-1 budget (PR 21): 3 s on 8 CPU cores
     def test_infer_vector_matches_topic(self):
         m = self._fit()
         s_animal = m.similarityToDoc("the cat and the dog and the cow", 0)
@@ -126,11 +129,13 @@ class TestParagraphVectors:
         assert sa > st + 0.2, (sa, st)
         assert np.isfinite(s_animal)
 
+    @pytest.mark.slow  # tier-1 budget (PR 21): 2 s on 8 CPU cores
     def test_no_vocab_text_rejected(self):
         m = self._fit()
         with pytest.raises(ValueError, match="no in-vocabulary"):
             m.inferVector("zzz qqq")
 
+    @pytest.mark.slow  # tier-1 budget (PR 21): 6 s on 8 CPU cores
     def test_pv_save_load_roundtrip_and_untrained_doc(self, tmp_path):
         from deeplearning4j_tpu.nlp import ParagraphVectors
 
@@ -173,6 +178,7 @@ class TestDeepWalk:
         g.addEdge(5, 6)  # bridge
         return g
 
+    @pytest.mark.slow  # tier-1 budget (PR 21): 3 s on 8 CPU cores
     def test_clusters_separate(self):
         from deeplearning4j_tpu.graph import DeepWalk
 

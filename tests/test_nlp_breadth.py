@@ -41,6 +41,7 @@ class TestCBOW:
                 .tokenizerFactory(DefaultTokenizerFactory())
                 .build().fit())
 
+    @pytest.mark.slow  # tier-1 budget (PR 21): 2 s on 8 CPU cores
     def test_topic_words_cluster(self):
         m = self._fit()
         assert m.algorithm == "cbow"
@@ -231,6 +232,7 @@ class TestHierarchicSoftmax:
         inter = m.similarity("cat", "gpu")
         assert intra > inter + 0.2, (algorithm, intra, inter)
 
+    @pytest.mark.slow  # tier-1 budget (PR 21): 2 s on 8 CPU cores
     def test_paragraph_vectors_hs_and_serde(self, tmp_path):
         from deeplearning4j_tpu.nlp import ParagraphVectors
 
@@ -265,6 +267,7 @@ class TestHierarchicSoftmax:
         np.testing.assert_array_equal(pv2.inferVector("cat dog sheep"),
                                       pv.inferVector("cat dog sheep"))
 
+    @pytest.mark.slow  # tier-1 budget (PR 21): 5 s on 8 CPU cores
     def test_load_then_save_roundtrips_both_modes(self, tmp_path):
         # regression: save() writes counts unconditionally, so a LOADED
         # model (old files may lack counts) must survive re-saving

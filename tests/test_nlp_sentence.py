@@ -134,6 +134,7 @@ class TestCnnSentenceIterator:
         with pytest.raises(ValueError):
             CnnSentenceDataSetIterator(provider=None, wordVectors=wv)
 
+    @pytest.mark.slow  # tier-1 budget (PR 21): 4 s on 8 CPU cores
     def test_end_to_end_cnn_classifier(self):
         from deeplearning4j_tpu.nn import (NeuralNetConfiguration, InputType,
                                            MultiLayerNetwork,
@@ -467,6 +468,7 @@ class TestBinaryWordVectors:
 
 
 class TestFastTextIntegration:
+    @pytest.mark.slow  # tier-1 budget (PR 21): 3 s on 8 CPU cores
     def test_fasttext_feeds_cnn_sentence_iterator(self):
         # FastText shares the WordVectors query surface, so it plugs
         # into CnnSentenceDataSetIterator exactly like Word2Vec
@@ -489,6 +491,7 @@ class TestFastTextIntegration:
 
 
 class TestParagraphVectorsSerializer:
+    @pytest.mark.slow  # tier-1 budget (PR 21): 3 s on 8 CPU cores
     def test_write_read_roundtrip(self, tmp_path):
         from deeplearning4j_tpu.nlp import ParagraphVectors
         sents, _ = _corpus(16)
@@ -509,6 +512,7 @@ class TestParagraphVectorsSerializer:
         with pytest.raises(TypeError, match="ParagraphVectors"):
             WordVectorSerializer.writeParagraphVectors(w, tmp_path / "x")
 
+    @pytest.mark.slow  # tier-1 budget (PR 21): 4 s on 8 CPU cores
     def test_read_word2vec_model_returns_paragraph_vectors(self, tmp_path):
         from deeplearning4j_tpu.nlp import ParagraphVectors
         sents, _ = _corpus(12)

@@ -46,6 +46,7 @@ def _labels(boxes):
 
 
 class TestYoloLoss:
+    @pytest.mark.slow  # tier-1 budget (PR 21): 3 s on 8 CPU cores
     def test_loss_finite_and_positive(self):
         net = _net()
         x = np.random.RandomState(0).rand(2, 1, IN, IN).astype("float32")
@@ -70,6 +71,7 @@ class TestYoloLoss:
         s = net.score(DataSet(x, y))
         assert np.isfinite(s) and s >= 0
 
+    @pytest.mark.slow  # tier-1 budget (PR 21): 3 s on 8 CPU cores
     def test_overfit_then_detect(self):
         # train hard on one example; the head must localize the box
         net = _net(lr=5e-2)
@@ -89,6 +91,7 @@ class TestYoloLoss:
         assert best.predictedClass == 1
         assert abs(best.centerX - 1.5) < 0.5 and abs(best.centerY - 1.5) < 0.5
 
+    @pytest.mark.slow  # tier-1 budget (PR 21): 10 s on 8 CPU cores
     def test_gradients_flow(self):
         net = _net()
         x = np.random.RandomState(0).rand(2, 1, IN, IN).astype("float32")

@@ -57,7 +57,7 @@ _drop_jax_caches_after_module = drop_jax_caches_fixture()
 def fresh_cache():
     """Fresh MEMORY-ONLY session cache (hermetic miss counting)."""
     prev = aot._SESSION
-    cache = aot._SESSION = aot.ExecutableCache(None)
+    cache = aot._SESSION = aot.ExecutableCache()
     yield cache
     aot._SESSION = prev
 

@@ -60,6 +60,7 @@ class TestMesh:
 
 
 class TestDataParallel:
+    @pytest.mark.slow  # tier-1 budget (PR 21): 2 s on 8 CPU cores
     def test_dp_matches_single_device(self):
         """Gradient sharing over the mesh must produce bit-identical params
         to single-device training on the same global batch (the property
@@ -249,6 +250,7 @@ class TestSequenceParallel:
         p = jax.nn.softmax(s, axis=-1)
         return jnp.einsum("bhqk,bhkd->bhqd", p, v)
 
+    @pytest.mark.slow  # tier-1 budget (PR 21): 4 s on 8 CPU cores
     @pytest.mark.parametrize("causal", [False, True])
     def test_ring_attention_exact(self, causal):
         mesh = build_mesh({SEQ_AXIS: 8})
@@ -289,17 +291,6 @@ class TestSequenceParallel:
                                    rtol=2e-4, atol=2e-5)
 
 
-# PipelineParallel differentiates THROUGH a shard_map'd scan; legacy
-# jax (< jax.shard_map) trips a _SpecError in the experimental
-# shard_map's transpose. The multi-process CPU bootstrap is likewise
-# newer-jax-only ("Multiprocess computations aren't implemented on the
-# CPU backend"). Skip honestly there instead of failing.
-_legacy_shard_map = pytest.mark.skipif(
-    not hasattr(jax, "shard_map"),
-    reason="needs modern jax.shard_map (legacy experimental shard_map "
-           "cannot transpose the pipelined scan / multiprocess CPU)")
-
-
 class TestPipelineParallel:
     """GPipe-style microbatch pipeline over the 'pipe' mesh axis
     (parallel/pipeline.py). No upstream analog — TPU-first addition."""
@@ -338,7 +329,6 @@ class TestPipelineParallel:
         with pytest.raises(ValueError, match="identical"):
             partition_stages(net.layers, net._params, 4)
 
-    @_legacy_shard_map
     def test_pipeline_matches_single_device(self):
         """With SGD the pipelined step computes the same loss/params as
         plain single-device training on the same batch (microbatching
@@ -360,7 +350,6 @@ class TestPipelineParallel:
                                    rtol=1e-4, atol=1e-5)
         assert abs(ref.score() - net.score()) < 1e-4
 
-    @_legacy_shard_map
     def test_pipeline_composes_with_dp(self):
         from deeplearning4j_tpu.parallel import PipelineParallel
 
@@ -378,7 +367,6 @@ class TestPipelineParallel:
                                    net.params().toNumpy(),
                                    rtol=1e-4, atol=1e-5)
 
-    @_legacy_shard_map
     def test_pipeline_converges(self):
         from deeplearning4j_tpu.parallel import PipelineParallel
 
@@ -435,7 +423,6 @@ class TestPipelineRegressions:
         with pytest.raises(ValueError, match="identical"):
             partition_stages(net.layers, net._params, 4)
 
-    @_legacy_shard_map
     def test_pipeline_applies_constraints(self):
         """A constrained net must keep its weight norms bounded under
         PipelineParallel just like under net.fit()."""
@@ -945,16 +932,16 @@ print("CHILDREC " + json.dumps({
 '''
 
 
-@_legacy_shard_map
 class TestMultiHostTwoProcess:
-    """VERDICT r4 weak #5: the DCN path had never crossed a process
-    boundary. This spawns TWO OS processes, joins them through
+    """The DCN path must cross a process boundary at least once. This
+    spawns TWO OS processes, joins them through
     multihost.initialize (jax.distributed on the CPU backend,
     coordinator on 127.0.0.1), builds the hybrid mesh across both, and
     runs one DP+MP-sharded train step where each process contributes
     only ITS half of the batch — asserting loss/param parity against a
     single-process numpy oracle."""
 
+    @pytest.mark.slow  # tier-1 budget (PR 21): 2 s on 8 CPU cores
     def test_two_process_dp_step_parity(self, tmp_path):
         import json
         import os

@@ -50,7 +50,7 @@ class TestTsne:
 
 class TestTiledTsne:
     """Tiled (block-pairwise) mode: same mathematics as exact with
-    O(tile*N) memory (VERDICT r3 #9); exact mode is the oracle."""
+    O(tile*N) memory; exact mode is the oracle."""
 
     _clusters = TestTsne._clusters
 

@@ -1,8 +1,8 @@
 """Argmax-routed maxpool backward vs the select-and-scatter oracle.
 
 The custom VJP in ops/pooling.py exists to kill the single largest HBM
-consumer in the ResNet-50 train step (206 MB select-and-scatter, see
-BENCH_NOTES.md). These tests pin (a) forward parity, (b) exact gradient
+consumer in the ResNet-50 train step (206 MB select-and-scatter).
+These tests pin (a) forward parity, (b) exact gradient
 parity with JAX's stock reduce_window gradient — including on tied inputs,
 where both sides must route to the FIRST maximal window element — and
 (c) that the compiled gradient HLO actually contains no select-and-scatter
@@ -21,7 +21,7 @@ from deeplearning4j_tpu.ops import pooling
 @pytest.fixture(autouse=True)
 def _argmax_impl(monkeypatch):
     """This whole file tests the ARGMAX rewrite. The library default is
-    stock (the measured winner on CPU and TPU v5e — BENCH_NOTES.md), so
+    stock (see the switch's comment in ops/pooling.py), so
     without this pin every new-vs-reference parity assertion would
     compare the stock path against itself and pass vacuously."""
     monkeypatch.setattr(pooling, "_BACKWARD_IMPL", "argmax")
@@ -57,6 +57,7 @@ def test_forward_matches_reference(kernel, stride, padding):
     np.testing.assert_array_equal(np.asarray(y), np.asarray(y_ref))
 
 
+@pytest.mark.slow  # tier-1 budget (PR 21): 10 s on 8 CPU cores
 @pytest.mark.parametrize("kernel,stride,padding", CASES)
 def test_gradient_matches_select_and_scatter(kernel, stride, padding):
     key = jax.random.PRNGKey(1)
@@ -72,6 +73,7 @@ def test_gradient_matches_select_and_scatter(kernel, stride, padding):
                                rtol=0, atol=1e-12)
 
 
+@pytest.mark.slow  # tier-1 budget (PR 21): 12 s on 8 CPU cores
 @pytest.mark.parametrize("kernel,stride,padding", CASES)
 def test_gradient_tie_routing_matches(kernel, stride, padding):
     # Integer-valued floats force many intra-window ties (the post-relu
@@ -93,6 +95,7 @@ def test_gradient_tie_routing_matches(kernel, stride, padding):
                                rtol=0, atol=1e-12)
 
 
+@pytest.mark.slow  # tier-1 budget (PR 21): 3 s on 8 CPU cores
 def test_finite_difference_gradcheck():
     # fp64 central differences at a tie-free point.
     rng = np.random.default_rng(7)
@@ -157,6 +160,7 @@ def test_forward_mode_ad_documented_behavior():
     assert np.isfinite(np.asarray(jac)).all()
 
 
+@pytest.mark.slow  # tier-1 budget (PR 21): 3 s on 8 CPU cores
 def test_bf16_dtype_preserved():
     x = jax.random.normal(jax.random.PRNGKey(8), (2, 8, 8, 3)).astype(jnp.bfloat16)
     y = pooling.max_pool2d(x, (3, 3), (2, 2), "SAME")
@@ -190,6 +194,7 @@ class TestIndicesImpl:
     def _indices_impl(self, monkeypatch):
         monkeypatch.setattr(pooling, "_BACKWARD_IMPL", "indices")
 
+    @pytest.mark.slow  # tier-1 budget (PR 21): 14 s on 8 CPU cores
     @pytest.mark.parametrize("kernel,stride,padding", NON_OVERLAP_CASES)
     def test_forward_and_gradient_bitwise(self, kernel, stride, padding):
         """First-match tie rule == select-and-scatter's ge-select, so
@@ -208,6 +213,7 @@ class TestIndicesImpl:
         np.testing.assert_array_equal(np.asarray(g_new),
                                       np.asarray(g_ref))
 
+    @pytest.mark.slow  # tier-1 budget (PR 21): 7 s on 8 CPU cores
     @pytest.mark.parametrize("kernel,stride,padding", NON_OVERLAP_CASES)
     def test_tie_routing_bitwise(self, kernel, stride, padding):
         x = jnp.floor(jax.random.uniform(
@@ -318,6 +324,7 @@ class TestIndicesImpl:
 
 
 class TestGlobalMaxIndices:
+    @pytest.mark.slow  # tier-1 budget (PR 21): 6 s on 8 CPU cores
     @pytest.mark.parametrize("shape,axes", [
         ((4, 6, 6, 3), (1, 2)),      # NHWC spatial
         ((4, 5, 6, 7, 3), (1, 2, 3)),  # NDHWC

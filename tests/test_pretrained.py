@@ -37,6 +37,7 @@ def resnet_h5(tmp_path_factory):
 
 
 class TestResNet50Pretrained:
+    @pytest.mark.slow  # tier-1 budget (PR 21): 15 s on 8 CPU cores
     def test_golden_activation_parity(self, resnet_h5):
         path, x, golden = resnet_h5
         model = ResNet50(numClasses=10, inputShape=(3, 64, 64))
@@ -77,6 +78,7 @@ class TestResNet50Pretrained:
         assert np.isfinite(losses).all()
         assert losses[-1] < losses[0], losses
 
+    @pytest.mark.slow  # tier-1 budget (PR 21): 4 s on 8 CPU cores
     def test_wrong_architecture_h5_is_loud(self, resnet_h5, tmp_path):
         path, _, _ = resnet_h5
         model = VGG16(numClasses=10, inputShape=(3, 64, 64))
@@ -84,6 +86,7 @@ class TestResNet50Pretrained:
                            match="block1_conv1"):
             model.initPretrained(localFile=path)
 
+    @pytest.mark.slow  # tier-1 budget (PR 21): 3 s on 8 CPU cores
     def test_unmapped_model_is_loud(self, resnet_h5):
         path, _, _ = resnet_h5
         with pytest.raises(InvalidKerasConfigurationException,
@@ -105,6 +108,7 @@ class TestResNet50Pretrained:
 
 
 class TestVGG16Pretrained:
+    @pytest.mark.slow  # tier-1 budget (PR 21): 3 s on 8 CPU cores
     def test_golden_activation_parity(self, tmp_path):
         keras.utils.set_random_seed(11)
         km = keras.applications.VGG16(weights=None, include_top=True,
@@ -121,6 +125,7 @@ class TestVGG16Pretrained:
 
 
 class TestKeras3ArchivePretrained:
+    @pytest.mark.slow  # tier-1 budget (PR 21): 7 s on 8 CPU cores
     def test_resnet50_from_keras_archive(self, tmp_path):
         # .keras archives carry config layer names (conv1_conv etc.) via
         # the recomputed-group-name loader, so the SAME name map applies

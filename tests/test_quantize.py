@@ -4,6 +4,7 @@ bench int8_inference leg's machinery, pinned on CPU."""
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from deeplearning4j_tpu.nn.quantize import (dequantize_params,
                                             int8_infer_fn, param_bytes,
@@ -57,6 +58,7 @@ class TestTreeQuantization:
                                    atol=float(np.max(np.asarray(sc[0]["W"]))
                                               / 2) + 1e-6)
 
+    @pytest.mark.slow  # tier-1 budget (PR 21): 3 s on 8 CPU cores
     def test_int8_infer_agrees_on_small_net(self):
         from deeplearning4j_tpu.nn import (DenseLayer, InputType,
                                            MultiLayerNetwork,

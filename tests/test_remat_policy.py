@@ -57,6 +57,7 @@ def _data(seed=0, n=8):
 
 
 class TestSaveConvOutputsPolicy:
+    @pytest.mark.slow  # tier-1 budget (PR 21): 5 s on 8 CPU cores
     def test_trajectory_parity_with_stock(self):
         # recompute is the same math — parameters must track exactly
         x, y = _data()
@@ -129,6 +130,7 @@ class TestSaveConvOutputsPolicy:
         with pytest.raises(ValueError, match="checkpointPolicy"):
             NeuralNetConfiguration.Builder().checkpointPolicy("save_everything")
 
+    @pytest.mark.slow  # tier-1 budget (PR 21): 3 s on 8 CPU cores
     def test_mln_trajectory_parity(self):
         # the policy is a shared Builder option — MultiLayerNetwork
         # implements it too (same tag + jax.checkpoint wrap)

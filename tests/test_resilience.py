@@ -278,6 +278,7 @@ class TestNanGuard:
 
 
 class TestParallelWrapperGuard:
+    @pytest.mark.slow  # tier-1 budget (PR 21): 3 s on 8 CPU cores
     def test_guarded_dp_matches_plain_and_skips_nan(self, tmp_path):
         from deeplearning4j_tpu.parallel import ParallelWrapper
 

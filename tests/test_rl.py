@@ -58,6 +58,7 @@ def _qnet(n_in, n_out):
 
 
 class TestDQN:
+    @pytest.mark.slow  # tier-1 budget (PR 21): 7 s on 8 CPU cores
     def test_learns_chain_policy(self):
         mdp = ChainMDP(5)
         conf = QLearningConfiguration(

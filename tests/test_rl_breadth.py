@@ -68,6 +68,7 @@ def _conv_qnet(n, hist, n_out):
 
 
 class TestConvDQN:
+    @pytest.mark.slow  # tier-1 budget (PR 21): 11 s on 8 CPU cores
     def test_learns_pixel_track_policy(self):
         n, hist = 5, 2
         mdp = PixelTrackMDP(n)
@@ -81,6 +82,7 @@ class TestConvDQN:
         dqn.train(maxSteps=2200)
         assert dqn.getPolicy().play(PixelTrackMDP(n), maxSteps=20) == 10.0
 
+    @pytest.mark.slow  # tier-1 budget (PR 21): 2 s on 8 CPU cores
     def test_frame_stack_semantics(self):
         mdp = PixelTrackMDP(4)
         dqn = QLearningDiscreteConv(
@@ -108,6 +110,7 @@ class TestA3C:
         return A3CDiscreteDense(lambda: ChainMDP(5), conf,
                                 hiddenSize=32).train(maxSteps=steps)
 
+    @pytest.mark.slow  # tier-1 budget (PR 21): 5 s on 8 CPU cores
     def test_solves_chain_and_losses_decrease(self):
         a3c = self._train()
         assert a3c.getPolicy().play(ChainMDP(5), maxSteps=20) == 10.0
@@ -117,6 +120,7 @@ class TestA3C:
         assert late < early * 0.5, (early, late)
         assert np.isfinite(a3c._policy_losses).all()
 
+    @pytest.mark.slow  # tier-1 budget (PR 21): 3 s on 8 CPU cores
     def test_greedy_policy_walks_right_from_every_state(self):
         a3c = self._train()
         pol = a3c.getPolicy()
@@ -149,6 +153,7 @@ class TestAsyncNStepQLearning:
         return AsyncNStepQLearningDiscreteDense(
             lambda: ChainMDP(5), conf, hiddenSize=32).train(maxSteps=steps)
 
+    @pytest.mark.slow  # tier-1 budget (PR 21): 2 s on 8 CPU cores
     def test_solves_chain(self):
         ql = self._train()
         assert ql.getPolicy().play(ChainMDP(5), maxSteps=20) == 10.0
@@ -156,6 +161,7 @@ class TestAsyncNStepQLearning:
         l = ql._losses
         assert np.mean(l[-10:]) < np.mean(l[:10]), (l[:3], l[-3:])
 
+    @pytest.mark.slow  # tier-1 budget (PR 21): 2 s on 8 CPU cores
     def test_greedy_policy_right_from_every_state(self):
         ql = self._train()
         pol = ql.getPolicy()
@@ -229,6 +235,7 @@ class TestPolicyPersistence:
         acts = {stoch.nextAction(obs) for _ in range(50)}
         assert len(acts) >= 2  # not degenerate argmax
 
+    @pytest.mark.slow  # tier-1 budget (PR 21): 7 s on 8 CPU cores
     def test_trained_policy_survives_roundtrip(self, tmp_path):
         # the policy from a trained DQN must keep solving the MDP
         from deeplearning4j_tpu.rl import (DQNPolicy,

@@ -155,8 +155,9 @@ class TestGymEnvAdapter:
         with pytest.raises(ValueError, match="observation_space"):
             GymEnv(NoShape())
 
+    @pytest.mark.slow  # tier-1 budget (PR 21): 6 s on 8 CPU cores
     def test_dqn_trains_through_adapter(self):
-        """The VERDICT's done-bar: DQN learns the chain THROUGH the
+        """The done-bar: DQN learns the chain THROUGH the
         adapter, same bar as test_rl.py's native-MDP run."""
         env = GymEnv(GymChain(), seed=7)
         net = _qnet(env.obsSize(), env.numActions())

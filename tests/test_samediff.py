@@ -293,7 +293,7 @@ class TestControlFlow:
         np.testing.assert_allclose(r[acc_b.name].toNumpy(), 16.0)
 
     def test_bounded_while_trains_under_jit(self):
-        """VERDICT ask: a dynamic-iteration-count graph trains under jit.
+        """A dynamic-iteration-count graph trains under jit.
         The applied step count comes from a runtime placeholder (differs
         per batch); w trains through the masked-scan while loop."""
         rs = np.random.RandomState(0)

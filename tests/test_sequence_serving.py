@@ -103,7 +103,7 @@ def _sched(net, **kw):
 def fresh_cache():
     """Fresh MEMORY-ONLY session cache (hermetic miss counting)."""
     prev = aot._SESSION
-    cache = aot._SESSION = aot.ExecutableCache(None)
+    cache = aot._SESSION = aot.ExecutableCache()
     yield cache
     aot._SESSION = prev
 
@@ -150,6 +150,7 @@ class TestCarryAPI:
         with pytest.raises(ValueError, match="no recurrent layers"):
             SequenceScheduler(MultiLayerNetwork(ff).init())
 
+    @pytest.mark.slow  # tier-1 budget (PR 21): 4 s on 8 CPU cores
     def test_step_batched_bitwise_vs_rnn_time_step(self):
         """One jitted slot-batched step == the eager stateful path,
         bitwise, carried state included — the foundation the whole
@@ -175,6 +176,7 @@ class TestCarryAPI:
 # ----------------------------------------------------------------------
 
 class TestSchedulerDeterministic:
+    @pytest.mark.slow  # tier-1 budget (PR 21): 7 s on 8 CPU cores
     def test_ragged_lengths_bitwise_and_occupancy(self):
         net = _rnn_net()
         lens = [5, 2, 7, 1, 3, 4]
@@ -411,6 +413,7 @@ class TestIterationVsGang:
         sched.close()
         return st, wall, results
 
+    @pytest.mark.slow  # tier-1 budget (PR 21): 39 s on 8 CPU cores
     def test_iteration_level_2x_gang_and_bitwise(self):
         """ISSUE 15 acceptance: >= 2x aggregate decode throughput vs
         run-to-completion batching on a mixed-length workload, per-slot
@@ -589,6 +592,7 @@ class TestHostSequenceModels:
         finally:
             srv.stop(close_host=True)
 
+    @pytest.mark.slow  # tier-1 budget (PR 21): 5 s on 8 CPU cores
     def test_threaded_scheduler_serves_blocking_submits(self,
                                                         fresh_cache):
         """clock=None -> the background iteration loop serves blocking
@@ -644,6 +648,7 @@ class TestNonF32Policies:
                 .setInputType(InputType.recurrent(4, 6)).build())
         return MultiLayerNetwork(conf).init()
 
+    @pytest.mark.slow  # tier-1 budget (PR 21): 8 s on 8 CPU cores
     def test_bf16_carries_live_in_compute_dtype(self, fresh_cache):
         """Regression: the slot table hardcoded float32 carries, so a
         bf16 model's cell math ran f32-promoted — every step diverged

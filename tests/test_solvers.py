@@ -51,6 +51,7 @@ class TestSolvers:
         with pytest.raises(ValueError, match="unknown OptimizationAlgorithm"):
             OptimizationAlgorithm.resolve("newton")
 
+    @pytest.mark.slow  # tier-1 budget (PR 21): 4 s on 8 CPU cores
     def test_lbfgs_crushes_convex_problem(self):
         X, Y = _lsq_data()
         lbfgs = _regression_net(OptimizationAlgorithm.LBFGS)
@@ -84,6 +85,7 @@ class TestSolvers:
         assert all(b <= a + 1e-7 for a, b in zip(losses, losses[1:])), losses
         assert losses[-1] < losses[0] * 0.1
 
+    @pytest.mark.slow  # tier-1 budget (PR 21): 4 s on 8 CPU cores
     def test_lbfgs_trains_nonconvex_classifier(self):
         rng = np.random.RandomState(5)
         X = rng.randn(96, 6).astype("float32")
@@ -122,6 +124,7 @@ class TestSolvers:
                 s0 = net2.score()
         assert net2.score() < s0
 
+    @pytest.mark.slow  # tier-1 budget (PR 21): 3 s on 8 CPU cores
     def test_minibatch_iterator_works_with_lbfgs(self):
         X, Y = _lsq_data(n=64)
         net = _regression_net(OptimizationAlgorithm.LBFGS)
@@ -158,6 +161,7 @@ class TestSolvers:
         with pytest.raises(ValueError, match="optimizationAlgo"):
             net.pretrainLayer(0, np.zeros((4, 5), "float32"))
 
+    @pytest.mark.slow  # tier-1 budget (PR 21): 3 s on 8 CPU cores
     def test_frozen_layers_stay_frozen_under_lbfgs(self):
         X, Y = _lsq_data()
         net = _regression_net(OptimizationAlgorithm.LBFGS)
@@ -169,6 +173,7 @@ class TestSolvers:
 
 
 class TestSolversOnGraphAndGuards:
+    @pytest.mark.slow  # tier-1 budget (PR 21): 4 s on 8 CPU cores
     def test_computation_graph_lbfgs(self):
         from deeplearning4j_tpu.nn import (ComputationGraph, InputType)
         rng = np.random.RandomState(4)
@@ -197,6 +202,7 @@ class TestSolversOnGraphAndGuards:
         with pytest.raises(ValueError, match="STOCHASTIC_GRADIENT_DESCENT"):
             ParallelWrapper(net)
 
+    @pytest.mark.slow  # tier-1 budget (PR 21): 2 s on 8 CPU cores
     def test_optax_not_imported_for_sgd_nets(self):
         # OptimizationAlgorithm constants must not drag optax in at
         # package-import time (it is imported lazily inside solvers)
@@ -242,6 +248,7 @@ class TestFrozenUnderSolver:
     solver's own output must never move frozen params and the
     post-update reset in _train_step stays a no-op."""
 
+    @pytest.mark.slow  # tier-1 budget (PR 21): 14 s on 8 CPU cores
     @pytest.mark.parametrize("algo", [OptimizationAlgorithm.LBFGS,
                                       OptimizationAlgorithm.CONJUGATE_GRADIENT])
     def test_solver_output_never_moves_frozen_params(self, algo,

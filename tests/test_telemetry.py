@@ -662,6 +662,7 @@ class TestTrainingTelemetry:
             net.fit(x, y)
         assert sentinel.compiles("train_step") == 1
 
+    @pytest.mark.slow  # tier-1 budget (PR 21): 3 s on 8 CPU cores
     def test_overhead_gate_3pct(self):
         """The CI overhead gate: instrumented steady-state fit within
         3% of telemetry-disabled wall. The subject is a ~2 ms/step net

@@ -734,7 +734,7 @@ class TestAuditRegressions:
         `stats['misses'] += 1` read-modify-write did)."""
         from deeplearning4j_tpu.runtime.aot import ExecutableCache
 
-        cache = ExecutableCache(None)
+        cache = ExecutableCache()
         start = threading.Barrier(8)
 
         def worker():

@@ -179,6 +179,7 @@ class TestTransferLearningHelper:
         assert np.array_equal(w0, _p(net, 0, "W"))  # bottom untouched
         assert net.score(ds) < s0                    # top learned
 
+    @pytest.mark.slow  # tier-1 budget (PR 21): 3 s on 8 CPU cores
     def test_cnn_featurize_layout(self):
         conf = (NeuralNetConfiguration.Builder()
                 .seed(1).updater(Adam(1e-2))
@@ -275,6 +276,7 @@ class TestTransferGraphBuilder:
             net.fit(x, y)
         return net
 
+    @pytest.mark.slow  # tier-1 budget (PR 21): 3 s on 8 CPU cores
     def test_replace_head_grafts_trunk_and_freezes(self):
         from deeplearning4j_tpu.nn import TransferLearning, OutputLayer
 

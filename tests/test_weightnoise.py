@@ -106,6 +106,7 @@ class TestWeightNoise:
 
 
 class TestNestedParams:
+    @pytest.mark.slow  # tier-1 budget (PR 21): 5 s on 8 CPU cores
     def test_bidirectional_wrapper_gets_noise(self):
         # Bidirectional stores nested {'fwd': {...}, 'bwd': {...}} params;
         # weight noise must walk the pytree instead of crashing on dicts

@@ -85,6 +85,7 @@ def _assert_tree_close(a, b, rtol=2e-6, atol=1e-7):
 # trajectory parity
 # ----------------------------------------------------------------------
 class TestParityMultiLayer:
+    @pytest.mark.slow  # tier-1 budget (PR 21): 3 s on 8 CPU cores
     def test_fit_matches_replicated(self):
         x, y = _data()
         net_r = MultiLayerNetwork(_mlp()).init()
@@ -100,6 +101,7 @@ class TestParityMultiLayer:
         # the trajectories come out bitwise, and must stay ulp-close
         _assert_tree_close(net_r._params, net_s._params)
 
+    @pytest.mark.slow  # tier-1 budget (PR 21): 2 s on 8 CPU cores
     def test_fit_dataset_steps_per_sync_composes(self):
         X, Y = _data(4 * 16)
         net_r = MultiLayerNetwork(_mlp()).init()
@@ -147,6 +149,7 @@ class TestParityGraph:
                 .setOutputs("out")
                 .setInputTypes(InputType.feedForward(32)).build())
 
+    @pytest.mark.slow  # tier-1 budget (PR 21): 3 s on 8 CPU cores
     def test_fit_and_fit_dataset_match_replicated(self):
         X, Y = _data(4 * 16)
         g_r = ComputationGraph(self._conf()).init()
@@ -205,6 +208,7 @@ class TestParitySameDiff:
             self.i += 1
             return b
 
+    @pytest.mark.slow  # tier-1 budget (PR 21): 2 s on 8 CPU cores
     def test_fit_matches_replicated(self):
         a = self._make()
         h1 = a.fit(data=self._batches(4))
@@ -219,6 +223,7 @@ class TestParitySameDiff:
                  for l in jtu.tree_leaves(b._train_state)}
         assert "PartitionSpec('data',)" in specs
 
+    @pytest.mark.slow  # tier-1 budget (PR 21): 2 s on 8 CPU cores
     def test_fit_dataset_steps_per_sync(self):
         a = self._make()
         h1 = a.fitDataSet(self._It(self._batches(4)), stepsPerSync=2)
@@ -261,6 +266,7 @@ class TestEligibilityAndLayout:
         # shards the total element count, not the leading dim
         assert z.eligible(jnp.zeros((5, 64)))          # 320 % 8 == 0
 
+    @pytest.mark.slow  # tier-1 budget (PR 21): 3 s on 8 CPU cores
     def test_indivisible_leaf_replicates_never_pads(self):
         """A large leaf whose SIZE dp does not divide takes the explicit
         replicate fallback: full-shape state, replicated placement, and
@@ -320,6 +326,7 @@ class TestEligibilityAndLayout:
         expected = (2 * elig // DP + 2 * rep) * 4  # Adam: 2 fp32 slots
         assert measured == expected
 
+    @pytest.mark.slow  # tier-1 budget (PR 21): 2 s on 8 CPU cores
     def test_rewrapping_replicated_uninstalls_the_hook(self):
         """A net trained under a sharded-mode wrapper, re-wrapped
         replicated (or by ParameterAveragingTrainingMaster), sheds the
@@ -519,6 +526,7 @@ class TestMeasuredWeightUpdateBin:
                 if "[weight_update]" in t["name"]]
         return sum(t["bytes"] for t in rows), rec
 
+    @pytest.mark.slow  # tier-1 budget (PR 21): 5 s on 8 CPU cores
     def test_sharded_bin_within_10pct_of_bill(self, sharded_step_subject):
         net, pw, compiled = sharded_step_subject["sharded"]
         measured, _ = self._collective_weight_update_bytes(compiled, net)
@@ -600,6 +608,7 @@ class TestResilientShardedResume:
                                     weight_update="sharded",
                                     min_shard_size=256)
 
+    @pytest.mark.slow  # tier-1 budget (PR 21): 7 s on 8 CPU cores
     def test_mid_epoch_resume_bitwise(self, tmp_path):
         from deeplearning4j_tpu.runtime.resilience import (
             FaultInjector, Preemption, ResilientFit)
@@ -625,6 +634,7 @@ class TestResilientShardedResume:
         _assert_tree_equal(w1._unview_upd_states(n1._upd_states),
                            w3._unview_upd_states(n3._upd_states))
 
+    @pytest.mark.slow  # tier-1 budget (PR 21): 4 s on 8 CPU cores
     def test_guarded_k_loop_matches_k1(self):
         """ResilientFit(stepsPerSync=2): the non-finite-guarded staged
         k-loop carries the SHARDED updater state and bitwise-matches the
@@ -639,6 +649,7 @@ class TestResilientShardedResume:
                              stepsPerSync=2)
         _assert_tree_equal(n1._params, n2._params)
 
+    @pytest.mark.slow  # tier-1 budget (PR 21): 2 s on 8 CPU cores
     def test_plain_serializer_saves_canonical_layout(self, tmp_path):
         """net.save() (the npz ModelSerializer) applies the same
         canonical unview as the Orbax path."""
