@@ -25,7 +25,7 @@ throughput, images/sec/chip, vs the reference's cuDNN fp16 V100 number
 Method notes: headline steps are the donated jitted train step chained
 back-to-back; every timed window ends in block_until_ready. MFU uses
 XLA's own cost_analysis() flop count over the chip's bf16 peak
-(util/profiler.py). fit()-based configs include the per-iteration
+(perfbench/peaks.py, the one table of peaks). fit()-based configs include the per-iteration
 host loss fetch — the reference's fit() semantics pay the same sync.
 
 Process model: the parent never imports jax, so it never holds the
@@ -219,6 +219,20 @@ class _tail_mode:
         return False
 
 
+def _mfu(flops_per_step, step_time_s):
+    """Achieved FLOP/s over the chip's bf16 peak; 0.0 on the CPU
+    platform, an error for an accelerator perfbench/peaks.py lacks."""
+    import jax
+
+    from perfbench.peaks import peaks_for
+
+    dev = jax.devices()[0]
+    if dev.platform == "cpu" or step_time_s <= 0:
+        return 0.0
+    return flops_per_step / step_time_s \
+        / peaks_for(dev.device_kind)["flops_bf16"]
+
+
 def _measure_resnet50(stem, remat=False, tail_mode=None):
     import jax
     import jax.numpy as jnp
@@ -327,7 +341,7 @@ def _measure_resnet50(stem, remat=False, tail_mode=None):
         "compile_s": round(compile_s, 1),
         "flops_per_step": cost["flops"],
         "hbm_bytes_per_step": cost["bytes_accessed"],
-        "mfu": round(profiler.mfu(cost["flops"], dt), 3),
+        "mfu": round(_mfu(cost["flops"], dt), 3),
     }
     if ledger_rec is not None:
         rec["hbm_ledger"] = ledger_rec
@@ -375,12 +389,12 @@ def bench_lenet():
         "images_per_sec",
         {"images_per_sec": round(B / dt_loop, 1),
          "step_ms": round(dt_loop * 1e3, 3), "batch": B,
-         "mfu": round(profiler.mfu(cost["flops"], dt_loop), 4),
+         "mfu": round(_mfu(cost["flops"], dt_loop), 4),
          "loop_steps": K,
          "note": f"fitSteps(k={K}) on-device loop, one loss fetch per k"},
         {"images_per_sec": round(B / dt, 1),
          "step_ms": round(dt * 1e3, 3), "batch": B,
-         "mfu": round(profiler.mfu(cost["flops"], dt), 4),
+         "mfu": round(_mfu(cost["flops"], dt), 4),
          "note": "fit() incl. per-iteration loss fetch"})
 
 

@@ -22,7 +22,10 @@ from deeplearning4j_tpu.nn.multilayer import (_grad_normalize, _unwrap,
                                                cast_params,
                                                default_param_update,
                                                strip_carries,
-                                               checkpointed_forward)
+                                               checkpointed_forward,
+                                               fit_iterator_epoch,
+                                               traced_train_step)
+from deeplearning4j_tpu.runtime import telemetry
 
 
 class ComputationGraph:
@@ -97,15 +100,16 @@ class ComputationGraph:
                               hbm_gb=hbm_gb, plan=plan)
         key = jax.random.key(self.conf.seed)
         params, states, upds, upd_states = {}, {}, {}, {}
-        for i, name in enumerate(self._layer_names):
-            node = self.conf.nodes[name]
-            k = jax.random.fold_in(key, i)
-            p, s = node.payload.initialize(k, node.layerInputType, self._param_dtype)
-            params[name] = p
-            states[name] = s
-            u = _upd.resolve(node.payload.updater) if node.payload.updater is not None else _upd.Sgd()
-            upds[name] = u
-            upd_states[name] = u.init(p) if p else ()
+        with telemetry.phase("weights_init"):
+            for i, name in enumerate(self._layer_names):
+                node = self.conf.nodes[name]
+                k = jax.random.fold_in(key, i)
+                p, s = node.payload.initialize(k, node.layerInputType, self._param_dtype)
+                params[name] = p
+                states[name] = s
+                u = _upd.resolve(node.payload.updater) if node.payload.updater is not None else _upd.Sgd()
+                upds[name] = u
+                upd_states[name] = u.init(p) if p else ()
         self._params, self._states = params, states
         self._updaters, self._upd_states = upds, upd_states
         if self._solver is not None:
@@ -527,8 +531,7 @@ class ComputationGraph:
             data.reset()
             for lst in self._listeners:
                 getattr(lst, "onEpochStart", lambda m: None)(self)
-            while data.hasNext():
-                self._fit_ds(data.next())
+            fit_iterator_epoch(self, data, self._step)
             for lst in self._listeners:
                 getattr(lst, "onEpochEnd", lambda m: None)(self)
             self._epoch += 1
@@ -570,24 +573,7 @@ class ComputationGraph:
                 v.ndim == 3 for v in inputs.values()):
             self._fit_tbptt(inputs, labels, fmasks, lmasks)
             return
-        key = jax.random.fold_in(jax.random.key(self.conf.seed ^ 0x5EED), self._iteration)
-        from deeplearning4j_tpu.nn.multilayer import _tm
-
-        tm = _tm()
-        t0 = tm["reg"].clock()
-        self._params, self._upd_states, self._states, loss = self._jit_train(
-            self._params, self._upd_states, self._states,
-            jnp.asarray(self._iteration, jnp.int32), inputs, labels, key,
-            fmasks, lmasks)
-        self._score = float(loss)
-        dt = tm["reg"].clock() - t0
-        tm["step_s"].observe(dt)
-        tm["steps"].inc()
-        tm["reg"].trace.add("train.step", "train", t0, dt,
-                            {"iteration": self._iteration})
-        self._iteration += 1
-        for lst in self._listeners:
-            lst.iterationDone(self, self._iteration, self._epoch)
+        traced_train_step(self, inputs, labels, fmasks, lmasks)
 
     def fitSteps(self, data, labels=None, numSteps=1):
         """TPU-native k-step fit for graphs — numSteps optimizer steps

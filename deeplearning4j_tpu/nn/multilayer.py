@@ -29,6 +29,7 @@ from deeplearning4j_tpu.nn import updaters as _upd
 from deeplearning4j_tpu.nn.conf import layers as L
 from deeplearning4j_tpu.nn.conf.builder import BackpropType, GradientNormalization
 from deeplearning4j_tpu.nn.conf.inputs import InputType
+from deeplearning4j_tpu.runtime import telemetry
 
 
 def _unwrap(x):
@@ -50,8 +51,6 @@ def _tm():
     function (zero added device syncs / compiles; CI-gated)."""
     global _TM
     if _TM is None:
-        from deeplearning4j_tpu.runtime import telemetry
-
         reg = telemetry.get_registry()
         _TM = {
             "reg": reg,
@@ -76,6 +75,67 @@ def _tm():
                 "fitDataSet host syncs (one per k-block)"),
         }
     return _TM
+
+
+def traced_train_step(net, x, y, fmasks, lmasks):
+    """One optimizer step of fit() with its host work under spans — the
+    ONE body MultiLayerNetwork._fit_batch and ComputationGraph._step
+    share, so the two cannot drift. All spans are cat ``train``, carry
+    ``iteration`` and lie on the registry's clock, outside every traced
+    function (docs/OBSERVABILITY.md):
+
+    train.prepare    the dropout key's fold_in
+    train.step       dispatch + loss fetch (feeds dl4j_train_step_seconds)
+      train.dispatch   the iteration scalar + the jitted call, until it
+                       returns its futures
+      train.sync       float(loss): the host waits out the device step
+    train.listeners  the iterationDone loop
+    """
+    tm = _tm()
+    reg = tm["reg"]
+    clock, add = reg.clock, reg.trace.add
+    args = {"iteration": net._iteration}
+    t0 = clock()
+    key = jax.random.fold_in(jax.random.key(net.conf.seed ^ 0x5EED),
+                             net._iteration)
+    t1 = clock()
+    net._params, net._upd_states, net._states, loss = net._jit_train(
+        net._params, net._upd_states, net._states,
+        jnp.asarray(net._iteration, jnp.int32), x, y, key, fmasks, lmasks)
+    t2 = clock()
+    # recorded while the device works: what follows the loss fetch is
+    # on the step's critical path, this is not
+    step_id = reg.new_span_id()
+    add("train.prepare", "train", t0, t1 - t0, args)
+    add("train.dispatch", "train", t1, t2 - t1, args, parent=step_id)
+    net._score = float(loss)
+    t3 = clock()
+    tm["step_s"].observe(t3 - t1)
+    tm["steps"].inc()
+    add("train.sync", "train", t2, t3 - t2, args, parent=step_id)
+    add("train.step", "train", t1, t3 - t1, args, span_id=step_id)
+    net._iteration += 1
+    t4 = clock()
+    for lst in net._listeners:
+        lst.iterationDone(net, net._iteration, net._epoch)
+    add("train.listeners", "train", t4, clock() - t4, args)
+
+
+def fit_iterator_epoch(net, data, fit_one):
+    """The batches of one epoch of fit(iterator): `fit_one(batch)` for
+    every batch `data` yields, with the iterator's share of the step
+    (hasNext + next + the unwrapping into arrays that `net._extract_ds`
+    does) recorded as ``train.data_wait`` — a slow source shows up
+    there, not as a slow-looking step."""
+    reg = _tm()["reg"]
+    while True:
+        t0 = reg.clock()
+        if not data.hasNext():
+            return
+        batch = net._extract_ds(data.next())
+        reg.trace.add("train.data_wait", "train", t0, reg.clock() - t0,
+                      {"iteration": net._iteration})
+        fit_one(*batch)
 
 
 def checkpointed_forward(layer, l_train):
@@ -390,6 +450,7 @@ def example_batch(net, batchSize, featuresShape=None, labelsShape=None):
             np.zeros(labelsShape, np.float32))
 
 
+@telemetry.phase("warm")
 def precompile_network(net, batchSize=32, featuresShape=None,
                        labelsShape=None, entries=("train", "infer"),
                        stepsPerSync=None, cache=None, wrap_args=None,
@@ -716,14 +777,15 @@ class MultiLayerNetwork:
                               hbm_gb=hbm_gb, plan=plan)
         key = jax.random.key(self.conf.seed)
         params, states, upds, upd_states = [], [], [], []
-        for i, layer in enumerate(self.layers):
-            k = jax.random.fold_in(key, i)
-            p, s = layer.initialize(k, self.conf.layerInputTypes[i], self._param_dtype)
-            params.append(p)
-            states.append(s)
-            u = _upd.resolve(layer.updater) if layer.updater is not None else _upd.Sgd()
-            upds.append(u)
-            upd_states.append(u.init(p) if p else ())
+        with telemetry.phase("weights_init"):
+            for i, layer in enumerate(self.layers):
+                k = jax.random.fold_in(key, i)
+                p, s = layer.initialize(k, self.conf.layerInputTypes[i], self._param_dtype)
+                params.append(p)
+                states.append(s)
+                u = _upd.resolve(layer.updater) if layer.updater is not None else _upd.Sgd()
+                upds.append(u)
+                upd_states.append(u.init(p) if p else ())
         self._params, self._states = params, states
         self._updaters, self._upd_states = upds, upd_states
         if self._solver is not None:
@@ -1096,8 +1158,7 @@ class MultiLayerNetwork:
             data.reset()
             for lst in self._listeners:
                 getattr(lst, "onEpochStart", lambda m: None)(self)
-            while data.hasNext():
-                self._fit_batch(data.next())
+            fit_iterator_epoch(self, data, self._step)
             for lst in self._listeners:
                 getattr(lst, "onEpochEnd", lambda m: None)(self)
             self._epoch += 1
@@ -1109,30 +1170,23 @@ class MultiLayerNetwork:
                 "Network is not initialized — call net.init() before "
                 "fit/output/score (reference: MultiLayerNetwork.init())")
 
+    @staticmethod
+    def _extract_ds(ds):
+        """(features, labels, features mask, labels mask) of a DataSet
+        as arrays."""
+        return (_unwrap(ds.getFeatures()), _unwrap(ds.getLabels()),
+                _unwrap(ds.getFeaturesMaskArray()),
+                _unwrap(ds.getLabelsMaskArray()))
+
     def _fit_batch(self, ds):
+        self._step(*self._extract_ds(ds))
+
+    def _step(self, x, y, fmask, lmask):
         self._require_init()
-        x = _unwrap(ds.getFeatures())
-        y = _unwrap(ds.getLabels())
-        fmask = _unwrap(ds.getFeaturesMaskArray())
-        lmask = _unwrap(ds.getLabelsMaskArray())
         if self.conf.backpropType == BackpropType.TruncatedBPTT and x.ndim == 3:
             self._fit_tbptt(x, y, fmask, lmask)
             return
-        key = jax.random.fold_in(jax.random.key(self.conf.seed ^ 0x5EED), self._iteration)
-        tm = _tm()
-        t0 = tm["reg"].clock()
-        self._params, self._upd_states, self._states, loss = self._jit_train(
-            self._params, self._upd_states, self._states,
-            jnp.asarray(self._iteration, jnp.int32), x, y, key, fmask, lmask)
-        self._score = float(loss)
-        dt = tm["reg"].clock() - t0
-        tm["step_s"].observe(dt)
-        tm["steps"].inc()
-        tm["reg"].trace.add("train.step", "train", t0, dt,
-                            {"iteration": self._iteration})
-        self._iteration += 1
-        for lst in self._listeners:
-            lst.iterationDone(self, self._iteration, self._epoch)
+        traced_train_step(self, x, y, fmask, lmask)
 
     def _fit_tbptt(self, x, y, fmask, lmask):
         """Truncated BPTT: split time into tbpttFwdLength chunks, carrying

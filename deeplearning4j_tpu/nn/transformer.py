@@ -88,8 +88,10 @@ class CausalTransformerLM:
         self.head_dim = self.d_model // self.n_heads
         self.seed = int(seed)
         self._compute_dtype = jnp.dtype(dtype)
-        self._params = self._init_params()
-        from deeplearning4j_tpu.runtime import aot
+        from deeplearning4j_tpu.runtime import aot, telemetry
+
+        with telemetry.phase("weights_init"):
+            self._params = self._init_params()
 
         fp = self.fingerprint()
         # the pool/slab buffers are donated on every backend: the step

@@ -103,9 +103,11 @@ def _tm_compile(t0, key=None, entry=None):
     dt = time.perf_counter() - t0
     tm["misses"].inc()
     tm["compile_s"].observe(dt)
+    # a compile paid inside a span() block (setup.warm) is its child
     tm["reg"].trace.add(
         "aot.compile", "compile", t0, dt,
-        {"key": (key or "")[:16], "entry": entry or ""})
+        {"key": (key or "")[:16], "entry": entry or ""},
+        parent=tm["reg"].current_span_id())
     return dt
 
 

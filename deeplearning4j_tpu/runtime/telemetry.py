@@ -9,8 +9,9 @@ of the system design (arXiv:1605.08695); under whole-program compilation
 EXECUTABLE, not the op — which is exactly what lets every instrument in
 this module live at dispatch boundaries, on host-side code that already
 runs between device dispatches, with zero added device syncs and zero
-added compiles (CI-gated: RetraceSentinel + the ≤3% overhead gate in
-tests/test_telemetry.py).
+added compiles (tier-1: RetraceSentinel in tests/test_telemetry.py and
+tests/test_span_tree.py; the measured cost of the spans on the chip is
+in docs/OBSERVABILITY.md).
 
 Three cooperating pieces:
 
@@ -23,15 +24,17 @@ Three cooperating pieces:
   ``serving.server.InferenceServer``).
 * span tracing — ``span()``/``add_span()``/``event()`` record structured
   spans (train step wall, fitDataSet staging vs data-wait, AOT
-  compile/deserialize, serving coalesce→dispatch→reply) into a bounded
-  ring buffer, exportable as JSONL (``export_jsonl``) and Chrome
-  trace-event JSON (``export_chrome_trace``) viewable in Perfetto
-  (ui.perfetto.dev → open trace file). docs/OBSERVABILITY.md has the
-  span taxonomy and a how-to.
+  compile/deserialize, serving coalesce→dispatch→reply, the sequence
+  scheduler's iteration tree) into a bounded ring buffer, exportable as
+  Chrome trace-event JSON (``export_chrome_trace``) viewable in Perfetto
+  (ui.perfetto.dev → open trace file). Every span carries an ``id``,
+  the ``parent`` that caused it and the ``rid`` of its request;
+  ``phase()`` is the set-up span that also feeds a counter.
+  docs/OBSERVABILITY.md has the span taxonomy and a how-to.
 * a process-wide kill switch — ``set_enabled(False)`` (or env
   ``DL4J_TPU_TELEMETRY=off``) turns every instrument write and span
-  record into a cheap no-op; the overhead CI gate measures the
-  instrumented step against exactly this mode.
+  record into a cheap no-op; the cost of the spans is measured
+  against exactly this mode.
 
 This module imports NO jax and performs NO device operations — the
 purity linter's PUR02 (host sync inside traced code) is clean over it by
@@ -41,7 +44,9 @@ RetraceSentinel's compile counter).
 
 from __future__ import annotations
 
+import collections
 import contextlib
+import itertools
 import json
 import math
 import os
@@ -50,7 +55,7 @@ import time
 
 __all__ = [
     "MetricsRegistry", "Counter", "Gauge", "Histogram", "TraceBuffer",
-    "get_registry", "set_enabled", "enabled", "percentile",
+    "get_registry", "set_enabled", "enabled", "percentile", "phase",
     "DEFAULT_BUCKETS",
 ]
 
@@ -366,39 +371,58 @@ class Histogram(_Instrument):
 # ----------------------------------------------------------------------
 # span tracing
 # ----------------------------------------------------------------------
-class TraceBuffer:
-    """Bounded ring of structured spans. A span is one dict:
-    {name, cat, ts (seconds on the registry clock), dur (seconds),
-    ph ('X' complete span / 'i' instant), pid, tid, args} — directly
-    mappable to the Chrome trace-event format Perfetto loads."""
+#: span ids: one counter for the process, so an id names one span
+#: whatever registry recorded it (next() on it is atomic under the GIL)
+_SPAN_IDS = itertools.count(1)
 
-    def __init__(self, capacity=8192):
+_SPAN_KEYS = ("id", "parent", "rid", "name", "cat", "ts", "dur", "ph",
+              "pid", "tid", "args")
+
+
+class TraceBuffer:
+    """Bounded ring of structured spans. A span reads as one dict:
+    {id, parent (the id of the span that caused it, or None), rid (the
+    request it belongs to, or None), name, cat, ts (seconds on the
+    registry clock), dur (seconds), ph ('X' complete span / 'i'
+    instant), pid, tid, args} — directly mappable to the Chrome
+    trace-event format Perfetto loads. The ring keeps tuples and builds
+    the dicts on read; ``dropped`` counts what the bound evicted since
+    the last clear(): a reader that finds it above 0 is looking at the
+    newest part of its window only."""
+
+    def __init__(self, capacity=32768):
         self.capacity = int(capacity)
         self._lock = threading.Lock()
-        self._spans = []
+        self._spans = collections.deque(maxlen=self.capacity)
         self.dropped = 0   # spans evicted by the ring bound
 
-    def add(self, name, cat, ts, dur, args=None, ph="X"):
+    def add(self, name, cat, ts, dur, args=None, ph="X", parent=None,
+            rid=None, span_id=None):
+        """Record one span; returns its id (`span_id` where the caller
+        drew it beforehand with new_span_id(), so that children recorded
+        earlier could name it), or None when telemetry is off."""
         if not _ENABLED:
-            return
-        span = {"name": str(name), "cat": str(cat), "ts": float(ts),
-                "dur": float(dur), "ph": ph, "pid": os.getpid(),
-                "tid": threading.get_ident(),
-                "args": dict(args) if args else {}}
+            return None
+        if span_id is None:
+            span_id = next(_SPAN_IDS)
+        span = (span_id, parent, rid, str(name), str(cat), float(ts),
+                float(dur), ph, os.getpid(), threading.get_ident(),
+                dict(args) if args else {})
         with self._lock:
+            if len(self._spans) == self.capacity:
+                self.dropped += 1
             self._spans.append(span)
-            if len(self._spans) > self.capacity:
-                drop = len(self._spans) - self.capacity
-                del self._spans[:drop]
-                self.dropped += drop
+        return span_id
 
     def spans(self):
         with self._lock:
-            return [dict(s) for s in self._spans]
+            raw = list(self._spans)
+        return [dict(zip(_SPAN_KEYS, s[:-1] + (dict(s[-1]),)))
+                for s in raw]
 
     def clear(self):
         with self._lock:
-            self._spans = []
+            self._spans.clear()
             self.dropped = 0
 
 
@@ -411,11 +435,12 @@ class MetricsRegistry:
     spans with explicit timestamps via add_span.
     """
 
-    def __init__(self, clock=None, trace_capacity=8192):
+    def __init__(self, clock=None, trace_capacity=32768):
         self.clock = clock if clock is not None else time.perf_counter
         self._lock = threading.RLock()
         self._instruments = {}
         self.trace = TraceBuffer(trace_capacity)
+        self._open = threading.local()   # .stack: ids of open span()s
 
     # -- instrument factories (get-or-create, type-checked) -------------
     def _get_or_create(self, cls, name, help, labelnames, **kw):
@@ -463,28 +488,59 @@ class MetricsRegistry:
         return self
 
     # -- tracing ---------------------------------------------------------
+    @staticmethod
+    def new_span_id():
+        """Draw a span id ahead of its span: code that owns its clock
+        records children (``add_span(parent=...)``) before the parent
+        that encloses them ends (``add_span(span_id=...)``)."""
+        return next(_SPAN_IDS)
+
+    def current_span_id(self):
+        """The id of the innermost ``span()`` block open on this thread,
+        or None — what ``parent`` a span recorded by hand inside it
+        takes."""
+        stack = getattr(self._open, "stack", None)
+        return stack[-1] if stack else None
+
     @contextlib.contextmanager
-    def span(self, name, cat="", **args):
+    def span(self, name, cat="", rid=None, **args):
         """Record the wrapped block as one complete span on this
-        registry's clock. No-op (beyond one clock read) when telemetry
-        is disabled."""
+        registry's clock. Blocks nest: each thread keeps a stack of its
+        open spans and a span's ``parent`` is the one it opened inside.
+        No-op (beyond one switch read) when telemetry is disabled."""
         if not _ENABLED:
             yield
             return
+        stack = getattr(self._open, "stack", None)
+        if stack is None:
+            stack = self._open.stack = []
+        span_id = next(_SPAN_IDS)
+        parent = stack[-1] if stack else None
+        stack.append(span_id)
         t0 = self.clock()
         try:
             yield
         finally:
-            self.trace.add(name, cat, t0, self.clock() - t0, args)
+            dur = self.clock() - t0
+            stack.pop()
+            self.trace.add(name, cat, t0, dur, args, parent=parent,
+                           rid=rid, span_id=span_id)
 
-    def add_span(self, name, cat, ts, dur, **args):
+    def add_span(self, name, cat, ts, dur, parent=None, rid=None,
+                 span_id=None, **args):
         """Record a span with explicit start/duration (seconds) — for
-        components that own their clock (MicroBatcher's ManualClock)."""
-        self.trace.add(name, cat, ts, dur, args)
+        components that own their clock (MicroBatcher's ManualClock,
+        the sequence schedulers), which also name its ``parent`` and
+        ``rid`` themselves. Returns the span's id."""
+        return self.trace.add(name, cat, ts, dur, args, parent=parent,
+                              rid=rid, span_id=span_id)
 
-    def event(self, name, cat="", **args):
-        """Record an instant event (Chrome ph 'i') at now."""
-        self.trace.add(name, cat, self.clock(), 0.0, args, ph="i")
+    def event(self, name, cat="", ts=None, rid=None, **args):
+        """Record an instant event (Chrome ph 'i') at `ts`, default
+        now on this registry's clock."""
+        return self.trace.add(name, cat,
+                              self.clock() if ts is None else ts, 0.0,
+                              args, ph="i", rid=rid)
 
     # -- export ----------------------------------------------------------
     def snapshot(self):
@@ -552,7 +608,11 @@ class MetricsRegistry:
         for s in self.trace.spans():
             ev = {"name": s["name"], "cat": s["cat"] or "default",
                   "ph": s["ph"], "ts": s["ts"] * 1e6,
-                  "pid": s["pid"], "tid": s["tid"], "args": s["args"]}
+                  "pid": s["pid"], "tid": s["tid"], "args": s["args"],
+                  "id": s["id"]}
+            for k in ("parent", "rid"):
+                if s[k] is not None:
+                    ev[k] = s[k]
             if s["ph"] == "X":
                 ev["dur"] = s["dur"] * 1e6
             else:
@@ -569,15 +629,6 @@ class MetricsRegistry:
         os.replace(tmp, path)
         return path
 
-    def export_jsonl(self, path):
-        """One JSON object per span, oldest first; returns the path."""
-        tmp = f"{path}.tmp"
-        with open(tmp, "w") as fh:  # fault-ok[FLT02]: observability export, off every dispatch path — same contract as export_chrome_trace above
-            for s in self.trace.spans():
-                fh.write(json.dumps(s) + "\n")
-        os.replace(tmp, path)
-        return path
-
 
 # ----------------------------------------------------------------------
 # the process-wide default registry
@@ -590,3 +641,22 @@ def get_registry() -> MetricsRegistry:
     Its identity is stable for the process lifetime — cache instrument
     handles freely; registry.reset() zeroes values in place."""
     return _REGISTRY
+
+
+@contextlib.contextmanager
+def phase(name):
+    """One phase of set-up (weights_init, warm): a span
+    ``setup.<name>`` on the process-wide registry whose duration is also
+    added to the counter ``dl4j_setup_seconds{phase}``. A counter
+    because a benchmark clears the ring when its window opens; labelled
+    by phase and not by model so that closing a model leaves it."""
+    reg = _REGISTRY
+    t0 = reg.clock()
+    try:
+        with reg.span(f"setup.{name}", "setup"):
+            yield
+    finally:
+        reg.counter("dl4j_setup_seconds",
+                    "host seconds spent in each phase of set-up",
+                    labels=("phase",)).labels(phase=name).inc(
+                        reg.clock() - t0)

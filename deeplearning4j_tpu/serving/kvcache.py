@@ -96,6 +96,7 @@ class PagedKVCache:
         self.k_pools = jnp.zeros(shape, self.dtype)
         self.v_pools = jnp.zeros(shape, self.dtype)
         self._free = deque(range(1, self.num_pages))
+        self._peak = 0                    # most pages in use, see below
         self._ref = np.zeros((self.num_pages,), np.int32)
         self._ref[0] = 1                  # the null page, pinned
         #: prompt-token tuple -> list of page ids, LRU order
@@ -119,6 +120,15 @@ class PagedKVCache:
     def pages_in_use(self):
         """Allocated pages (null page excluded)."""
         return self.num_pages - 1 - len(self._free)
+
+    def take_pages_peak(self):
+        """Most pages in use since the last call (pages only rise in
+        alloc(), which counts it) — the scheduler reads it once an
+        iteration, so a peak between two outside polls of the gauge is
+        not lost."""
+        peak = self._peak
+        self._peak = self.pages_in_use    # where the next count starts
+        return peak
 
     @property
     def capacity(self):
@@ -159,6 +169,7 @@ class PagedKVCache:
         pages = [self._free.popleft() for _ in range(n)]
         for p in pages:
             self._ref[p] = 1
+        self._peak = max(self._peak, self.pages_in_use)
         self._g_in_use.set(self.pages_in_use)
         return pages
 

@@ -60,7 +60,6 @@ from __future__ import annotations
 
 import itertools
 import threading
-import time
 from collections import deque
 
 import numpy as np
@@ -164,6 +163,27 @@ def _seq_metrics(reg, name):
             labels=("model",),
             buckets=(0.25, 0.5, 0.75, 1.0)).labels(**lab),
     }
+
+
+def _wait_for_work(sched):
+    """Block a scheduler's background loop until something is queued or
+    active (True), or the scheduler is closed with nothing left (False).
+    An idle period — from the first wait that found nothing to the
+    wake-up that finds work, or to close() — is ONE ``sequence.idle``
+    span, not one per 50 ms poll: the device idle for want of demand
+    reads apart from the device idle with work pending."""
+    idle_since = None
+    with sched._cond:
+        while not sched._pending and not sched._active \
+                and not sched._closed:
+            if idle_since is None:
+                idle_since = sched.clock()
+            sched._cond.wait(0.05)
+        work = bool(sched._pending or sched._active)
+    if idle_since is not None:
+        sched._registry.add_span("sequence.idle", "serving", idle_since,
+                                 sched.clock() - idle_since)
+    return work
 
 
 def greedy_onehot_feedback(vocab):
@@ -290,7 +310,10 @@ class SequenceScheduler:
         self.queue_limit = int(queue_limit)
         self.admission = admission
         self.feedback = feedback
-        self.clock = clock if clock is not None else time.monotonic
+        # one clock for every program span: the registry's, unless a
+        # test injects its own (docs/OBSERVABILITY.md "One clock")
+        self.clock = clock if clock is not None \
+            else telemetry.get_registry().clock
         it = model.conf.inputType
         #: per-step feature width the submit contract validates
         self.feature_size = int(it.size)
@@ -581,14 +604,7 @@ class SequenceScheduler:
         return self
 
     def _loop(self):
-        while True:
-            with self._cond:
-                if self._closed and not self._pending \
-                        and not self._active:
-                    return
-                if not self._pending and not self._active:
-                    self._cond.wait(0.05)
-                    continue
+        while _wait_for_work(self):
             try:
                 self._step_once()
             except Exception as e:
@@ -635,6 +651,7 @@ class SequenceScheduler:
         docs/SERVING.md)."""
         return occupancy_summary_from(self.occupancy, "mean_live_slots")
 
+    @telemetry.phase("warm")
     def warm(self, cache=None):
         """Precompile the decode-step executable for EVERY slot bucket
         (hits are free) so a serving process steps its first sequence
@@ -701,10 +718,21 @@ class GenerationRequest:
     tokens have been sampled. ``pages``/``block_row``/``seq_len`` are
     the slot's KV state (owned page ids, logical-block -> physical-page
     row, live KV rows). ``wait`` follows the serving tier's one release
-    contract — see ``queue.InferenceRequest.wait``."""
+    contract — see ``queue.InferenceRequest.wait``.
+
+    The request's timeline, seconds on the scheduler's clock, always
+    set (telemetry on or off) and part of the API: ``enqueued_at``
+    (submit), ``started_at`` (a slot was granted), ``first_chunk_at``
+    (its first prompt chunk was dispatched, or its prompt was adopted
+    whole), ``first_token_at``, ``token_times`` (one per sampled token,
+    the first included) and ``finished_at`` (done or failed). Time to
+    first token is ``first_token_at - enqueued_at``; the gaps between
+    tokens are the differences of ``token_times``."""
 
     __slots__ = ("tokens", "max_new", "sampler", "rng", "stream_id",
-                 "enqueued_at", "deadline", "started_at", "prefilled",
+                 "enqueued_at", "deadline", "started_at",
+                 "first_chunk_at", "first_token_at", "token_times",
+                 "finished_at", "chunks", "prefilled",
                  "seq_len", "pages", "block_row", "out_tokens",
                  "logits_rows", "logits", "result", "error", "_event")
 
@@ -718,6 +746,11 @@ class GenerationRequest:
         self.enqueued_at = float(enqueued_at)
         self.deadline = None if deadline is None else float(deadline)
         self.started_at = None
+        self.first_chunk_at = None
+        self.first_token_at = None
+        self.token_times = []               # one clock read a token
+        self.finished_at = None
+        self.chunks = 0                     # prompt chunks dispatched
         self.prefilled = 0                  # prompt tokens with KV live
         self.seq_len = 0                    # total KV rows live
         self.pages = []                     # owned page ids (in order)
@@ -775,6 +808,15 @@ class PagedSequenceScheduler:
     per-request ``stream_rng(sampler_seed, stream_id)`` stream, stream
     ids assigned in submit order — deterministic per (seed, stream), so
     the bitwise-vs-serial gate holds with temperature sampling too.
+
+    Spans (cat ``serving``, on this scheduler's clock; the tree is in
+    docs/OBSERVABILITY.md): every iteration that found work is one
+    ``sequence.iteration`` whose children are ``sequence.admit``,
+    ``sequence.prefill`` and ``sequence.prefill_finish`` (rid = the
+    request's ``stream_id``), ``sequence.decode_prep``,
+    ``sequence.step`` (child ``sequence.fetch``) and
+    ``sequence.sample``; a request that ends, done or failed, leaves
+    one instant ``sequence.request`` with its whole timeline.
     """
 
     def __init__(self, model, *, num_pages, slot_buckets=None,
@@ -805,7 +847,10 @@ class PagedSequenceScheduler:
         self.sampler = sampler if sampler is not None else greedy_sampler()
         self.sampler_seed = int(sampler_seed)
         self.prefix_sharing = bool(prefix_sharing)
-        self.clock = clock if clock is not None else time.monotonic
+        # one clock for every program span: the registry's, unless a
+        # test injects its own (docs/OBSERVABILITY.md "One clock")
+        self.clock = clock if clock is not None \
+            else telemetry.get_registry().clock
         self.name = str(name) if name else f"seq{next(_SCHED_SEQ)}"
         self.cache = PagedKVCache(
             n_layers=model.n_layers, n_heads=model.n_heads,
@@ -896,12 +941,37 @@ class PagedSequenceScheduler:
             self.cache.release(req.pages)
             req.pages = []
 
+    def _end_req(self, req, exc=None):
+        """The one place a request ends, done (exc None) or failed:
+        stamp ``finished_at``, leave its timeline as one instant
+        ``sequence.request`` and release its waiter. An instant and not
+        a span as long as the request: such a span would cover every
+        device-idle gap of a busy scheduler and take, in an idle-gap
+        attribution, the time that belongs to no span."""
+        req.finished_at = now = self.clock()
+        self._registry.event(
+            "sequence.request", "serving", ts=now, rid=req.stream_id,
+            prompt_tokens=int(req.tokens.shape[0]),
+            new_tokens=len(req.out_tokens), chunks=req.chunks,
+            enqueued_at=req.enqueued_at, started_at=req.started_at,
+            first_chunk_at=req.first_chunk_at,
+            first_token_at=req.first_token_at, finished_at=now,
+            token_times=tuple(req.token_times),
+            error=None if exc is None else type(exc).__name__)
+        if exc is None:
+            req.finish(np.asarray(req.out_tokens, np.int64))
+        else:
+            req.fail(exc)
+
     def _expire_locked(self, now):
+        """Fail what is past its deadline, queued or mid-flight;
+        returns how many."""
         keep = deque()
+        n = len(self._pending) + len(self._active)
         for req in self._pending:
             if req.deadline is not None and now >= req.deadline:
                 self._m["expired"].inc()
-                req.fail(DeadlineExceededError(
+                self._end_req(req, DeadlineExceededError(
                     f"deadline passed {now - req.deadline:.3f}s before "
                     "a slot was granted"))
             else:
@@ -912,7 +982,7 @@ class PagedSequenceScheduler:
             if req.deadline is not None and now >= req.deadline:
                 self._m["expired"].inc()
                 self._release_req(req)
-                req.fail(DeadlineExceededError(
+                self._end_req(req, DeadlineExceededError(
                     f"deadline passed at {len(req.out_tokens)}/"
                     f"{req.max_new} tokens — slot released "
                     "mid-generation"))
@@ -921,20 +991,24 @@ class PagedSequenceScheduler:
         self._active = live
         self._m["depth"].set(len(self._pending))
         self._m["active"].set(len(self._active))
+        return n - len(keep) - len(live)
 
     def _refill_locked(self, now):
         """Admit queued prompts into free KV slots; prefix sharing
         adopts registered pages copy-on-write here. An exact-prompt
         adoption may complete the prompt outright — its first token is
-        sampled from the registered logits (returned for the caller to
-        process OUTSIDE this lock)."""
+        sampled from the registered logits. Returns (how many were
+        admitted, those adoptions for the caller to process OUTSIDE
+        this lock)."""
         adopted_done = []
         if self.admission == "gang" and self._active:
-            return adopted_done
+            return 0, adopted_done
         midrun = any(r.seq_len > 0 for r in self._active)
+        admitted = 0
         while self._pending and len(self._active) < self.max_slots:
             req = self._pending.popleft()
             req.started_at = now
+            admitted += 1
             req.block_row = np.zeros((self._mp,), np.int32)
             logits = None
             if self.prefix_sharing:
@@ -949,10 +1023,11 @@ class PagedSequenceScheduler:
             if midrun:
                 self._m["refills"].inc()
             if logits is not None:
+                req.first_chunk_at = now    # adopted whole: no chunk
                 adopted_done.append((req, logits))
         self._m["depth"].set(len(self._pending))
         self._m["active"].set(len(self._active))
-        return adopted_done
+        return admitted, adopted_done
 
     def bucket_for(self, n):
         """Smallest slot bucket >= n live slots."""
@@ -966,7 +1041,7 @@ class PagedSequenceScheduler:
         self._release_req(req)
         with self._cond:
             self._m["errors"].inc()
-            req.fail(exc)
+            self._end_req(req, exc)
             self._active = [r for r in self._active if r is not req]
             self._m["active"].set(len(self._active))
 
@@ -977,6 +1052,8 @@ class PagedSequenceScheduler:
         row = np.asarray(last_logits, np.float32)
         req.logits_rows.append(row)
         req.out_tokens.append(int(req.sampler(row, req.rng)))
+        req.first_token_at = self.clock()
+        req.token_times.append(req.first_token_at)
         if len(req.out_tokens) >= req.max_new:
             self._finish_req(req)
             return True
@@ -988,15 +1065,16 @@ class PagedSequenceScheduler:
             self._active = [r for r in self._active if r is not req]
             self._m["completed"].inc()
             self._m["active"].set(len(self._active))
-        req.finish(np.asarray(req.out_tokens, np.int64))
+        self._end_req(req)
 
-    def _prefill_one(self, req):
+    def _prefill_one(self, req, parent=None):
         """Dispatch ONE page-sized prompt chunk for one slot: allocate
         the chunk's page, append its K/V, attend causally over the
         table so far. Completing the prompt registers it for prefix
         sharing and samples the first token. Returns True on progress;
         a pool-exhausted or chaos-injected failure fails THIS request
-        only (typed, 429 at the HTTP tier)."""
+        only (typed, 429 at the HTTP tier). `parent` is the id of the
+        iteration's span."""
         import jax.numpy as jnp
 
         page = self.model.page_size
@@ -1004,6 +1082,9 @@ class PagedSequenceScheduler:
         t0 = req.prefilled
         n_valid = min(page, T - t0)
         t0c = self.clock()
+        if req.first_chunk_at is None:
+            req.first_chunk_at = t0c
+        req.chunks += 1
         try:
             pg = self.cache.alloc(1)[0]
             req.pages.append(pg)
@@ -1022,17 +1103,23 @@ class PagedSequenceScheduler:
             self._fail_req(req, e)
             return True                     # progress: the slot freed
         finally:
+            t1c = self.clock()
             self._registry.add_span(
-                "sequence.prefill", "serving", t0c,
-                self.clock() - t0c, model=self.name, chunk=n_valid)
+                "sequence.prefill", "serving", t0c, t1c - t0c,
+                parent=parent, rid=req.stream_id, model=self.name,
+                chunk=n_valid)
         req.prefilled += n_valid
         req.seq_len = req.prefilled
         self.prefill_chunks += 1
         if req.prefilled >= T:
+            # the fetch waits out the chunk on the device
             last = np.asarray(logits)
             if self.prefix_sharing:
                 self.cache.register_prefix(req.tokens, req.pages, last)
             self._complete_prompt(req, last)
+            self._registry.add_span(
+                "sequence.prefill_finish", "serving", t1c,
+                self.clock() - t1c, parent=parent, rid=req.stream_id)
         return True
 
     def _staging_for(self, S):
@@ -1048,13 +1135,14 @@ class PagedSequenceScheduler:
             self.staging_reuse_bytes += sum(a.nbytes for a in st)
         return st
 
-    def _decode_batch(self, batch):
+    def _decode_batch(self, batch, parent=None):
         """One slot-batched decode step over every fully-prefilled
         slot: per-slot page prep (CoW fork / fresh page at a page
         boundary — a pool-exhausted slot fails alone), padded gather,
-        ONE dispatch, scatter + sample."""
-        import jax.numpy as jnp
-
+        ONE dispatch, scatter + sample. `parent` is the id of the
+        iteration's span."""
+        reg = self._registry
+        t_prep = self.clock()
         ready = []
         for req in batch:
             try:
@@ -1087,6 +1175,9 @@ class PagedSequenceScheduler:
         sls[n:] = 0
         bts[n:] = 0
         t0c = self.clock()
+        reg.add_span("sequence.decode_prep", "serving", t_prep,
+                     t0c - t_prep, parent=parent, slots=n)
+        step_id = reg.new_span_id()
         self._m["dispatches"].inc()
         self._m["slot_steps"].inc(n)
         self._m["occupancy"].observe(n / S)
@@ -1097,21 +1188,27 @@ class PagedSequenceScheduler:
                 self.model._params, tok, self.cache.k_pools,
                 self.cache.v_pools, bts, sls)
             self.cache.k_pools, self.cache.v_pools = kps, vps
-            out = np.asarray(out)
+            t_f = self.clock()
+            out = np.asarray(out)       # waits out the step, then copies
+            reg.add_span("sequence.fetch", "serving", t_f,
+                         self.clock() - t_f, parent=step_id,
+                         bytes=out.nbytes)
         except Exception as e:
             with self._cond:
                 self._m["errors"].inc(len(ready))
                 for req in ready:
                     self._release_req(req)
-                    req.fail(e)
+                    self._end_req(req, e)
                 self._active = [r for r in self._active
                                 if r not in ready]
                 self._m["active"].set(len(self._active))
             return 0
         finally:
-            self._registry.add_span(
-                "sequence.step", "serving", t0c, self.clock() - t0c,
-                model=self.name, slots=n, bucket=S)
+            t_s = self.clock()
+            reg.add_span(
+                "sequence.step", "serving", t0c, t_s - t0c,
+                parent=parent, span_id=step_id, model=self.name,
+                slots=n, bucket=S)
         finished = []
         for i, req in enumerate(ready):
             if req.done:                # expired between gather + now
@@ -1120,10 +1217,14 @@ class PagedSequenceScheduler:
             row = out[i].astype(np.float32, copy=False)
             req.logits_rows.append(row)
             req.out_tokens.append(int(req.sampler(row, req.rng)))
+            req.token_times.append(self.clock())
             if len(req.out_tokens) >= req.max_new:
                 finished.append(req)
         for req in finished:
             self._finish_req(req)
+        reg.add_span("sequence.sample", "serving", t_s,
+                     self.clock() - t_s, parent=parent, slots=n,
+                     finished=len(finished))
         return n
 
     def _step_once(self):
@@ -1133,30 +1234,43 @@ class PagedSequenceScheduler:
     def _iterate_locked(self):
         """One iteration: expire -> refill (prefix adoption) -> at most
         ONE prefill chunk -> one slot-batched decode step. Returns the
-        progress count (0 = idle)."""
+        progress count (0 = idle). An iteration that found anything to
+        do is one ``sequence.iteration`` span, its parts the children
+        (class docstring)."""
+        reg = self._registry
+        it_id = reg.new_span_id()
+        t_it = self.clock()
         with self._cond:
             now = self.clock()
-            self._expire_locked(now)
-            adopted = self._refill_locked(now)
+            expired = self._expire_locked(now)
+            admitted, adopted = self._refill_locked(now)
         progress = 0
         for req, logits in adopted:       # exact-prefix admissions
             self._complete_prompt(req, logits)
             progress += 1
         with self._cond:
             batch = list(self._active)
-        if not batch:
-            return progress
+            pending = len(self._pending)
+        if not (batch or admitted or expired):
+            return progress               # an empty poll: no span
+        reg.add_span("sequence.admit", "serving", t_it,
+                     self.clock() - t_it, parent=it_id,
+                     admitted=admitted, adopted=len(adopted))
         pre = next((r for r in batch
                     if not r.done and r.prefilled < r.tokens.shape[0]),
                    None)
         if pre is not None:
-            self._prefill_one(pre)
+            self._prefill_one(pre, it_id)
             progress += 1
         decode = [r for r in batch
                   if not r.done and r.prefilled >= r.tokens.shape[0]]
-        if decode:
-            progress += self._decode_batch(decode)
-        return progress
+        slots = self._decode_batch(decode, it_id) if decode else 0
+        reg.add_span("sequence.iteration", "serving", t_it,
+                     self.clock() - t_it, span_id=it_id,
+                     active=len(batch), pending=pending,
+                     pages_in_use=self.cache.take_pages_peak(),
+                     prefill=int(pre is not None), decode_slots=slots)
+        return progress + slots
 
     # -- drivers --------------------------------------------------------
     def poll(self):
@@ -1171,17 +1285,13 @@ class PagedSequenceScheduler:
         return self
 
     def _loop(self):
-        while True:
-            with self._cond:
-                if self._closed and not self._pending \
-                        and not self._active:
-                    return
-                if not self._pending and not self._active:
-                    self._cond.wait(0.05)
-                    continue
+        while _wait_for_work(self):
             try:
                 self._step_once()
             except Exception as e:
+                # defensive: an unexpected scheduler bug must release
+                # every waiter, never leave them blocked on a dead
+                # thread; the loop stays up for new submits
                 self._fail_all(e)
 
     def _fail_all(self, exc):
@@ -1190,10 +1300,10 @@ class PagedSequenceScheduler:
             if n:
                 self._m["errors"].inc(n)
             while self._pending:
-                self._pending.popleft().fail(exc)
+                self._end_req(self._pending.popleft(), exc)
             for req in self._active:
                 self._release_req(req)
-                req.fail(exc)
+                self._end_req(req, exc)
             self._active = []
             self._m["depth"].set(0)
             self._m["active"].set(0)
@@ -1217,6 +1327,7 @@ class PagedSequenceScheduler:
     def occupancy_summary(self):
         return occupancy_summary_from(self.occupancy, "mean_live_slots")
 
+    @telemetry.phase("warm")
     def warm(self, cache=None):
         """Precompile the decode executable for EVERY slot bucket plus
         the (bucket-independent) prefill chunk executable, so a serving
@@ -1256,12 +1367,13 @@ class PagedSequenceScheduler:
             self._closed = True
             if not drain:
                 while self._pending:
-                    self._pending.popleft().fail(
+                    self._end_req(
+                        self._pending.popleft(),
                         ServingClosedError("scheduler closed before "
                                            "a slot was granted"))
                 for req in self._active:
                     self._release_req(req)
-                    req.fail(ServingClosedError(
+                    self._end_req(req, ServingClosedError(
                         "scheduler closed mid-generation"))
                 self._active = []
                 self._m["depth"].set(0)

@@ -131,34 +131,6 @@ class OpProfiler:
 # read it from the compiled executable instead of re-deriving per-op)
 # ----------------------------------------------------------------------
 
-#: bf16 peak FLOP/s per chip, keyed by the EXACT ``device_kind`` JAX
-#: reports, each with its source. A kind that is not here is an error,
-#: not a default: a substring match would price a v5p at the v5e's peak.
-_PEAK_BF16_FLOPS = {
-    # Google Cloud documentation, "TPU v5e": 197 TFLOP/s bf16 per chip;
-    # the kind string is what chip_smoke.py prints on the v5e
-    "TPU v5 lite": 197e12,
-}
-
-
-def device_peak_flops(device=None) -> float:
-    """Per-chip peak bf16 FLOP/s for the given (default: first) device.
-    0.0 on the CPU platform (test meshes have no peak to speak of); an
-    accelerator whose device_kind is not in the table raises."""
-    import jax
-
-    d = device or jax.devices()[0]
-    if d.platform == "cpu":
-        return 0.0
-    try:
-        return _PEAK_BF16_FLOPS[d.device_kind]
-    except KeyError:
-        raise ValueError(
-            f"no bf16 peak known for device_kind {d.device_kind!r} "
-            f"(platform {d.platform!r}): add it to "
-            "util/profiler.py::_PEAK_BF16_FLOPS with its source") from None
-
-
 def compiled_cost(fn, *args, **kwargs) -> dict:
     """FLOPs + HBM bytes of one call of `fn(*args, **kwargs)` as XLA
     compiled it: {'flops': float, 'bytes_accessed': float}. `fn` may
@@ -169,16 +141,6 @@ def compiled_cost(fn, *args, **kwargs) -> dict:
     ca = jitted.lower(*args, **kwargs).compile().cost_analysis() or {}
     return {"flops": float(ca.get("flops", 0.0)),
             "bytes_accessed": float(ca.get("bytes accessed", 0.0))}
-
-
-def mfu(flops_per_step: float, step_time_s: float, device=None) -> float:
-    """Model FLOP utilization: achieved FLOP/s over the chip's bf16 peak.
-    0.0 on the CPU platform; raises for an accelerator with no known
-    peak (device_peak_flops)."""
-    peak = device_peak_flops(device)
-    if not peak or step_time_s <= 0:
-        return 0.0
-    return flops_per_step / step_time_s / peak
 
 
 @contextlib.contextmanager

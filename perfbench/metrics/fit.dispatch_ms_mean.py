@@ -1,0 +1,15 @@
+"""Layer: trainers. Source: program_span (`train.dispatch`,
+nn/multilayer.py::traced_train_step: the jitted train step's call until
+it returns its futures; child of `train.step`). Mean over the steps of
+the window. None where the ring dropped spans. Moves:
+train_samples_per_s_per_chip."""
+
+from deeplearning4j_tpu.runtime import telemetry
+from perfbench.stats import mean
+
+
+def read(run):
+    if telemetry.get_registry().trace.dropped:
+        return None
+    spans = run.program_spans("train.dispatch")
+    return 1e3 * mean(s["dur"] for s in spans) if spans else None

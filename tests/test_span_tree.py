@@ -1,0 +1,580 @@
+"""Spans with a cause and a request (runtime/telemetry.py, ISSUE 26).
+
+What must hold:
+
+- nested ``span()`` blocks set ``parent`` by themselves, one stack per
+  thread; ``add_span(parent=, rid=, span_id=)`` takes them explicitly;
+  the ring counts what it drops and ``clear()`` forgets the count;
+- a paged scheduler leaves, for every iteration that found work, the
+  tree of docs/OBSERVABILITY.md, and for every request that ends, done
+  or failed, one instant ``sequence.request`` with its timeline; the
+  request's timestamps are set with telemetry off too;
+- an idle scheduler loop is ONE ``sequence.idle`` per idle period;
+- both schedulers default to the registry's clock;
+- ``fit(iterator)`` records one of each trainer span a step, children
+  inside parents, and compiles nothing more for it;
+- ``telemetry.phase`` feeds ``dl4j_setup_seconds{phase}``, which
+  survives ``trace.clear()`` and ``host.close()``.
+"""
+
+import threading
+import time
+
+import numpy as np
+import pytest
+
+from deeplearning4j_tpu.nn.transformer import CausalTransformerLM
+from deeplearning4j_tpu.runtime import telemetry
+from deeplearning4j_tpu.runtime.telemetry import MetricsRegistry
+from deeplearning4j_tpu.serving import (
+    DeadlineExceededError, KVCacheFullError, ManualClock, ModelHost,
+    PagedSequenceScheduler, SequenceScheduler, ServingClosedError,
+)
+
+
+class TickClock(ManualClock):
+    """Every read moves the clock on by one tick, so that intervals taken
+    from it nest strictly and repeat exactly."""
+
+    def __call__(self):
+        self.now += 0.001
+        return self.now
+
+
+def _lm(**kw):
+    return CausalTransformerLM(vocab=23, d_model=32, n_heads=2, n_layers=2,
+                               max_context=64, page_size=8, seed=3, **kw)
+
+
+def _prompt(n, vocab=23, seed=0):
+    return np.random.default_rng(seed).integers(0, vocab, n).astype(np.int32)
+
+
+def _paged(model, clock=None, **kw):
+    kw.setdefault("num_pages", 48)
+    kw.setdefault("slot_buckets", (2,))
+    kw.setdefault("prefix_sharing", False)
+    kw.setdefault("start_thread", False)
+    return PagedSequenceScheduler(model, clock=clock or TickClock(), **kw)
+
+
+@pytest.fixture
+def ring():
+    """The process-wide ring, emptied before and after."""
+    trace = telemetry.get_registry().trace
+    trace.clear()
+    yield trace
+    trace.clear()
+
+
+def _by_name(spans):
+    out = {}
+    for s in spans:
+        out.setdefault(s["name"], []).append(s)
+    return out
+
+
+def _inside(child, parent):
+    return (parent["ts"] <= child["ts"]
+            and child["ts"] + child["dur"] <= parent["ts"] + parent["dur"])
+
+
+# ----------------------------------------------------------------------
+# what a span records
+# ----------------------------------------------------------------------
+class TestSpanRecord:
+    def test_nested_span_sets_parent(self):
+        reg = MetricsRegistry(clock=TickClock())
+        with reg.span("outer", "c"):
+            with reg.span("inner", "c", rid=7, k=1):
+                assert reg.current_span_id() is not None
+            with reg.span("second", "c"):
+                pass
+        assert reg.current_span_id() is None
+        by = {s["name"]: s for s in reg.trace.spans()}
+        assert by["outer"]["parent"] is None
+        assert by["inner"]["parent"] == by["outer"]["id"]
+        assert by["second"]["parent"] == by["outer"]["id"]
+        assert by["inner"]["rid"] == 7 and by["inner"]["args"] == {"k": 1}
+        assert len({s["id"] for s in by.values()}) == 3
+        assert _inside(by["inner"], by["outer"])
+
+    def test_stack_unwinds_on_error(self):
+        reg = MetricsRegistry()
+        with pytest.raises(KeyError):
+            with reg.span("outer"):
+                with reg.span("inner"):
+                    raise KeyError("x")
+        assert reg.current_span_id() is None
+        assert [s["name"] for s in reg.trace.spans()] == ["inner", "outer"]
+
+    def test_threads_keep_separate_stacks(self):
+        reg = MetricsRegistry()
+        inside, release = threading.Event(), threading.Event()
+
+        def other():
+            with reg.span("other-thread"):
+                inside.set()
+                release.wait(5)
+
+        t = threading.Thread(target=other)
+        t.start()
+        assert inside.wait(5)
+        with reg.span("main-thread"):
+            pass                    # opened while the other is open
+        release.set()
+        t.join(5)
+        assert not t.is_alive()
+        by = {s["name"]: s for s in reg.trace.spans()}
+        assert by["main-thread"]["parent"] is None
+        assert by["other-thread"]["parent"] is None
+        assert by["main-thread"]["tid"] != by["other-thread"]["tid"]
+
+    def test_add_span_takes_parent_rid_and_a_drawn_id(self):
+        reg = MetricsRegistry()
+        pid = reg.new_span_id()
+        kid = reg.add_span("child", "c", 1.0, 0.5, parent=pid, rid=3, n=2)
+        got = reg.add_span("parent", "c", 0.5, 2.0, span_id=pid)
+        assert got == pid and kid != pid
+        child, parent = reg.trace.spans()
+        assert (child["parent"], child["rid"], child["args"]) == \
+            (pid, 3, {"n": 2})
+        assert (parent["id"], parent["parent"], parent["rid"]) == \
+            (pid, None, None)
+
+    def test_event_carries_rid_and_its_own_time(self):
+        reg = MetricsRegistry(clock=ManualClock(5.0))
+        reg.event("e", "c", ts=2.5, rid=9, why="x")
+        reg.event("now", "c")
+        a, b = reg.trace.spans()
+        assert (a["ph"], a["ts"], a["rid"], a["args"]) == \
+            ("i", 2.5, 9, {"why": "x"})
+        assert b["ts"] == 5.0 and b["rid"] is None
+
+    def test_ring_counts_what_it_drops_and_clear_forgets(self):
+        reg = MetricsRegistry(trace_capacity=4)
+        for k in range(10):
+            reg.add_span(f"s{k}", "c", float(k), 1.0)
+        assert reg.trace.dropped == 6
+        assert [s["name"] for s in reg.trace.spans()] == \
+            ["s6", "s7", "s8", "s9"]
+        reg.trace.clear()
+        assert reg.trace.dropped == 0 and reg.trace.spans() == []
+        assert MetricsRegistry().trace.capacity == 32768
+
+    def test_chrome_trace_carries_the_links(self):
+        reg = MetricsRegistry()
+        with reg.span("outer", rid=4):
+            with reg.span("inner"):
+                pass
+        inner, outer = reg.chrome_trace()["traceEvents"]
+        assert inner["parent"] == outer["id"] and outer["rid"] == 4
+        assert "parent" not in outer and "rid" not in inner
+
+    def test_disabled_records_nothing(self):
+        reg = MetricsRegistry()
+        telemetry.set_enabled(False)
+        try:
+            with reg.span("a"):
+                assert reg.current_span_id() is None
+            assert reg.add_span("b", "c", 0.0, 1.0) is None
+            reg.event("c")
+        finally:
+            telemetry.set_enabled(True)
+        assert reg.trace.spans() == []
+
+
+# ----------------------------------------------------------------------
+# the paged scheduler's tree and the request's timeline
+# ----------------------------------------------------------------------
+class TestPagedSchedulerSpans:
+    def test_one_prompt_three_chunks_four_tokens(self, ring):
+        """20 prompt tokens at a page of 8 are three chunks; the third
+        iteration finishes the prompt and decodes, three more decode."""
+        s = _paged(_lm())
+        req = s.submit(_prompt(20), max_new_tokens=4, wait=False)
+        s.drain()
+        assert req.wait(1.0).shape == (4,)
+        spans = ring.spans()
+        by = _by_name(spans)
+        its = by["sequence.iteration"]
+        assert len(its) == 5
+        assert all(i["parent"] is None and i["rid"] is None for i in its)
+        assert [i["args"]["prefill"] for i in its] == [1, 1, 1, 0, 0]
+        assert [i["args"]["decode_slots"] for i in its] == [0, 0, 1, 1, 1]
+        assert [i["args"]["pages_in_use"] for i in its] == [1, 2, 3, 3, 3]
+        assert all(i["args"]["active"] == 1 and i["args"]["pending"] == 0
+                   for i in its)
+        kids = {}
+        for sp in spans:
+            if sp["parent"] is not None:
+                kids.setdefault(sp["parent"], []).append(sp)
+        tree = [[k["name"] for k in sorted(kids[i["id"]],
+                                           key=lambda k: k["ts"])]
+                for i in its]
+        decode = ["sequence.decode_prep", "sequence.step",
+                  "sequence.sample"]
+        assert tree == [
+            ["sequence.admit", "sequence.prefill"],
+            ["sequence.admit", "sequence.prefill"],
+            ["sequence.admit", "sequence.prefill",
+             "sequence.prefill_finish"] + decode,
+            ["sequence.admit"] + decode,
+            ["sequence.admit"] + decode]
+        for i in its:
+            assert all(_inside(k, i) for k in kids[i["id"]])
+        for step in by["sequence.step"]:
+            (fetch,) = kids[step["id"]]
+            assert fetch["name"] == "sequence.fetch" and _inside(fetch, step)
+            assert fetch["args"]["bytes"] > 0
+            # the accepted readers' args, as before
+            assert step["args"] == {"model": s.name, "slots": 1,
+                                    "bucket": 2}
+        assert [p["args"]["chunk"] for p in by["sequence.prefill"]] == \
+            [8, 8, 4]
+        assert by["sequence.admit"][0]["args"] == {"admitted": 1,
+                                                   "adopted": 0}
+        assert [x["args"]["finished"] for x in by["sequence.sample"]] == \
+            [0, 0, 1]
+        # one rid on every span that belongs to the request
+        mine = [sp for sp in spans if sp["rid"] is not None]
+        assert {sp["rid"] for sp in mine} == {req.stream_id}
+        assert sorted(sp["name"] for sp in mine) == \
+            ["sequence.prefill"] * 3 + ["sequence.prefill_finish",
+                                        "sequence.request"]
+        s.close()
+
+    def test_request_timeline(self, ring):
+        s = _paged(_lm())
+        req = s.submit(_prompt(20), max_new_tokens=4, wait=False)
+        s.drain()
+        assert (req.enqueued_at <= req.started_at <= req.first_chunk_at
+                < req.first_token_at < req.finished_at)
+        assert len(req.token_times) == 4 and req.chunks == 3
+        assert req.token_times[0] == req.first_token_at
+        assert req.token_times == sorted(req.token_times)
+        assert req.token_times[-1] <= req.finished_at
+        (ev,) = _by_name(ring.spans())["sequence.request"]
+        assert ev["ph"] == "i" and ev["ts"] == req.finished_at
+        assert ev["args"] == {
+            "prompt_tokens": 20, "new_tokens": 4, "chunks": 3,
+            "enqueued_at": req.enqueued_at, "started_at": req.started_at,
+            "first_chunk_at": req.first_chunk_at,
+            "first_token_at": req.first_token_at,
+            "finished_at": req.finished_at,
+            "token_times": tuple(req.token_times), "error": None}
+        s.close()
+
+    def test_failed_request_still_leaves_its_event(self, ring):
+        """The pool runs dry mid-generation: the victim's event names
+        the error's class, the survivor's names none."""
+        s = _paged(_lm(), num_pages=5)
+        a = s.submit(_prompt(4, seed=1), max_new_tokens=14, wait=False)
+        b = s.submit(_prompt(4, seed=2), max_new_tokens=14, wait=False)
+        s.drain()
+        errors = {}
+        for r in (a, b):
+            try:
+                r.wait(1.0)
+                errors[r.stream_id] = None
+            except KVCacheFullError:
+                errors[r.stream_id] = "KVCacheFullError"
+        assert sorted(errors.values(), key=str) == ["KVCacheFullError",
+                                                    None]
+        events = _by_name(ring.spans())["sequence.request"]
+        assert {e["rid"]: e["args"]["error"] for e in events} == errors
+        victim = next(r for r in (a, b) if errors[r.stream_id])
+        assert victim.finished_at is not None
+        assert len(victim.token_times) == len(victim.out_tokens) < 14
+        s.close()
+
+    def test_expired_and_closed_requests_leave_events(self, ring):
+        clk = ManualClock()
+        s = _paged(_lm(), clock=clk)
+        late = s.submit(_prompt(4), max_new_tokens=2, deadline=1.0,
+                        wait=False)
+        clk.advance(2.0)
+        s.poll()
+        with pytest.raises(DeadlineExceededError):
+            late.wait(0.1)
+        cut = s.submit(_prompt(20), max_new_tokens=2, wait=False)
+        s.poll()                            # one chunk in, then closed
+        s.close(drain=False)
+        with pytest.raises(ServingClosedError):
+            cut.wait(0.1)
+        events = _by_name(ring.spans())["sequence.request"]
+        assert [(e["rid"], e["args"]["error"], e["args"]["chunks"])
+                for e in events] == [
+            (late.stream_id, "DeadlineExceededError", 0),
+            (cut.stream_id, "ServingClosedError", 1)]
+        assert late.first_chunk_at is None and late.finished_at == 2.0
+
+    def test_adopted_prompt_has_a_whole_timeline(self, ring):
+        """An exact-prefix adoption runs no chunk: first_chunk_at is the
+        grant, the first token comes in the admit."""
+        s = _paged(_lm(), prefix_sharing=True)
+        p = _prompt(16)
+        s.submit(p, max_new_tokens=1, wait=False)
+        s.drain()
+        ring.clear()
+        again = s.submit(p, max_new_tokens=1, wait=False)
+        s.drain()
+        assert again.chunks == 0
+        assert again.started_at == again.first_chunk_at \
+            < again.first_token_at <= again.finished_at
+        by = _by_name(ring.spans())
+        assert "sequence.prefill" not in by
+        assert by["sequence.admit"][0]["args"] == {"admitted": 1,
+                                                   "adopted": 1}
+        s.close()
+
+    def test_empty_poll_leaves_no_span(self, ring):
+        s = _paged(_lm())
+        ring.clear()                        # the set-up phases' spans
+        assert s.poll() == 0
+        assert ring.spans() == []
+        s.close()
+
+    def test_disabled_no_span_but_timestamps_set(self, ring):
+        s = _paged(_lm())
+        ring.clear()                        # the set-up phases' spans
+        telemetry.set_enabled(False)
+        try:
+            req = s.submit(_prompt(20), max_new_tokens=4, wait=False)
+            s.drain()
+        finally:
+            telemetry.set_enabled(True)
+        assert ring.spans() == []
+        assert (req.enqueued_at <= req.started_at <= req.first_chunk_at
+                < req.first_token_at < req.finished_at)
+        assert len(req.token_times) == 4
+        s.close()
+
+    def test_pages_peak_is_counted_where_pages_are_allotted(self):
+        s = _paged(_lm())
+        cache = s.cache
+        got = cache.alloc(3)
+        cache.release(got[:2])
+        assert cache.pages_in_use == 1
+        assert cache.take_pages_peak() == 3     # the peak, not the level
+        assert cache.take_pages_peak() == 1     # nothing allotted since
+        cache.release(got[2:])
+        s.close()
+
+
+# ----------------------------------------------------------------------
+# the idle loop and the clock
+# ----------------------------------------------------------------------
+def _carry_net():
+    from deeplearning4j_tpu.nn import (InputType, NeuralNetConfiguration,
+                                       RnnOutputLayer, Sgd)
+    from deeplearning4j_tpu.nn.conf.recurrent import LSTM
+    from deeplearning4j_tpu.nn.multilayer import MultiLayerNetwork
+
+    conf = (NeuralNetConfiguration.Builder().seed(5).updater(Sgd(0.1))
+            .list()
+            .layer(LSTM(nOut=6, activation="tanh"))
+            .layer(RnnOutputLayer(nOut=3, activation="softmax",
+                                  lossFunction="mcxent"))
+            .setInputType(InputType.recurrent(4)).build())
+    return MultiLayerNetwork(conf).init()
+
+
+def _make_scheduler(kind, **kw):
+    if kind == "paged":
+        return PagedSequenceScheduler(_lm(), num_pages=16,
+                                      slot_buckets=(2,), **kw)
+    return SequenceScheduler(_carry_net(), slot_buckets=(2,), **kw)
+
+
+class TestIdleAndClock:
+    @pytest.mark.parametrize("kind", ["paged", "carry"])
+    def test_default_clock_is_the_registrys(self, kind):
+        s = _make_scheduler(kind, start_thread=False)
+        assert s.clock is telemetry.get_registry().clock
+        s.close()
+        clk = ManualClock()
+        s = _make_scheduler(kind, start_thread=False, clock=clk)
+        assert s.clock is clk               # an injected clock wins
+        s.close()
+
+    @pytest.mark.parametrize("kind", ["paged", "carry"])
+    def test_idle_loop_is_one_span_per_idle_period(self, kind, ring):
+        s = _make_scheduler(kind, start_thread=True)
+        time.sleep(0.13)                    # more than two 50 ms polls
+        s.close()
+        by = _by_name(ring.spans())
+        (idle,) = by["sequence.idle"]
+        assert idle["dur"] >= 0.1 and idle["parent"] is None
+        assert "sequence.iteration" not in by
+
+    def test_work_ends_an_idle_period(self, ring):
+        s = _make_scheduler("paged", start_thread=True)
+        time.sleep(0.06)
+        s.submit(_prompt(4), max_new_tokens=2, wait=True, timeout=60.0)
+        s.close()
+        by = _by_name(ring.spans())
+        idles = sorted(by["sequence.idle"], key=lambda x: x["ts"])
+        assert 1 <= len(idles) <= 2         # before the request, after it
+        first_it = min(i["ts"] for i in by["sequence.iteration"])
+        assert idles[0]["ts"] + idles[0]["dur"] <= first_it
+        # the loop's clock is the registry's: the spans of two layers
+        # order on one axis
+        assert idles[0]["ts"] < first_it
+
+
+# ----------------------------------------------------------------------
+# trainers
+# ----------------------------------------------------------------------
+def _tiny_mln():
+    from deeplearning4j_tpu.nn import (DenseLayer, InputType,
+                                       NeuralNetConfiguration, Nesterovs,
+                                       OutputLayer)
+    from deeplearning4j_tpu.nn.multilayer import MultiLayerNetwork
+
+    conf = (NeuralNetConfiguration.Builder().seed(7)
+            .updater(Nesterovs(0.1, 0.9)).list()
+            .layer(DenseLayer(nOut=8, activation="relu"))
+            .layer(OutputLayer(nOut=4, activation="softmax",
+                               lossFunction="mcxent"))
+            .setInputType(InputType.feedForward(8)).build())
+    return MultiLayerNetwork(conf).init()
+
+
+def _tiny_graph():
+    from deeplearning4j_tpu.nn import (ComputationGraph, DenseLayer,
+                                       InputType, NeuralNetConfiguration,
+                                       Nesterovs, OutputLayer)
+
+    conf = (NeuralNetConfiguration.Builder().seed(7)
+            .updater(Nesterovs(0.1, 0.9)).graphBuilder()
+            .addInputs("in")
+            .addLayer("d", DenseLayer(nOut=8, activation="relu"), "in")
+            .addLayer("out", OutputLayer(nOut=4, activation="softmax",
+                                         lossFunction="mcxent"), "d")
+            .setOutputs("out")
+            .setInputTypes(InputType.feedForward(8)).build())
+    return ComputationGraph(conf).init()
+
+
+def _batches(n):
+    from deeplearning4j_tpu.data.dataset import DataSetIterator
+
+    rng = np.random.RandomState(0)
+    x = rng.randn(8 * n, 8).astype(np.float32)
+    y = np.eye(4, dtype=np.float32)[rng.randint(0, 4, 8 * n)]
+    return DataSetIterator(x, y, 8)
+
+
+TRAIN_SPANS = ("train.data_wait", "train.prepare", "train.step",
+               "train.dispatch", "train.sync", "train.listeners")
+
+
+class TestTrainerSpans:
+    @pytest.mark.parametrize("build", [_tiny_graph, _tiny_mln],
+                             ids=["graph", "multilayer"])
+    def test_fit_iterator_records_one_of_each_a_step(self, build, ring):
+        from deeplearning4j_tpu.analysis.retrace import RetraceSentinel
+
+        net = build()
+        seen = []
+        net._listeners.append(type("L", (), {
+            "iterationDone": lambda self, m, it, ep: seen.append(it)})())
+        sentinel = RetraceSentinel(max_compiles=1).install(net)
+        net.fit(_batches(3))
+        assert sentinel.compiles("train_step") == 1   # none added
+        assert seen == [1, 2, 3]
+        by = _by_name(ring.spans())
+        for name in TRAIN_SPANS:
+            assert [s["args"] for s in by[name]] == \
+                [{"iteration": k} for k in range(3)], name
+            assert all(s["cat"] == "train" for s in by[name])
+        for k in range(3):
+            step = by["train.step"][k]
+            disp, sync = by["train.dispatch"][k], by["train.sync"][k]
+            assert disp["parent"] == sync["parent"] == step["id"]
+            assert _inside(disp, step) and _inside(sync, step)
+            assert disp["ts"] == step["ts"]
+            assert disp["ts"] + disp["dur"] == sync["ts"]
+            assert sync["ts"] + sync["dur"] == pytest.approx(
+                step["ts"] + step["dur"])
+            for name in ("train.data_wait", "train.prepare", "train.step",
+                         "train.listeners"):
+                assert by[name][k]["parent"] is None
+            # in the order the host does them, none overlapping
+            order = [by[n][k] for n in ("train.data_wait", "train.prepare",
+                                        "train.step", "train.listeners")]
+            for a, b in zip(order, order[1:]):
+                assert a["ts"] + a["dur"] <= b["ts"]
+
+    def test_disabled_fit_records_no_span(self, ring):
+        net = _tiny_mln()
+        ring.clear()                        # init()'s setup.weights_init
+        telemetry.set_enabled(False)
+        try:
+            net.fit(_batches(2))
+        finally:
+            telemetry.set_enabled(True)
+        assert ring.spans() == [] and net._iteration == 2
+
+
+# ----------------------------------------------------------------------
+# set-up phases
+# ----------------------------------------------------------------------
+def _phase_seconds(phase):
+    fam = telemetry.get_registry().get("dl4j_setup_seconds")
+    child = None if fam is None else fam.labels_get(phase=phase)
+    return 0.0 if child is None else child.value
+
+
+class TestSetupPhases:
+    def test_phase_is_a_span_and_a_counter(self, ring):
+        before = _phase_seconds("unit_test_phase")
+        with telemetry.phase("unit_test_phase"):
+            inner = telemetry.get_registry().add_span(
+                "by-hand", "c", 0.0, 0.0,
+                parent=telemetry.get_registry().current_span_id())
+        assert inner is not None
+        by = {s["name"]: s for s in ring.spans()}
+        ph = by["setup.unit_test_phase"]
+        assert ph["cat"] == "setup" and by["by-hand"]["parent"] == ph["id"]
+        assert _phase_seconds("unit_test_phase") - before >= ph["dur"] > 0
+
+    @pytest.mark.parametrize("build,phase", [
+        (_tiny_graph, "weights_init"), (_tiny_mln, "weights_init"),
+        (_lm, "weights_init")], ids=["graph", "multilayer", "causal_lm"])
+    def test_construction_feeds_weights_init(self, build, phase, ring):
+        before = _phase_seconds(phase)
+        build()
+        assert _phase_seconds(phase) > before
+        assert "setup." + phase in _by_name(ring.spans())
+
+    def test_counter_survives_clear_and_close(self, ring):
+        from deeplearning4j_tpu.runtime import aot
+
+        before = {p: _phase_seconds(p)
+                  for p in ("weights_init", "warm")}
+        host = ModelHost()
+        prev, aot._SESSION = aot._SESSION, aot.ExecutableCache()
+        try:
+            host.register_sequence("lm", _lm(), slotBuckets=(2,),
+                                   numPages=16)
+        finally:
+            aot._SESSION = prev
+        after = {p: _phase_seconds(p) for p in before}
+        assert all(after[p] > before[p] for p in before), (before, after)
+        by = _by_name(ring.spans())
+        (warm,) = by["setup.warm"]
+        compiles = by["aot.compile"]      # a fresh cache: both compile
+        assert len(compiles) == 2
+        assert all(c["parent"] == warm["id"] for c in compiles)
+        ring.clear()
+        host.close()
+        assert {p: _phase_seconds(p) for p in before} == after
+        assert "dl4j_setup_seconds" in telemetry.get_registry().prometheus()
+
+    def test_trainer_warm_feeds_warm(self, ring):
+        before = _phase_seconds("warm")
+        _tiny_mln().precompile(batchSize=8, entries=("train",))
+        assert _phase_seconds("warm") > before
+        assert "setup.warm" in _by_name(ring.spans())

@@ -298,19 +298,6 @@ class TestTracing:
         assert all(isinstance(e[k], int) for e in evs
                    for k in ("pid", "tid"))
 
-    def test_jsonl_export(self, tmp_path):
-        reg = MetricsRegistry()
-        with reg.span("a"):
-            pass
-        with reg.span("b"):
-            pass
-        path = str(tmp_path / "trace.jsonl")
-        reg.export_jsonl(path)
-        with open(path) as fh:
-            recs = [json.loads(line) for line in fh]
-        assert [r["name"] for r in recs] == ["a", "b"]
-        assert all(r["dur"] >= 0 for r in recs)
-
     def test_ring_bound(self):
         reg = MetricsRegistry(trace_capacity=10)
         for k in range(25):
