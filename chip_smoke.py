@@ -12,10 +12,13 @@ One process, the public entry points, one model at full width:
             and the serving window must compile nothing
   kernels   flash_attention forward and jax.grad through it, COMPILED, at
             (B4,H8,T512,D64), (B4,H8,T8192,D64), (B2,H4,T4096,D128), bf16,
-            against the XLA references; each paged kernel once against
-            paged_attend
-  sequence  CausalTransformerLM (d_model 1024, 16 heads, 8 layers, vocab
-            32768, context 2048, page 16, bf16) behind
+            against the XLA references; each paged kernel, on the whole
+            pool with a layer index, against paged_attend at two shapes
+            (H16 Dh64 page 16; the benchmark's H16 Dh128 page 128)
+  sequence  CausalTransformerLM (d_model 2048, 16 heads, 8 layers, vocab
+            32768, context 2048, page 16, bf16: the paged kernels' shape
+            rule admits it), then one of d_model 1024 and 4 layers (head
+            size 64: the rule refuses it, paged_attend serves it), behind
             ModelHost.register_sequence + InferenceServer, HTTP :generate
             requests with shared prefixes; tokens must equal
             dense_serial_trajectory (greedy), zero steady-state compiles,
@@ -70,9 +73,21 @@ FULL = dict(
     buckets=(4, 8), request_rows=(1, 3, 4, 6),
     attn=((4, 8, 512, 64), (4, 8, 8192, 64), (2, 4, 4096, 128)),
     attn_block=512,
-    paged=dict(S=8, H=16, Dh=64, page=16, MP=128, P=256),
-    lm=dict(vocab=32768, d_model=1024, n_heads=16, n_layers=8,
-            max_context=2048, page_size=16, dtype="bfloat16"),
+    # heads and pages of the sequence leg's second model (the shape rule
+    # refuses them for serving; the kernels compile there all the same),
+    # then the benchmark's, which the rule admits
+    paged=(dict(L=2, S=8, H=16, Dh=64, page=16, MP=128, P=256),
+           dict(L=2, S=16, H=16, Dh=128, page=128, MP=16, P=160)),
+    # two served models and what the dispatcher must pick for each on the
+    # chip. Dh 128, 16 heads, page 16: a shape the paged kernels' rule
+    # admits, so the served tokens and the dense oracle both go through
+    # the kernels. Dh 64: a shape it refuses, served through paged_attend
+    # on the TPU backend, the branch a model of that head size gets
+    lm=(dict(vocab=32768, d_model=2048, n_heads=16, n_layers=8,
+             max_context=2048, page_size=16, dtype="bfloat16"),
+        dict(vocab=32768, d_model=1024, n_heads=16, n_layers=4,
+             max_context=2048, page_size=16, dtype="bfloat16")),
+    lm_attend=("pallas", "reference"),
     lm_pages=512, lm_prefix=40, lm_new=8,
     # loss after k steps on 4 chips against the one-chip run on the same
     # global batch, as a fraction of the STARTING loss: the dense psum
@@ -84,9 +99,10 @@ TINY = dict(
     classes=8, image=32, batch=16, fit_steps=4, steps_per_sync=2,
     buckets=(4, 8), request_rows=(1, 3, 4, 6),
     attn=((1, 2, 64, 16),), attn_block=16,
-    paged=dict(S=4, H=2, Dh=16, page=8, MP=8, P=48),
-    lm=dict(vocab=61, d_model=32, n_heads=2, n_layers=2,
-            max_context=64, page_size=8, dtype="float32"),
+    paged=(dict(L=2, S=4, H=2, Dh=16, page=8, MP=8, P=48),),
+    lm=(dict(vocab=61, d_model=32, n_heads=2, n_layers=2,
+             max_context=64, page_size=8, dtype="float32"),),
+    lm_attend=("reference",),
     lm_pages=48, lm_prefix=11, lm_new=4,
     # 4 rows per shard at 1x1 spatial: bf16 trajectories scatter, the
     # rehearsal only checks that they fall
@@ -342,13 +358,18 @@ class Smoke:
                     f"err {e_bwd:.1e} (tol {ATTN_GRAD_TOL})")
                 assert e_fwd <= ATTN_FWD_TOL and e_bwd <= ATTN_GRAD_TOL
 
-        # the paged kernels, once each, against the serving path's form
-        p = c["paged"]
-        S, H, Dh, page, MP, P = (p[n] for n in
-                                 ("S", "H", "Dh", "page", "MP", "P"))
+        # the paged kernels, once each, on the whole pool with a layer
+        # index, against the serving path's portable form on that layer
+        for p in c["paged"]:
+            self.paged_kernels(**p)
+        if self.rehearsal:
+            pa._INTERPRET = False
+
+    def paged_kernels(self, L, S, H, Dh, page, MP, P):
         dt = jnp.float32 if self.rehearsal else jnp.bfloat16
+        li = L - 1
         rng = np.random.default_rng(7)
-        kp, vp = (jnp.asarray(rng.standard_normal((P, page, H, Dh)), dt)
+        kp, vp = (jnp.asarray(rng.standard_normal((L, P, page, H, Dh)), dt)
                   for _ in range(2))
         q = jnp.asarray(rng.standard_normal((S, H, Dh)), dt)
         lens = rng.integers(1, MP * page, S).astype(np.int32)
@@ -362,8 +383,8 @@ class Smoke:
             bts[s, :n] = free[s * ((P - 1) // S):][:n]
         got = jax.jit(functools.partial(
             pa.paged_flash_decode, interpret=self.rehearsal))(
-            q, kp, vp, bts, lens)
-        want = pa.paged_attend(q[:, None], kp[bts], vp[bts],
+            q, kp, vp, bts, lens, layer=jnp.asarray(li, jnp.int32))
+        want = pa.paged_attend(q[:, None], kp[li][bts], vp[li][bts],
                                jnp.asarray(lens), jnp.asarray(lens) - 1)[:, 0]
         e_dec = rel_err(got, want)
         assert np.all(np.asarray(got[-1], np.float32) == 0)
@@ -372,21 +393,29 @@ class Smoke:
         qc = jnp.asarray(rng.standard_normal((page, H, Dh)), dt)
         got = jax.jit(functools.partial(
             pa.paged_flash_prefill, interpret=self.rehearsal))(
-            qc, kp, vp, bts[0], t0, n_valid)
+            qc, kp, vp, bts[0], t0, n_valid,
+            layer=jnp.asarray(li, jnp.int32))
         want = pa.paged_attend(
-            qc[None], kp[bts[0]][None], vp[bts[0]][None],
+            qc[None], kp[li][bts[0]][None], vp[li][bts[0]][None],
             jnp.asarray([t0 + n_valid]), jnp.asarray([t0]))[0]
         e_pre = rel_err(got[:n_valid], want[:n_valid])
-        log(f"paged_flash_decode S{S} H{H} Dh{Dh} page{page} MP{MP} vs "
-            f"paged_attend: err {e_dec:.1e}; paged_flash_prefill: err "
-            f"{e_pre:.1e} (tol {ATTN_FWD_TOL})")
+        rule = pa._paged_kernel_fits(page, H, Dh, jnp.dtype(dt).itemsize)
+        log(f"paged_flash_decode S{S} H{H} Dh{Dh} page{page} MP{MP} "
+            f"layer {li} of {L} (shape rule: {rule}) vs paged_attend: err "
+            f"{e_dec:.1e}; paged_flash_prefill: err {e_pre:.1e} "
+            f"(tol {ATTN_FWD_TOL})")
         assert e_dec <= ATTN_FWD_TOL and e_pre <= ATTN_FWD_TOL
-        if self.rehearsal:
-            pa._INTERPRET = False
 
     def sequence(self):
+        for lm, attend in zip(self.cfg["lm"], self.cfg["lm_attend"]):
+            self.serve_lm(lm, attend)
+
+    def serve_lm(self, lm, attend):
         c = self.cfg
-        model = CausalTransformerLM(seed=3, **c["lm"])
+        model = CausalTransformerLM(seed=3, **lm)
+        log(f"sequence model d_model {lm['d_model']} attends through "
+            f"{model.attend_impl()!r}")
+        assert model.attend_impl() == attend
         self.on_device(model._params, "LM params")
         rng = np.random.default_rng(11)
         prefix = rng.integers(0, model.vocab, c["lm_prefix"]).tolist()

@@ -17,19 +17,22 @@ is a pair of PURE step functions over an external paged KV cache
   rnnStepBatched discipline — warm every bucket, zero steady-state
   compiles).
 
-Attention goes through ``ops.pallas_attention.paged_attend`` on every
-backend, the TPU included — the page-sequential online-softmax twin of
-the pallas block-table kernels, with page_size as the block size, so it
-accumulates in the SAME block order as the dense flash kernel (the
-bitwise-parity contract tests/test_paged_attention.py gates in
-interpret mode). The pallas kernels themselves are not called from
-here yet (ROADMAP S4).
+Attention goes through ``ops.pallas_attention.paged_attention``, which
+chooses from the backend and the shapes alone (``attend_impl()`` says
+which): on the TPU, at shapes ``_paged_kernel_fits`` admits, the pallas
+block-table kernels, which fetch each live page of the pool into VMEM
+once and no other page; everywhere else ``paged_attend``, their
+page-sequential online-softmax twin on the gathered tables (the CPU
+path and the reference). Both take page_size as the block size and
+accumulate per head in the dense flash kernel's block order
+(tests/test_paged_attention.py gates the kernels in interpret mode).
 
 A DENSE-cache twin (``decode_dense``/``prefill_dense``: contiguous
 ``[L, S, max_context, H, Dh]`` slabs, the pre-paged shape) rides along
 as the bench A/B baseline and the serial-trajectory oracle: it views
-its slab as pages and runs the SAME attention core, so paged-vs-dense
-generation is bitwise comparable (``dense_serial_trajectory``).
+its slab as a pool under an identity block table and goes through the
+SAME dispatcher, so paged-vs-dense generation is bitwise comparable on
+one backend (``dense_serial_trajectory``).
 
 This is a serving twin, not a trainer: parameters are seeded at
 construction (pure ``numpy.random.default_rng``), there is no fit
@@ -114,6 +117,15 @@ class CausalTransformerLM:
             self._prefill_dense, entry="dense_prefill", fingerprint=fp,
             donate_argnums=pre_don)
 
+    def attend_impl(self):
+        """'pallas' or 'reference': which attention the step functions
+        trace on this backend (ops.pallas_attention.paged_attention)."""
+        from deeplearning4j_tpu.ops.pallas_attention import \
+            paged_attention_impl
+
+        return paged_attention_impl(self.page_size, self.n_heads,
+                                    self.head_dim, self._compute_dtype)
+
     def fingerprint(self):
         """Config hash for the AOT cache key (explicit: this twin has
         no conf JSON for network_fingerprint to derive from)."""
@@ -180,15 +192,15 @@ class CausalTransformerLM:
         h = params["emb"][tokens] + params["pos"][sls]
         pages = bts[jnp.arange(S), sls // self.page_size]
         offs = sls % self.page_size
-        from deeplearning4j_tpu.ops.pallas_attention import paged_attend
+        from deeplearning4j_tpu.ops.pallas_attention import paged_attention
 
         for li, lp in enumerate(params["layers"]):
             x = _rmsnorm(h, lp["ln1"])
             q, k, v = self._qkv(lp, x)
             kps = kps.at[li, pages, offs].set(k)
             vps = vps.at[li, pages, offs].set(v)
-            att = paged_attend(q[:, None], kps[li][bts], vps[li][bts],
-                               sls + 1, sls)[:, 0]
+            att = paged_attention(q[:, None], kps, vps, li, bts, sls + 1,
+                                  sls)[:, 0]
             h = h + att.reshape(S, self.d_model) @ lp["wo"]
             h = self._mlp(lp, h)
         return self._logits(params, h), kps, vps
@@ -210,15 +222,15 @@ class CausalTransformerLM:
         page_id = bt[t0 // self.page_size]
         L = jnp.reshape(t0 + n_valid, (1,))
         t0v = jnp.reshape(t0, (1,))
-        from deeplearning4j_tpu.ops.pallas_attention import paged_attend
+        from deeplearning4j_tpu.ops.pallas_attention import paged_attention
 
         for li, lp in enumerate(params["layers"]):
             x = _rmsnorm(h, lp["ln1"])
             q, k, v = self._qkv(lp, x)
             kps = kps.at[li, page_id].set(k)
             vps = vps.at[li, page_id].set(v)
-            att = paged_attend(q[None], kps[li][bt][None],
-                               vps[li][bt][None], L, t0v)[0]
+            att = paged_attention(q[None], kps, vps, li, bt[None], L,
+                                  t0v)[0]
             h = h + att.reshape(C, self.d_model) @ lp["wo"]
             h = self._mlp(lp, h)
         h_last = jax.lax.dynamic_index_in_dim(h, n_valid - 1, 0,
@@ -226,6 +238,18 @@ class CausalTransformerLM:
         return self._logits(params, h_last)[0], kps, vps
 
     # -- dense-cache twins (bench baseline + serial oracle) --------------
+    def _as_pool(self, slab):
+        """A dense slab [L, S, max_context, H, Dh] seen as a pool
+        [L, S*MP, page, H, Dh]: slot s's page j is pool page s*MP + j
+        (`_identity_tables`)."""
+        L, S = slab.shape[:2]
+        return slab.reshape(L, S * self.max_pages_per_slot, self.page_size,
+                            self.n_heads, self.head_dim)
+
+    def _identity_tables(self, S):
+        MP = self.max_pages_per_slot
+        return jnp.arange(S * MP, dtype=jnp.int32).reshape(S, MP)
+
     def _decode_dense(self, params, tokens, kcs, vcs, sls):
         """Dense-slab decode: kcs/vcs [L, S, max_context, H, Dh].
         Views the slab as pages and runs the SAME attention core, so
@@ -233,20 +257,17 @@ class CausalTransformerLM:
         S = tokens.shape[0]
         h = params["emb"][tokens] + params["pos"][sls]
         rows = jnp.arange(S)
-        from deeplearning4j_tpu.ops.pallas_attention import paged_attend
+        bts = self._identity_tables(S)
+        from deeplearning4j_tpu.ops.pallas_attention import paged_attention
 
         for li, lp in enumerate(params["layers"]):
             x = _rmsnorm(h, lp["ln1"])
             q, k, v = self._qkv(lp, x)
             kcs = kcs.at[li, rows, sls].set(k)
             vcs = vcs.at[li, rows, sls].set(v)
-            kpg = kcs[li].reshape(S, self.max_pages_per_slot,
-                                  self.page_size, self.n_heads,
-                                  self.head_dim)
-            vpg = vcs[li].reshape(S, self.max_pages_per_slot,
-                                  self.page_size, self.n_heads,
-                                  self.head_dim)
-            att = paged_attend(q[:, None], kpg, vpg, sls + 1, sls)[:, 0]
+            att = paged_attention(q[:, None], self._as_pool(kcs),
+                                  self._as_pool(vcs), li, bts, sls + 1,
+                                  sls)[:, 0]
             h = h + att.reshape(S, self.d_model) @ lp["wo"]
             h = self._mlp(lp, h)
         return self._logits(params, h), kcs, vcs
@@ -262,7 +283,9 @@ class CausalTransformerLM:
         h = params["emb"][tokens] + pos
         L = jnp.reshape(t0 + n_valid, (1,))
         t0v = jnp.reshape(t0, (1,))
-        from deeplearning4j_tpu.ops.pallas_attention import paged_attend
+        bt = jax.lax.dynamic_index_in_dim(
+            self._identity_tables(kcs.shape[1]), slot, 0, keepdims=True)
+        from deeplearning4j_tpu.ops.pallas_attention import paged_attention
 
         for li, lp in enumerate(params["layers"]):
             x = _rmsnorm(h, lp["ln1"])
@@ -272,15 +295,8 @@ class CausalTransformerLM:
                 kcs, k[None, None], (liv, slot, t0, zero, zero))
             vcs = jax.lax.dynamic_update_slice(
                 vcs, v[None, None], (liv, slot, t0, zero, zero))
-            kr = jax.lax.dynamic_index_in_dim(kcs[li], slot, 0,
-                                              keepdims=False)
-            vr = jax.lax.dynamic_index_in_dim(vcs[li], slot, 0,
-                                              keepdims=False)
-            kpg = kr.reshape(self.max_pages_per_slot, self.page_size,
-                             self.n_heads, self.head_dim)
-            vpg = vr.reshape(self.max_pages_per_slot, self.page_size,
-                             self.n_heads, self.head_dim)
-            att = paged_attend(q[None], kpg[None], vpg[None], L, t0v)[0]
+            att = paged_attention(q[None], self._as_pool(kcs),
+                                  self._as_pool(vcs), li, bt, L, t0v)[0]
             h = h + att.reshape(C, self.d_model) @ lp["wo"]
             h = self._mlp(lp, h)
         h_last = jax.lax.dynamic_index_in_dim(h, n_valid - 1, 0,

@@ -40,6 +40,7 @@ import os
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from deeplearning4j_tpu.ops.attention import blockwise_attention
 
@@ -473,54 +474,93 @@ def _choose_impl(T, *, on_tpu, force_streaming=False, has_mask=False,
 # paged KV attention: block-table decode + chunked prefill (serving)
 # ----------------------------------------------------------------------
 # The serving tier (serving/kvcache.py) stores KV in fixed-size pages
-# inside a device-resident pool [P, page, H, Dh]; a per-slot block
+# inside device-resident pools [L, P, page, H, Dh]; a per-slot block
 # table maps logical KV block j -> physical page bt[s, j] (the
 # vLLM/PagedAttention shape). The kernels below index K/V through that
-# table instead of a contiguous [T, Dh] buffer; page_size doubles as
-# the kernel's block_k, so the online-softmax accumulation order is
-# IDENTICAL to the dense flash kernel's block order and the outputs
-# are bitwise equal to _fwd_kernel on the same tokens
-# (tests/test_paged_attention.py gates it across aligned/padded/bf16
-# grids). Trailing pages past a slot's seq_len are fully masked and
-# are bitwise no-ops on the (acc, m, l) carry — the grid can always
-# run the full static block-table width.
+# table instead of a contiguous [T, Dh] buffer, one page per grid step,
+# with page_size as the online-softmax block: the per-head page order
+# is the dense flash kernel's block order.
+#
+# What the kernels read. Layer index, block tables and live lengths are
+# scalar-prefetched, so the pool operand is the WHOLE pool (a layer's
+# slice as an operand of a custom call would be a copy XLA cannot fuse
+# away) and its index map picks (layer, bt[s, p]). Past a slot's last
+# live page the index map repeats that page — a repeated block index is
+# not fetched again — and the body does not run: a dead page is neither
+# read nor visited (tests/test_paged_attention.py poisons them with
+# NaN). The pool is viewed [L, P, page*H, Dh], rows ordered (token,
+# head): with H a multiple of the dtype's sublane tile that view is the
+# same bytes in the TPU's tiled layout as [L, P, page, H, Dh], where a
+# [.., page, H*Dh] view would be a relayout of the whole pool.
+#
+# Numerics: scores, softmax and the (acc, m, l) carry are float32 in
+# both kernels. The PREFILL kernel keeps one product per head and runs
+# _fwd_kernel's block body op for op — float32 operands, the query
+# scaled before the product — so its rows are bitwise the dense flash
+# kernel's on the same tokens (page == block_k) and paged_attend's in
+# bfloat16. The DECODE kernel scores all heads of a page in one product
+# and hands the MXU its operands in the pool's dtype (bf16 products are
+# exact in the float32 accumulator): the scale follows the product and
+# the probabilities are rounded to the pool's dtype for p.V, as XLA's
+# default precision rounds them in paged_attend on the TPU. Its sums
+# associate differently, so it sits a rounding of the dtype from the
+# dense kernel, not on it (tests/test_paged_attention.py states the
+# gap).
+
+_NT_DIMS = (((1,), (1,)), ((), ()))
 
 
-def _page_update(q, kj, vj, valid, m, l, acc):
-    """One page of one head's online softmax inside the paged kernels:
-    q [R, Dh] (fp32, pre-scaled) against kj/vj [page, Dh] (fp32) under
-    valid [R or 1, page]; carry m/l [R, 1], acc [R, Dh]. Same ops in
-    the same order as _fwd_kernel's block body."""
-    s = jax.lax.dot_general(q, kj, (((1,), (1,)), ((), ())),
+def _page_update(q, kj, vj, valid, m, l, acc, scale=None):
+    """One page of online softmax inside the paged kernels: q [R, D]
+    against kj/vj [N, D] under valid [R, N]; carry m/l [R, 1], acc
+    [R, D] (fp32). Prefill calls it per head (R = chunk rows, N = page)
+    with float32 operands and q already scaled: the same ops in the
+    same order as _fwd_kernel's block body. Decode calls it once for
+    all heads (R = H, N = page*H, `valid` holding the head-diagonal)
+    with operands in the pool's dtype and `scale` to apply to the
+    float32 product."""
+    s = jax.lax.dot_general(q, kj, _NT_DIMS,
                             preferred_element_type=jnp.float32)
+    if scale is not None:
+        s = s * scale
     s = jnp.where(valid, s, _NEG_INF)
     m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
     corr = jnp.exp(m - m_new)
+    # explicit zero where invalid: a fully-masked row's sentinel
+    # otherwise normalises itself away (exp(s - m) == 1)
     pr = jnp.where(valid, jnp.exp(s - m_new), 0.0)
     return (m_new, l * corr + jnp.sum(pr, axis=1, keepdims=True),
-            acc * corr + jax.lax.dot_general(
-                pr, vj, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32))
+            acc * corr + jnp.dot(pr.astype(vj.dtype), vj,
+                                 preferred_element_type=jnp.float32))
 
 
-def _paged_decode_kernel(bt_ref, sl_ref, q_ref, k_ref, v_ref, *refs,
-                         page: int, scale: float):
+def _init_carry(acc_ref, m_ref, l_ref):
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+    m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
+    l_ref[...] = jnp.zeros_like(l_ref)
+
+
+def _paged_decode_kernel(lay_ref, bt_ref, sl_ref, q_ref, pos_ref, k_ref,
+                         v_ref, *refs, page: int, scale: float):
     """One (slot, page) program of the block-table decode grid, all
-    heads per program.
+    heads in one pair of products.
 
-    Scalar-prefetch refs: bt_ref [S, MP] block table, sl_ref [S] live
-    KV length per slot. q_ref [1, H, Dh] is the slot's single query
-    row per head; k_ref/v_ref [1, page, H*Dh] are the page the index
-    map gathered through the block table, heads side by side on the
-    lane axis (Mosaic refuses a block of 1 on the head axis of a
-    [P, page, H, Dh] pool, so the wrapper views the pool as
-    [P, page, H*Dh] and the kernel slices each head's lanes). The
-    online-softmax carry (acc, m, l) lives in VMEM scratch across the
-    page axis (innermost grid dim); p == 0 initialises it, the last
-    page normalises and writes the output rows. A padded slot
-    (sl == 0) masks every key, so l stays 0 and the l == 0 guard emits
-    exact zeros — with the _LSE_EMPTY (+1e30) sentinel on the lse
-    output, exactly like the dense kernel's fully-padded rows."""
+    Scalar-prefetch refs: lay_ref [1] layer, bt_ref [S, MP] block
+    table, sl_ref [S] live KV length per slot. q_ref [H, Dh] is the
+    slot's query row per head; k_ref/v_ref [page*H, Dh] the page the
+    index map picked, rows (token, head). q . k^T is [H, page*H]: row
+    h' against every (token, head) row, of which the head-diagonal
+    h == h' is this head's scores. pos_ref [H, page*H] holds the token
+    index on that diagonal and a value past any length off it, so one
+    comparison masks both the other heads' rows and the tokens past
+    the slot's length (the decode query sits at position length-1, so
+    the length mask is the causal mask). The masked probabilities are
+    exact zeros off the diagonal and p . v is [H, Dh] directly. The
+    carry (acc, m, l) lives in VMEM scratch across the page axis
+    (innermost grid dim); the last grid step normalises and writes. A
+    padded slot (sl == 0) visits no page: l stays 0 and the l == 0
+    guard emits exact zeros, with the _LSE_EMPTY (+1e30) sentinel on
+    the lse output, like the dense kernel's fully-padded rows."""
     from jax.experimental import pallas as pl
 
     if len(refs) == 5:
@@ -528,182 +568,242 @@ def _paged_decode_kernel(bt_ref, sl_ref, q_ref, k_ref, v_ref, *refs,
     else:
         o_ref, acc_ref, m_ref, l_ref = refs
         lse_ref = None
-    _, H, Dh = q_ref.shape
-    s_i = pl.program_id(0)
     p = pl.program_id(1)
-    n_pages = pl.num_programs(1)
+    length = sl_ref[pl.program_id(0)]
 
     @pl.when(p == 0)
     def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
+        _init_carry(acc_ref, m_ref, l_ref)
 
-    k_pos = p * page + jax.lax.broadcasted_iota(jnp.int32, (1, page), 1)
-    # the decode query sits at position length-1, so the causal mask
-    # q_pos >= k_pos coincides with the length mask k_pos < length —
-    # causal by construction, one comparison
-    valid = k_pos < sl_ref[s_i]
-    for h in range(H):
-        hs = slice(h, h + 1)
-        lanes = slice(h * Dh, (h + 1) * Dh)
-        m_ref[hs, :], l_ref[hs, :], acc_ref[hs, :] = _page_update(
-            q_ref[0, hs, :].astype(jnp.float32) * scale,     # [1, Dh]
-            k_ref[0, :, lanes].astype(jnp.float32),          # [page, Dh]
-            v_ref[0, :, lanes].astype(jnp.float32), valid,
-            m_ref[hs, :], l_ref[hs, :], acc_ref[hs, :])
+    @pl.when(p * page < length)
+    def _visit():
+        valid = p * page + pos_ref[...] < length
+        m_ref[...], l_ref[...], acc_ref[...] = _page_update(
+            q_ref[...], k_ref[...], v_ref[...], valid,
+            m_ref[...], l_ref[...], acc_ref[...], scale=scale)
 
-    @pl.when(p == n_pages - 1)
+    @pl.when(p == pl.num_programs(1) - 1)
     def _finalize():
         l_f = l_ref[...]
-        o_ref[0] = (acc_ref[...] / jnp.where(l_f == 0, 1.0, l_f)
-                    ).astype(o_ref.dtype)
+        o_ref[...] = (acc_ref[...] / jnp.where(l_f == 0, 1.0, l_f)
+                      ).astype(o_ref.dtype)
         if lse_ref is not None:
-            lse_ref[0] = jnp.where(l_f > 0, m_ref[...] + jnp.log(l_f),
-                                   _LSE_EMPTY)
+            lse_ref[...] = jnp.where(l_f > 0, m_ref[...] + jnp.log(l_f),
+                                     _LSE_EMPTY)
 
 
 def _pool_rows(pool):
-    """[P, page, H, Dh] -> [P, page, H*Dh]: the heads-on-lanes view the
-    paged kernels block over (a free reshape of the serving layout)."""
-    P, page, H, Dh = pool.shape
-    return pool.reshape(P, page, H * Dh)
+    """[L, P, page, H, Dh] (or one layer's [P, page, H, Dh]) ->
+    [L, P, page*H, Dh]: the rows-by-(token, head) view the paged
+    kernels block over (section comment: the same bytes on the TPU)."""
+    if pool.ndim == 4:
+        pool = pool[None]
+    L, P, page, H, Dh = pool.shape
+    return pool.reshape(L, P, page * H, Dh)
 
 
-def paged_flash_decode(q, k_pool, v_pool, block_tables, seq_lens,
-                       need_lse=False, interpret=None):
-    """Block-table flash decode: one query row per slot, K/V gathered
-    through the slot's block table.
+def _live_pages(length, page):
+    """Pages a context of `length` tokens reaches: the pages the
+    kernels' bodies run on (`p * page < length`)."""
+    return (length + page - 1) // page
 
-    q [S, H, Dh]; k_pool/v_pool [P, page, H, Dh]; block_tables
-    [S, MP] int32 (physical page per logical block — padded slots
-    point at the pool's null page); seq_lens [S] int32 (live KV
-    tokens per slot; 0 = padded slot -> zero output row + _LSE_EMPTY
-    sentinel). Returns [S, H, Dh] (and lse [S, H] fp32 when
-    need_lse). Bitwise-equal to the dense flash kernel on the same
-    tokens when page == the dense kernel's block_k."""
+
+def _last_live_page(length, page):
+    """Index of the last page a context of `length` tokens reaches (0
+    for an empty one): where the kernels' index maps stop advancing."""
+    return jnp.maximum(_live_pages(length, page) - 1, 0)
+
+
+def paged_pages_visited(impl, lengths, page, table_width):
+    """Pages one step's attention reads for slots of live KV `lengths`
+    (host integers, one or an array) under `impl` (what
+    paged_attention_impl said): each slot's live pages for the kernels,
+    its whole table for paged_attend. Derived from the rule the
+    kernels run by (_live_pages), not counted on the device: the spans
+    that carry it (sequence.step, sequence.prefill) say what the
+    dispatcher chose, and the device trace says whether that is what
+    ran."""
+    lengths = np.atleast_1d(np.asarray(lengths))
+    if impl != "pallas":
+        return int(lengths.size * table_width)
+    return int(np.sum(_live_pages(lengths, page)))
+
+
+def _layer_operand(k_pool, layer):
+    if (layer is None) != (k_pool.ndim == 4):
+        raise ValueError(
+            "paged kernels take a layer's pool [P, page, H, Dh] or the "
+            "whole pool [L, P, page, H, Dh] with a layer index")
+    return jnp.reshape(jnp.asarray(0 if layer is None else layer,
+                                   jnp.int32), (1,))
+
+
+@functools.partial(jax.jit, static_argnames=("need_lse", "interpret"))
+def _paged_decode_call(layer, block_tables, seq_lens, q, k_rows, v_rows,
+                       *, need_lse, interpret):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     S, H, Dh = q.shape
-    page = k_pool.shape[1]
+    page = k_rows.shape[2] // H
     MP = block_tables.shape[1]
-    interp = _INTERPRET if interpret is None else interpret
     kernel = functools.partial(_paged_decode_kernel, page=page,
                                scale=1.0 / (Dh ** 0.5))
-    row = lambda s, p, bt, sl: (s, 0, 0)
-    gathered = pl.BlockSpec((1, page, H * Dh),
-                            lambda s, p, bt, sl: (bt[s, p], 0, 0))
+    row = lambda s, p, lay, bt, sl: (s, 0, 0)
+    live = pl.BlockSpec(
+        (None, None, page * H, Dh),
+        lambda s, p, lay, bt, sl: (
+            lay[0], bt[s, jnp.minimum(p, _last_live_page(sl[s], page))],
+            0, 0))
+    # token index of each (token, head) row on its head's diagonal, a
+    # value past any length elsewhere
+    lane = jax.lax.broadcasted_iota(jnp.int32, (H, page * H), 1)
+    head = jax.lax.broadcasted_iota(jnp.int32, (H, page * H), 0)
+    pos = jnp.where(lane % H == head, lane // H, 2 ** 30)
     out_shape = [jax.ShapeDtypeStruct((S, H, Dh), q.dtype)]
-    out_specs = [pl.BlockSpec((1, H, Dh), row)]
+    out_specs = [pl.BlockSpec((None, H, Dh), row)]
     if need_lse:
         out_shape.append(jax.ShapeDtypeStruct((S, H, 1), jnp.float32))
-        out_specs.append(pl.BlockSpec((1, H, 1), row))
+        out_specs.append(pl.BlockSpec((None, H, 1), row))
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=3,
         grid=(S, MP),
-        in_specs=[pl.BlockSpec((1, H, Dh), row), gathered, gathered],
+        in_specs=[pl.BlockSpec((None, H, Dh), row),
+                  pl.BlockSpec((H, page * H),
+                               lambda s, p, lay, bt, sl: (0, 0)),
+                  live, live],
         out_specs=out_specs,
         scratch_shapes=[pltpu.VMEM((H, Dh), jnp.float32),
                         pltpu.VMEM((H, 1), jnp.float32),
                         pltpu.VMEM((H, 1), jnp.float32)],
     )
-    res = pl.pallas_call(kernel, grid_spec=grid_spec,
-                         out_shape=out_shape, interpret=interp)(
+    return pl.pallas_call(kernel, grid_spec=grid_spec,
+                          out_shape=out_shape, interpret=interpret)(
+        layer, block_tables, seq_lens, q, pos, k_rows, v_rows)
+
+
+def paged_flash_decode(q, k_pool, v_pool, block_tables, seq_lens,
+                       layer=None, need_lse=False, interpret=None):
+    """Block-table flash decode: one query row per slot, K/V read
+    through the slot's block table, live pages only.
+
+    q [S, H, Dh]; k_pool/v_pool the whole pools [L, P, page, H, Dh]
+    with `layer` the (traced or static) layer index, or one layer's
+    [P, page, H, Dh] with layer=None; block_tables [S, MP] int32
+    (physical page per logical block; entries past a slot's last live
+    page are never read); seq_lens [S] int32 (live KV tokens per slot;
+    0 = padded slot -> zero output row + _LSE_EMPTY sentinel). Returns
+    [S, H, Dh] (and lse [S, H] fp32 when need_lse)."""
+    res = _paged_decode_call(
+        _layer_operand(k_pool, layer),
         jnp.asarray(block_tables, jnp.int32),
-        jnp.asarray(seq_lens, jnp.int32), q,
-        _pool_rows(k_pool), _pool_rows(v_pool))
+        jnp.asarray(seq_lens, jnp.int32), q, _pool_rows(k_pool),
+        _pool_rows(v_pool), need_lse=bool(need_lse),
+        interpret=bool(_INTERPRET if interpret is None else interpret))
     return (res[0], res[1][..., 0]) if need_lse else res[0]
 
 
-def _paged_prefill_kernel(bt_ref, prm_ref, q_ref, k_ref, v_ref, o_ref,
-                          acc_ref, m_ref, l_ref, *, page: int,
-                          scale: float):
+def _paged_prefill_kernel(lay_ref, bt_ref, prm_ref, q_ref, k_ref, v_ref,
+                          o_ref, acc_ref, m_ref, l_ref, kf_ref, vf_ref,
+                          *, page: int, scale: float):
     """One page program of the chunked-prefill grid, all heads per
     program: the chunk's C query rows (positions t0..t0+C-1) against
-    every page of ONE slot's block table — its own freshly written
-    page included, so in-chunk attention is causal by the
-    q_pos >= k_pos mask. q_ref [H, C, Dh]; k_ref/v_ref [1, page, H*Dh]
-    (the heads-on-lanes pool view, see _paged_decode_kernel). prm_ref
-    carries (t0, L) where L = t0 + valid chunk rows; padded chunk rows
-    (q_pos >= L) emit garbage the caller slices off, and their zeroed
-    KV rows are masked from every valid query by k_pos < L."""
+    the live pages of ONE slot's block table — its own freshly written
+    page, the last live one, included, so in-chunk attention is causal
+    by the q_pos >= k_pos mask. q_ref [H, C, Dh]; k_ref/v_ref
+    [page*H, Dh], rows (token, head): a head's [page, Dh] is every
+    H-th row, read by a strided load from a float32 copy of the page
+    (kf_ref/vf_ref; sublane strides are a 32-bit affair), the float32
+    operands _fwd_kernel's block body takes. prm_ref carries (t0, L) where
+    L = t0 + valid chunk rows; padded chunk rows (q_pos >= L) emit
+    garbage the caller slices off, and their KV rows are masked from
+    every valid query by k_pos < L."""
     from jax.experimental import pallas as pl
 
     H, chunk, Dh = q_ref.shape
     p = pl.program_id(0)
-    n_pages = pl.num_programs(0)
+    t0 = prm_ref[0]
+    L = prm_ref[1]
 
     @pl.when(p == 0)
     def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
+        _init_carry(acc_ref, m_ref, l_ref)
 
-    t0 = prm_ref[0]
-    L = prm_ref[1]
-    q_pos = t0 + jax.lax.broadcasted_iota(jnp.int32, (chunk, page), 0)
-    k_pos = p * page + jax.lax.broadcasted_iota(jnp.int32,
-                                                (chunk, page), 1)
-    valid = (k_pos < L) & (q_pos >= k_pos)
-    for h in range(H):
-        lanes = slice(h * Dh, (h + 1) * Dh)
-        m_ref[h], l_ref[h], acc_ref[h] = _page_update(
-            q_ref[h].astype(jnp.float32) * scale,            # [C, Dh]
-            k_ref[0, :, lanes].astype(jnp.float32),          # [page, Dh]
-            v_ref[0, :, lanes].astype(jnp.float32), valid,
-            m_ref[h], l_ref[h], acc_ref[h])
+    @pl.when(p * page < L)
+    def _visit():
+        q_pos = t0 + jax.lax.broadcasted_iota(jnp.int32, (chunk, page), 0)
+        k_pos = p * page + jax.lax.broadcasted_iota(jnp.int32,
+                                                    (chunk, page), 1)
+        valid = (k_pos < L) & (q_pos >= k_pos)
+        kf_ref[...] = k_ref[...].astype(jnp.float32)
+        vf_ref[...] = v_ref[...].astype(jnp.float32)
+        for h in range(H):
+            rows = pl.ds(h, page, stride=H)
+            m_ref[h], l_ref[h], acc_ref[h] = _page_update(
+                q_ref[h].astype(jnp.float32) * scale, kf_ref[rows, :],
+                vf_ref[rows, :], valid, m_ref[h], l_ref[h], acc_ref[h])
 
-    @pl.when(p == n_pages - 1)
+    @pl.when(p == pl.num_programs(0) - 1)
     def _finalize():
         l_f = l_ref[...]
         o_ref[...] = (acc_ref[...] / jnp.where(l_f == 0, 1.0, l_f)
                       ).astype(o_ref.dtype)
 
 
-def paged_flash_prefill(q_chunk, k_pool, v_pool, block_table, t0,
-                        n_valid, interpret=None):
-    """Chunked-prefill attention for ONE slot: the prompt chunk's
-    queries (C rows at offset t0, C == page_size) against the slot's
-    whole block table — the chunk's own KV page must already be
-    written into the pool (kvcache append, then this kernel; causal
-    in-chunk by construction).
-
-    q_chunk [C, H, Dh]; k_pool/v_pool [P, page, H, Dh]; block_table
-    [MP] int32; t0 = chunk offset (multiple of page_size); n_valid =
-    live rows in this chunk (< C only for the prompt's tail chunk).
-    Returns [C, H, Dh]; rows past n_valid are padding garbage the
-    caller slices off."""
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def _paged_prefill_call(layer, block_table, prm, q_heads, k_rows, v_rows,
+                        *, interpret):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    C, H, Dh = q_chunk.shape
-    page = k_pool.shape[1]
-    MP = block_table.shape[0]
-    interp = _INTERPRET if interpret is None else interpret
+    H, C, Dh = q_heads.shape
+    page = k_rows.shape[2] // H
     kernel = functools.partial(_paged_prefill_kernel, page=page,
                                scale=1.0 / (Dh ** 0.5))
-    t0 = jnp.asarray(t0, jnp.int32)
-    prm = jnp.stack([t0, t0 + jnp.asarray(n_valid, jnp.int32)])
-    whole = pl.BlockSpec((H, C, Dh), lambda p, bt, prm_: (0, 0, 0))
-    gathered = pl.BlockSpec((1, page, H * Dh),
-                            lambda p, bt, prm_: (bt[p], 0, 0))
+    whole = pl.BlockSpec((H, C, Dh), lambda p, lay, bt, prm_: (0, 0, 0))
+    live = pl.BlockSpec(
+        (None, None, page * H, Dh),
+        lambda p, lay, bt, prm_: (
+            lay[0], bt[jnp.minimum(p, _last_live_page(prm_[1], page))],
+            0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(MP,),
-        in_specs=[whole, gathered, gathered],
+        num_scalar_prefetch=3,
+        grid=(block_table.shape[0],),
+        in_specs=[whole, live, live],
         out_specs=whole,
         scratch_shapes=[pltpu.VMEM((H, C, Dh), jnp.float32),
                         pltpu.VMEM((H, C, 1), jnp.float32),
-                        pltpu.VMEM((H, C, 1), jnp.float32)],
+                        pltpu.VMEM((H, C, 1), jnp.float32),
+                        pltpu.VMEM((page * H, Dh), jnp.float32),
+                        pltpu.VMEM((page * H, Dh), jnp.float32)],
     )
-    out = pl.pallas_call(kernel, grid_spec=grid_spec,
-                         out_shape=jax.ShapeDtypeStruct((H, C, Dh),
-                                                        q_chunk.dtype),
-                         interpret=interp)(
-        jnp.asarray(block_table, jnp.int32), prm,
-        jnp.moveaxis(q_chunk, 1, 0), _pool_rows(k_pool),
-        _pool_rows(v_pool))
+    return pl.pallas_call(
+        kernel, grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((H, C, Dh), q_heads.dtype),
+        interpret=interpret)(
+        layer, block_table, prm, q_heads, k_rows, v_rows)
+
+
+def paged_flash_prefill(q_chunk, k_pool, v_pool, block_table, t0,
+                        n_valid, layer=None, interpret=None):
+    """Chunked-prefill attention for ONE slot: the prompt chunk's
+    queries (C rows at offset t0, C == page_size) against the slot's
+    block table up to the chunk's own page — which must already be
+    written into the pool (kvcache append, then this kernel; causal
+    in-chunk by construction).
+
+    q_chunk [C, H, Dh]; k_pool/v_pool and `layer` as paged_flash_decode
+    takes them; block_table [MP] int32; t0 = chunk offset (multiple of
+    page_size); n_valid = live rows in this chunk (< C only for the
+    prompt's tail chunk). Returns [C, H, Dh]; rows past n_valid are
+    padding garbage the caller slices off."""
+    t0 = jnp.asarray(t0, jnp.int32)
+    prm = jnp.stack([t0, t0 + jnp.asarray(n_valid, jnp.int32)])
+    out = _paged_prefill_call(
+        _layer_operand(k_pool, layer), jnp.asarray(block_table, jnp.int32),
+        prm, jnp.moveaxis(q_chunk, 1, 0), _pool_rows(k_pool),
+        _pool_rows(v_pool),
+        interpret=bool(_INTERPRET if interpret is None else interpret))
     return jnp.moveaxis(out, 0, 1)
 
 
@@ -765,6 +865,70 @@ def paged_attend(q, k_pages, v_pages, lengths, q_starts):
     per_slot = jax.vmap(per_head, in_axes=(0, 0, 0, 0, 0))
     out = per_slot(qt, kt, vt, lengths, q_starts)
     return jnp.moveaxis(out, 1, 2)
+
+
+#: VMEM the paged kernels' blocks may take: K and V pages double-
+#: buffered, the prefill kernel's two float32 page copies, its q and
+#: output blocks (double-buffered) and its float32 carry. The benchmark's
+#: shape (page 128, H 16, Dh 128, bfloat16) takes 9 MiB of it and
+#: compiles for a v5e inside the 16 MiB scoped limit
+#: (tests/test_pallas_tpu_lowering.py).
+_PAGED_VMEM_BUDGET = 10 * 2 ** 20
+
+
+def _paged_kernel_fits(page, H, Dh, itemsize):
+    """Shape rule for the paged kernels on the TPU: Dh lane-aligned
+    (the MXU contraction and every block's last dim); H and page
+    multiples of the dtype's sublane tile (H so that the pool's
+    [page*H, Dh] view is the pool's own bytes and a head's rows sit at
+    a whole sublane stride, page so that the chunk's [C, Dh] tiles are
+    whole); and the blocks inside _PAGED_VMEM_BUDGET."""
+    sub = 32 // itemsize
+    if Dh % 128 or H % sub or page % sub:
+        return False
+    rows = page * H * Dh
+    return (4 * rows * itemsize + 2 * rows * 4      # K, V pages; copies
+            + 4 * rows * itemsize                   # q chunk and output
+            + rows * 4 + 2 * page * H * 128 * 4     # acc; m, l (padded)
+            <= _PAGED_VMEM_BUDGET)
+
+
+def paged_attention_impl(page, H, Dh, dtype):
+    """'pallas' or 'reference': what paged_attention runs for these
+    shapes here. Read at trace time from the backend and the shapes
+    alone; a kernel it chose either compiles or raises."""
+    fits = _paged_kernel_fits(page, H, Dh, jnp.dtype(dtype).itemsize)
+    return "pallas" if _on_tpu() and fits else "reference"
+
+
+def paged_attention(q, k_pools, v_pools, layer, block_tables, lengths,
+                    q_starts):
+    """The serving step functions' attention over a block table: q
+    [S, R, H, Dh] (R = 1: one decode row per slot; R = page with S = 1:
+    one prefill chunk), k_pools/v_pools the whole pools
+    [L, P, page, H, Dh], layer a Python int, block_tables [S, MP],
+    lengths [S] live KV tokens, q_starts [S] position of q row 0.
+    Returns [S, R, H, Dh].
+
+    On the TPU, at shapes _paged_kernel_fits admits, the pallas kernels
+    read the live pages straight from the pool; everywhere else
+    paged_attend runs on the layer's gathered tables (the CPU path and
+    the reference)."""
+    _, _, page, H, Dh = k_pools.shape
+    if paged_attention_impl(page, H, Dh, k_pools.dtype) == "reference":
+        return paged_attend(q, k_pools[layer][block_tables],
+                            v_pools[layer][block_tables], lengths,
+                            q_starts)
+    if q.shape[1] == 1:
+        return paged_flash_decode(q[:, 0], k_pools, v_pools, block_tables,
+                                  lengths, layer=layer)[:, None]
+    if q.shape[0] != 1 or q.shape[1] != page:
+        raise ValueError(
+            f"paged_attention takes one row per slot or one page-sized "
+            f"chunk of one slot, got q {q.shape} at page {page}")
+    return paged_flash_prefill(q[0], k_pools, v_pools, block_tables[0],
+                               q_starts[0], lengths[0] - q_starts[0],
+                               layer=layer)[None]
 
 
 def flash_attention(q, k, v, causal=False, key_mask=None,

@@ -6,14 +6,15 @@ vLLM/PagedAttention shape bounds it by the tokens actually alive:
 the pool is ``num_pages`` fixed-size pages per layer, device-resident
 (``[L, P, page, H, Dh]`` for K and V), and each slot maps logical KV
 block j -> physical page through its **block table** row. The serving
-step functions (nn/transformer.py) gather K/V through that table and
-attend with ``ops.pallas_attention.paged_attend`` on EVERY backend,
-the chip included. The pallas kernels beside it
-(``paged_flash_decode`` / ``paged_flash_prefill``, which gather per
-page in VMEM) compile and run on the TPU (chip_smoke.py checks them
-against ``paged_attend``) but no serving path calls them yet — ROADMAP
-S4. ``page_size`` doubles as the kernels' block_k so paged attention
-accumulates in the dense flash kernel's block order.
+step functions (nn/transformer.py) attend through
+``ops.pallas_attention.paged_attention``: on the TPU, at shapes its
+rule admits, the pallas kernels (``paged_flash_decode`` /
+``paged_flash_prefill``) take the whole pools and fetch each live page
+of a slot's table into VMEM once, and no page past its last live one;
+elsewhere ``paged_attend`` runs on the tables gathered whole (the CPU
+path and the reference; chip_smoke.py checks the kernels against it).
+``page_size`` doubles as the kernels' block_k so paged attention
+accumulates per head in the dense flash kernel's block order.
 
 ``PagedKVCache`` is the HOST-side manager plus the device pools:
 

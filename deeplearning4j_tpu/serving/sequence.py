@@ -64,6 +64,7 @@ from collections import deque
 
 import numpy as np
 
+from deeplearning4j_tpu.ops.pallas_attention import paged_pages_visited
 from deeplearning4j_tpu.runtime import telemetry
 from deeplearning4j_tpu.runtime.chaos import \
     fault_point as _chaos_fault_point
@@ -817,6 +818,14 @@ class PagedSequenceScheduler:
     ``sequence.step`` (child ``sequence.fetch``) and
     ``sequence.sample``; a request that ends, done or failed, leaves
     one instant ``sequence.request`` with its whole timeline.
+    ``sequence.step`` and ``sequence.prefill`` say what the dispatcher
+    chose for their attention: ``attend`` (``"pallas"`` or
+    ``"reference"``, the model's ``attend_impl()``), ``pages_visited``
+    (the live pages of the step's live slots on the kernel path, their
+    whole tables on the reference path: derived by
+    ``ops.pallas_attention.paged_pages_visited`` from the kernels' own
+    rule, not counted on the device) and ``pages_table`` (live slots x
+    table width).
     """
 
     def __init__(self, model, *, num_pages, slot_buckets=None,
@@ -858,6 +867,8 @@ class PagedSequenceScheduler:
             num_pages=num_pages, dtype=model._compute_dtype,
             model=self.name)
         self._mp = int(model.max_pages_per_slot)
+        impl = getattr(model, "attend_impl", None)
+        self._attend = impl() if impl is not None else "reference"
         self._cond = threading.Condition()
         self._step_lock = threading.Lock()
         self._pending = deque()
@@ -1107,7 +1118,9 @@ class PagedSequenceScheduler:
             self._registry.add_span(
                 "sequence.prefill", "serving", t0c, t1c - t0c,
                 parent=parent, rid=req.stream_id, model=self.name,
-                chunk=n_valid)
+                chunk=n_valid, attend=self._attend,
+                pages_visited=self._pages_visited(t0 + n_valid),
+                pages_table=self._mp)
         req.prefilled += n_valid
         req.seq_len = req.prefilled
         self.prefill_chunks += 1
@@ -1121,6 +1134,12 @@ class PagedSequenceScheduler:
                 "sequence.prefill_finish", "serving", t1c,
                 self.clock() - t1c, parent=parent, rid=req.stream_id)
         return True
+
+    def _pages_visited(self, lengths):
+        """Pages one step's attention reads for live slots of KV
+        `lengths` (an int or an array), by the kernels' own rule."""
+        return paged_pages_visited(self._attend, lengths,
+                                   self.model.page_size, self._mp)
 
     def _staging_for(self, S):
         """Per-bucket decode staging buffers (tokens, seq lens, block
@@ -1208,7 +1227,9 @@ class PagedSequenceScheduler:
             reg.add_span(
                 "sequence.step", "serving", t0c, t_s - t0c,
                 parent=parent, span_id=step_id, model=self.name,
-                slots=n, bucket=S)
+                slots=n, bucket=S, attend=self._attend,
+                pages_visited=self._pages_visited(sls[:n] + 1),
+                pages_table=n * self._mp)
         finished = []
         for i, req in enumerate(ready):
             if req.done:                # expired between gather + now

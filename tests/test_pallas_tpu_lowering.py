@@ -31,6 +31,9 @@ from deeplearning4j_tpu.ops import pallas_attention as pa
 ATTN_SHAPES = [(4, 8, 512, 64), (4, 8, 8192, 64), (2, 4, 4096, 128)]
 # chip_smoke.py's paged shape: the sequence-serving model's heads/pages
 PAGED = dict(S=8, H=16, Dh=64, page=16, MP=128, P=256)
+# the benchmark's own (perfbench/configs/cerebras-gpt-1.3b.json): the
+# whole pools with a layer index, as the step functions pass them
+PAGED_BENCH = dict(L=24, S=16, H=16, Dh=128, page=128, MP=16, P=320)
 
 
 @pytest.fixture(scope="module")
@@ -71,21 +74,34 @@ def _attn_cases(device=None):
 
 
 def _paged_cases(device=None, dtype=jnp.bfloat16):
-    p = PAGED
-    pool = _sds((p["P"], p["page"], p["H"], p["Dh"]), dtype, device)
-    q = _sds((p["S"], p["H"], p["Dh"]), dtype, device)
-    bts = _sds((p["S"], p["MP"]), jnp.int32, device)
-    lens = _sds((p["S"],), jnp.int32, device)
-    for need_lse in (False, True):
-        yield (f"paged decode lse={need_lse}",
-               functools.partial(pa.paged_flash_decode, need_lse=need_lse,
-                                 interpret=False),
-               (q, pool, pool, bts, lens))
-    yield ("paged prefill",
-           functools.partial(pa.paged_flash_prefill, interpret=False),
-           (_sds((p["page"], p["H"], p["Dh"]), dtype, device), pool, pool,
-            _sds((p["MP"],), jnp.int32, device),
-            _sds((), jnp.int32, device), _sds((), jnp.int32, device)))
+    for p in (PAGED, PAGED_BENCH):
+        whole = "L" in p
+        tag = "whole pool, benchmark shape" if whole else "a layer's pool"
+        shape = (p["P"], p["page"], p["H"], p["Dh"])
+        pool = _sds(((p["L"],) if whole else ()) + shape, dtype, device)
+        layer = (_sds((), jnp.int32, device),) if whole else ()
+        q = _sds((p["S"], p["H"], p["Dh"]), dtype, device)
+        bts = _sds((p["S"], p["MP"]), jnp.int32, device)
+        lens = _sds((p["S"],), jnp.int32, device)
+        for need_lse in (False, True):
+            def decode(q, kp, vp, bts, lens, layer=None,
+                       need_lse=need_lse):
+                return pa.paged_flash_decode(q, kp, vp, bts, lens,
+                                             layer=layer, need_lse=need_lse,
+                                             interpret=False)
+
+            yield (f"paged decode lse={need_lse}, {tag}", decode,
+                   (q, pool, pool, bts, lens) + layer)
+
+        def prefill(qc, kp, vp, bt, t0, n_valid, layer=None):
+            return pa.paged_flash_prefill(qc, kp, vp, bt, t0, n_valid,
+                                          layer=layer, interpret=False)
+
+        yield (f"paged prefill, {tag}", prefill,
+               (_sds((p["page"], p["H"], p["Dh"]), dtype, device), pool,
+                pool, _sds((p["MP"],), jnp.int32, device),
+                _sds((), jnp.int32, device), _sds((), jnp.int32, device))
+               + layer)
 
 
 def _all_cases(device=None):
@@ -141,3 +157,54 @@ def test_dispatch_rule_rejects_unaligned_blocks():
     assert pa._choose_impl(8192, on_tpu=True, kernel_fits=False) \
         == "blockwise"
     assert pa._choose_impl(8192, on_tpu=True, kernel_fits=True) == "flash"
+
+
+def test_the_benchmarks_step_functions_compile_with_the_kernels(
+        v5e, monkeypatch):
+    """The whole `_decode_paged` and `_prefill_paged` of the benchmark's
+    configuration, from shapes, for the described chip, the dispatcher
+    steered to the kernels from here (this process sees a CPU): 24
+    custom calls each, and temporaries far under a pool's 4 GB — the
+    scatter before each layer's kernel updates the donated pool in
+    place and the kernel's pool operand is no copy."""
+    from deeplearning4j_tpu.nn.transformer import CausalTransformerLM
+
+    p = PAGED_BENCH
+    d, f, vocab, ctx = p["H"] * p["Dh"], 8192, 50257, p["MP"] * p["page"]
+    dt = jnp.bfloat16
+
+    def sd(*shape, dtype=dt):
+        return _sds(shape, dtype, v5e)
+
+    def shapes(self):
+        layer = {"ln1": sd(d), "wq": sd(d, d), "wk": sd(d, d),
+                 "wv": sd(d, d), "wo": sd(d, d), "ln2": sd(d),
+                 "w1": sd(d, f), "w2": sd(f, d)}
+        return {"emb": sd(vocab, d), "pos": sd(ctx, d), "lnf": sd(d),
+                "layers": [dict(layer) for _ in range(p["L"])]}
+
+    monkeypatch.setattr(CausalTransformerLM, "_init_params", shapes)
+    monkeypatch.setattr(pa, "_on_tpu", lambda: True)
+    model = CausalTransformerLM(
+        vocab=vocab, d_model=d, n_heads=p["H"], n_layers=p["L"], d_ff=f,
+        max_context=ctx, page_size=p["page"], dtype="bfloat16")
+    assert model.attend_impl() == "pallas"
+    pool = sd(p["L"], p["P"], p["page"], p["H"], p["Dh"])
+    i32 = jnp.int32
+    steps = {
+        "_decode_paged": (model._decode_paged, (2, 3), (
+            model._params, sd(p["S"], dtype=i32), pool, pool,
+            sd(p["S"], p["MP"], dtype=i32), sd(p["S"], dtype=i32))),
+        "_prefill_paged": (model._prefill_paged, (4, 5), (
+            model._params, sd(p["page"], dtype=i32), sd(dtype=i32),
+            sd(dtype=i32), pool, pool, sd(p["MP"], dtype=i32))),
+    }
+    with jax.enable_x64(False):
+        for name, (fn, donate, args) in steps.items():
+            compiled = jax.jit(fn, donate_argnums=donate).lower(
+                *args).compile()
+            assert compiled.as_text().count("tpu_custom_call") >= p["L"], \
+                f"{name}: the kernels are not in the compiled step"
+            temp = compiled.memory_analysis().temp_size_in_bytes
+            assert temp < 2 ** 30, \
+                f"{name}: {temp / 1e9:.2f} GB of temporaries (a pool copy?)"

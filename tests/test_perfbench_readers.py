@@ -1,4 +1,5 @@
-"""The per-layer readers that ISSUE 26 adds under perfbench/metrics/, fed
+"""The per-layer readers that ISSUE 26 adds under perfbench/metrics/ (and
+ISSUE 27's `paged_attend.pages_visited_share`), fed
 hand-made spans: each returns the number worked out by hand below, None
 where the ring dropped spans (a truncated window gives no number) and
 None, without raising, where the program left nothing to read (the
@@ -32,6 +33,7 @@ EXPECTED = {
     "fit.dispatch_ms_mean": 3.0,
     "fit.sync_wait_ms_mean": 97.0,
     "fit.outside_step_ms_mean": 2.8,
+    "paged_attend.pages_visited_share": 100.0 * 129 / 304,
 }
 SETUP = ("setup.weights_init_s", "setup.warm_s")
 
@@ -49,11 +51,13 @@ class StubRun:
 
 
 def _iteration(reg, ts, dur, pages, parts):
-    """One sequence.iteration at `ts` with children laid end to end."""
+    """One sequence.iteration at `ts` with children laid end to end;
+    a part is (name, seconds) or (name, seconds, args)."""
     it = reg.new_span_id()
     at = ts
-    for name, d in parts:
-        sid = reg.add_span(name, "serving", at, d, parent=it)
+    for name, d, *args in parts:
+        sid = reg.add_span(name, "serving", at, d, parent=it,
+                           **(args[0] if args else {}))
         if name == "sequence.step":
             reg.add_span("sequence.fetch", "serving", at + d / 2, d / 2,
                          parent=sid, bytes=1)
@@ -70,24 +74,34 @@ def _request(reg, rid, enq, chunk, tokens, error=None):
               token_times=tuple(tokens), error=error)
 
 
+def _pages(visited, table):
+    return {"attend": "pallas", "pages_visited": visited,
+            "pages_table": table}
+
+
 def _fill(reg):
     ms = 1e-3
     # three iterations in the window: 100, 70, 80 ms -> median 80; their
     # children cover 98, 69, 77 ms -> self 2, 1, 3 -> median 2; samples
     # 5, 7, 9 ms -> median 7; one of three carries a chunk; pages 300,
-    # 319, 310 -> 319. One iteration before the window counts nowhere.
-    _iteration(reg, 5.0, 1.0, 999, [("sequence.prefill", 0.5),
-                                    ("sequence.sample", 0.5)])
+    # 319, 310 -> 319. Their steps read 40 of 96, 45 of 96 and 44 of 112
+    # pages -> 129 of 304. One iteration before the window counts nowhere.
+    _iteration(reg, 5.0, 1.0, 999, [
+        ("sequence.prefill", 0.4),
+        ("sequence.step", 0.1, _pages(16, 16)), ("sequence.sample", 0.5)])
     _iteration(reg, 10.0, 100 * ms, 300, [
         ("sequence.admit", 1 * ms), ("sequence.prefill", 30 * ms),
-        ("sequence.decode_prep", 2 * ms), ("sequence.step", 60 * ms),
+        ("sequence.decode_prep", 2 * ms),
+        ("sequence.step", 60 * ms, _pages(40, 96)),
         ("sequence.sample", 5 * ms)])
     _iteration(reg, 10.2, 70 * ms, 319, [
         ("sequence.admit", 1 * ms), ("sequence.decode_prep", 1 * ms),
-        ("sequence.step", 60 * ms), ("sequence.sample", 7 * ms)])
+        ("sequence.step", 60 * ms, _pages(45, 96)),
+        ("sequence.sample", 7 * ms)])
     _iteration(reg, 10.4, 80 * ms, 310, [
         ("sequence.admit", 1 * ms), ("sequence.decode_prep", 1 * ms),
-        ("sequence.step", 66 * ms), ("sequence.sample", 9 * ms)])
+        ("sequence.step", 66 * ms, _pages(44, 112)),
+        ("sequence.sample", 9 * ms)])
     # two requests count: time to first token 100 and 250 ms (median 175,
     # 95th 242.5), wait for the first chunk 10 and 50 ms (95th 48),
     # service 90 and 200 ms (median 145), gaps 100, 110 and 80 ms
@@ -146,6 +160,14 @@ def test_reader_gives_none_where_nothing_was_recorded(name):
     assert _read(name) is None
 
 
+def test_pages_visited_share_is_none_where_a_step_lacks_the_args(filled):
+    """The parent commit's `sequence.step` carries slots and bucket
+    only: one such step in the window and the reader gives no number."""
+    filled.add_span("sequence.step", "serving", 10.6, 0.06, slots=16,
+                    bucket=16)
+    assert _read("paged_attend.pages_visited_share") is None
+
+
 def test_idle_with_work_is_none_where_the_top_ten_hide_the_waiting(filled):
     """tracered keeps the ten largest names of idle_gaps: ten of them
     and no sequence.idle cannot be told from no waiting at all; fewer
@@ -200,6 +222,8 @@ def test_manifest_lists_the_new_metrics_with_their_readers():
              "fit.sync_wait_ms_mean": R, "fit.outside_step_ms_mean": R}
     for name in EXPECTED:
         assert by[name]["workloads"] == [cells.get(name, D)], name
+    assert by["paged_attend.pages_visited_share"]["layer"] == "kernels" \
+        and by["paged_attend.pages_visited_share"]["better"] == "lower"
     for name in SETUP:                      # every cell reports setup_s
         assert "workloads" not in by[name] and \
             by[name]["moves"] == "setup_s" and by[name]["layer"] == "set-up"

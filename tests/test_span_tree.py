@@ -227,9 +227,16 @@ class TestPagedSchedulerSpans:
             (fetch,) = kids[step["id"]]
             assert fetch["name"] == "sequence.fetch" and _inside(fetch, step)
             assert fetch["args"]["bytes"] > 0
-            # the accepted readers' args, as before
-            assert step["args"] == {"model": s.name, "slots": 1,
-                                    "bucket": 2}
+            # the accepted readers' args, as before, and what the
+            # attention read: the CPU takes paged_attend, whole tables
+            assert step["args"] == {
+                "model": s.name, "slots": 1, "bucket": 2,
+                "attend": "reference", "pages_visited": s._mp,
+                "pages_table": s._mp}
+        assert all(p["args"]["attend"] == "reference"
+                   and p["args"]["pages_visited"] == s._mp
+                   == p["args"]["pages_table"]
+                   for p in by["sequence.prefill"])
         assert [p["args"]["chunk"] for p in by["sequence.prefill"]] == \
             [8, 8, 4]
         assert by["sequence.admit"][0]["args"] == {"admitted": 1,
