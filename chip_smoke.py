@@ -56,7 +56,8 @@ import numpy as np
 from deeplearning4j_tpu.data.dataset import DataSetIterator
 from deeplearning4j_tpu.ndarray import DataType
 from deeplearning4j_tpu.nn import Nesterovs
-from deeplearning4j_tpu.nn.transformer import (CausalTransformerLM,
+from deeplearning4j_tpu.nn.transformer import (PREFILL_CHUNK_PAGES,
+                                               CausalTransformerLM,
                                                dense_serial_trajectory)
 from deeplearning4j_tpu.ops import pallas_attention as pa
 from deeplearning4j_tpu.ops.attention import (blockwise_attention,
@@ -388,22 +389,27 @@ class Smoke:
                                jnp.asarray(lens), jnp.asarray(lens) - 1)[:, 0]
         e_dec = rel_err(got, want)
         assert np.all(np.asarray(got[-1], np.float32) == 0)
-        t0 = page * (int(lens[0]) // page)
-        n_valid = max(1, int(lens[0]) - t0)
-        qc = jnp.asarray(rng.standard_normal((page, H, Dh)), dt)
-        got = jax.jit(functools.partial(
-            pa.paged_flash_prefill, interpret=self.rehearsal))(
-            qc, kp, vp, bts[0], t0, n_valid,
-            layer=jnp.asarray(li, jnp.int32))
-        want = pa.paged_attend(
-            qc[None], kp[li][bts[0]][None], vp[li][bts[0]][None],
-            jnp.asarray([t0 + n_valid]), jnp.asarray([t0]))[0]
-        e_pre = rel_err(got[:n_valid], want[:n_valid])
+        # slot 0's last pages as one chunk: of one page, and of as many
+        # as the scheduler's longest pass takes
+        live0 = -(-int(lens[0]) // page)
+        e_pre = 0.0
+        for n in sorted({1, min(PREFILL_CHUNK_PAGES[-1], live0)}):
+            t0 = page * (live0 - n)
+            n_valid = int(lens[0]) - t0
+            qc = jnp.asarray(rng.standard_normal((n * page, H, Dh)), dt)
+            got = jax.jit(functools.partial(
+                pa.paged_flash_prefill, interpret=self.rehearsal))(
+                qc, kp, vp, bts[0], t0, n_valid,
+                layer=jnp.asarray(li, jnp.int32))
+            want = pa.paged_attend(
+                qc[None], kp[li][bts[0]][None], vp[li][bts[0]][None],
+                jnp.asarray([t0 + n_valid]), jnp.asarray([t0]))[0]
+            e_pre = max(e_pre, rel_err(got[:n_valid], want[:n_valid]))
         rule = pa._paged_kernel_fits(page, H, Dh, jnp.dtype(dt).itemsize)
         log(f"paged_flash_decode S{S} H{H} Dh{Dh} page{page} MP{MP} "
             f"layer {li} of {L} (shape rule: {rule}) vs paged_attend: err "
-            f"{e_dec:.1e}; paged_flash_prefill: err {e_pre:.1e} "
-            f"(tol {ATTN_FWD_TOL})")
+            f"{e_dec:.1e}; paged_flash_prefill, chunks of 1 and {n} "
+            f"pages: err {e_pre:.1e} (tol {ATTN_FWD_TOL})")
         assert e_dec <= ATTN_FWD_TOL and e_pre <= ATTN_FWD_TOL
 
     def sequence(self):

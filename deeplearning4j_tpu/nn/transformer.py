@@ -6,11 +6,15 @@ carries KV instead of a fixed-width hidden state, so its serving twin
 is a pair of PURE step functions over an external paged KV cache
 (serving/kvcache.py):
 
-* ``prefill`` — append ONE page-sized prompt chunk's K/V into the
-  slot's freshly allocated page and attend the chunk's queries over
-  the block table so far (causal in-chunk). Bounded work per call:
-  a long prompt is consumed one chunk per scheduler iteration and can
-  never stall the running decode batch.
+* ``prefill`` — append ONE prompt chunk's K/V into the slot's freshly
+  allocated pages and attend the chunk's queries over the block table
+  so far (causal in-chunk). A chunk is a whole number of KV pages of
+  one slot, one of ``PREFILL_CHUNK_PAGES``: a pass reads every weight
+  once, which costs the same whatever the pass carries until it holds
+  a couple of hundred tokens, so ``prefill_plan`` keeps a prompt's
+  passes longer than a page. Bounded work per call: a long prompt is
+  consumed one chunk per scheduler iteration and can never stall the
+  running decode batch.
 * ``decode`` — one token per live slot: append each slot's K/V row at
   its block table's (page, offset), then one block-table attention
   step over every slot (one executable per slot bucket, exactly the
@@ -23,9 +27,10 @@ which): on the TPU, at shapes ``_paged_kernel_fits`` admits, the pallas
 block-table kernels, which fetch each live page of the pool into VMEM
 once and no other page; everywhere else ``paged_attend``, their
 page-sequential online-softmax twin on the gathered tables (the CPU
-path and the reference). Both take page_size as the block size and
-accumulate per head in the dense flash kernel's block order
-(tests/test_paged_attention.py gates the kernels in interpret mode).
+path and the reference). Both take page_size as the block size,
+whatever the chunk's length, and accumulate per head in the dense
+flash kernel's block order (tests/test_paged_attention.py gates the
+kernels in interpret mode).
 
 A DENSE-cache twin (``decode_dense``/``prefill_dense``: contiguous
 ``[L, S, max_context, H, Dh]`` slabs, the pre-paged shape) rides along
@@ -46,7 +51,51 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-__all__ = ["CausalTransformerLM", "dense_serial_trajectory"]
+__all__ = ["CausalTransformerLM", "PREFILL_CHUNK_PAGES",
+           "dense_serial_trajectory", "prefill_plan"]
+
+#: Lengths of a prefill chunk in KV pages, ascending: one executable of
+#: ``_prefill_paged`` each (warmed by the scheduler). On the v5e at
+#: bfloat16 a pass of one 128-token page is bound by the weights (30 us
+#: a token) and from two pages on by the matrix products (19-20 us a
+#: token whatever the length), so what pays is never to run a pass of
+#: one page and never to pad one; twos and threes do that for every
+#: prompt of two pages or more. PERF.md section 6 (PR 31) has the
+#: device time of each length and the lengths that were tried and
+#: dropped.
+PREFILL_CHUNK_PAGES = (1, 2, 3)
+
+
+def prefill_plan(n_tokens, n_live, page_size, max_pages):
+    """The prefill passes of one prompt: ``[(t0, n_valid, C), ...]``.
+
+    ``n_tokens`` prompt tokens of which the first ``n_live`` are in KV
+    already (whole adopted pages), pages of ``page_size`` tokens, a
+    block table of ``max_pages`` entries. Each pass takes ``n_valid``
+    prompt tokens from position ``t0`` in a chunk of ``C`` tokens, C a
+    length of ``PREFILL_CHUNK_PAGES``: the largest that the pages still
+    to fill hold and that does not leave a single page behind (four
+    pages are two and two, not three and one). Largest first, so a
+    prompt pays for the weights as few times as the lengths allow; no
+    chunk holds a page without a prompt token, so none runs past the
+    table's end (the step's ``dynamic_slice`` of the table would clamp
+    there and write other pages). The scheduler, its warm-up and the
+    dense oracle all take their chunks from here."""
+    if n_live % page_size and n_live < n_tokens:
+        raise ValueError(
+            f"prefill resumes on a page boundary, got {n_live} live "
+            f"tokens at page {page_size}")
+    passes = []
+    t0 = n_live
+    while t0 < n_tokens:
+        left = -(-(n_tokens - t0) // page_size)
+        n = max((c for c in PREFILL_CHUNK_PAGES
+                 if c <= left and left - c != 1), default=1)
+        n_valid = min(n * page_size, n_tokens - t0)
+        assert t0 // page_size + n <= max_pages      # the table's end
+        passes.append((t0, n_valid, n * page_size))
+        t0 += n_valid
+    return passes
 
 
 def _rmsnorm(x, g):
@@ -62,7 +111,8 @@ class CausalTransformerLM:
 
     vocab/d_model/n_heads/n_layers/d_ff: the usual dims (d_ff defaults
     to 4*d_model). max_context bounds positions; page_size is the KV
-    page AND the prefill chunk size (max_context % page_size == 0).
+    page (max_context % page_size == 0) and the unit of a prefill
+    chunk, which is PREFILL_CHUNK_PAGES pages long (prefill_plan).
     dtype is the compute/storage dtype (params, KV pools, residual
     stream); logits always come back fp32 for host-side sampling.
     """
@@ -110,6 +160,11 @@ class CausalTransformerLM:
         self._jit_prefill = aot.cached_jit(
             self._prefill_paged, entry="paged_prefill", fingerprint=fp,
             donate_argnums=pre_don)
+        # an inner jit with the layer index as an operand: the layers
+        # of a prefill step are one trace and one lowering, which is
+        # most of what an executable a chunk length costs set-up (XLA
+        # inlines the calls: the compiled step is the same)
+        self._prefill_layer = jax.jit(self._prefill_layer)
         self._jit_decode_dense = aot.cached_jit(
             self._decode_dense, entry="dense_decode", fingerprint=fp,
             donate_argnums=dec_don)
@@ -206,36 +261,52 @@ class CausalTransformerLM:
         return self._logits(params, h), kps, vps
 
     def _prefill_paged(self, params, tokens, t0, n_valid, kps, vps, bt):
-        """One page-sized prompt chunk for ONE slot. tokens [C=page]
-        i32 (zero-padded past n_valid), t0 = chunk offset (multiple of
-        page_size), bt [MP] the slot's block table with the chunk's
-        fresh page already installed at t0//page. Writes the chunk's
-        K/V into that page (padded rows too — decode overwrites them
-        before they are ever unmasked) and attends the chunk causally
-        over the table. Returns (last-valid-row logits [V] fp32,
+        """One prompt chunk of n whole pages for ONE slot. tokens
+        [C = n * page] i32 (zero-padded past n_valid), t0 = chunk offset
+        (multiple of page_size), bt [MP] the slot's block table with the
+        chunk's fresh pages already installed from t0//page on. Writes
+        the chunk's K/V into those n pages (padded rows too — decode
+        overwrites them before they are ever unmasked; a page the
+        scheduler left at the null page would take its rows there, as
+        padded decode slots' rows go) and attends the chunk causally
+        over the table. t0//page + n never passes the table's end
+        (prefill_plan). Returns (logits of row n_valid-1 [V] fp32,
         kps', vps')."""
         C = tokens.shape[0]
         zero = jnp.zeros((), t0.dtype)      # x64 mode: indices must
         pos = jax.lax.dynamic_slice(params["pos"], (t0, zero),
                                     (C, self.d_model))
         h = params["emb"][tokens] + pos
-        page_id = bt[t0 // self.page_size]
+        page_ids = jax.lax.dynamic_slice(
+            bt, (t0 // self.page_size,), (C // self.page_size,))
         L = jnp.reshape(t0 + n_valid, (1,))
         t0v = jnp.reshape(t0, (1,))
-        from deeplearning4j_tpu.ops.pallas_attention import paged_attention
-
         for li, lp in enumerate(params["layers"]):
-            x = _rmsnorm(h, lp["ln1"])
-            q, k, v = self._qkv(lp, x)
-            kps = kps.at[li, page_id].set(k)
-            vps = vps.at[li, page_id].set(v)
-            att = paged_attention(q[None], kps, vps, li, bt[None], L,
-                                  t0v)[0]
-            h = h + att.reshape(C, self.d_model) @ lp["wo"]
-            h = self._mlp(lp, h)
+            h, kps, vps = self._prefill_layer(
+                lp, jnp.asarray(li, jnp.int32), h, kps, vps, page_ids, bt,
+                L, t0v)
         h_last = jax.lax.dynamic_index_in_dim(h, n_valid - 1, 0,
                                               keepdims=True)
         return self._logits(params, h_last)[0], kps, vps
+
+    def _prefill_layer(self, lp, li, h, kps, vps, page_ids, bt, L, t0v):
+        """Layer `li` (a traced int32) of a prefill chunk: the chunk's
+        K/V into its pages `page_ids`, attention over the table `bt`,
+        the block's two matmul halves. Returns (h', kps', vps')."""
+        from deeplearning4j_tpu.ops.pallas_attention import paged_attention
+
+        C, page = h.shape[0], self.page_size
+        x = _rmsnorm(h, lp["ln1"])
+        q, k, v = self._qkv(lp, x)
+        # one in-place update a page: a scatter over the page ids could
+        # not alias the donated pool
+        for j in range(C // page):
+            rows = slice(j * page, (j + 1) * page)
+            kps = kps.at[li, page_ids[j]].set(k[rows])
+            vps = vps.at[li, page_ids[j]].set(v[rows])
+        att = paged_attention(q[None], kps, vps, li, bt[None], L, t0v)[0]
+        h = h + att.reshape(C, self.d_model) @ lp["wo"]
+        return self._mlp(lp, h), kps, vps
 
     # -- dense-cache twins (bench baseline + serial oracle) --------------
     def _as_pool(self, slab):
@@ -275,7 +346,8 @@ class CausalTransformerLM:
     def _prefill_dense(self, params, tokens, t0, n_valid, kcs, vcs,
                        slot):
         """Dense-slab chunked prefill for ONE slot (same chunking as
-        the paged path — the oracle must take the same block steps)."""
+        the paged path, any length of prefill_plan — the oracle must
+        take the same block steps)."""
         C = tokens.shape[0]
         zero = jnp.zeros((), t0.dtype)      # x64 mode: indices must
         pos = jax.lax.dynamic_slice(params["pos"], (t0, zero),
@@ -323,26 +395,23 @@ def dense_serial_trajectory(model, prompt, n_new, sampler, rng,
                             bucket=1):
     """The serial oracle: ONE sequence generated through the DENSE
     twin at a fixed slot bucket (live row 0, padding rows dead) —
-    page-size prefill chunks, then one decode step per generated
-    token, sampling with the caller's rng stream. Returns (tokens
-    [n_new] int list, logits [n_new, V] fp32) — what the paged
+    the prefill chunks of ``prefill_plan``, then one decode step per
+    generated token, sampling with the caller's rng stream. Returns
+    (tokens [n_new] int list, logits [n_new, V] fp32) — what the paged
     scheduler must reproduce bitwise for the same (seed, stream)
     within the same bucket."""
     prompt = np.asarray(prompt, np.int32).reshape(-1)
     S = int(bucket)
     kcs, vcs = model.dense_cache(S)
-    page = model.page_size
-    t0 = 0
     last = None
-    while t0 < prompt.shape[0]:
-        n_valid = min(page, prompt.shape[0] - t0)
-        chunk = np.zeros((page,), np.int32)
+    for t0, n_valid, C in prefill_plan(prompt.shape[0], 0, model.page_size,
+                                       model.max_pages_per_slot):
+        chunk = np.zeros((C,), np.int32)
         chunk[:n_valid] = prompt[t0:t0 + n_valid]
         last, kcs, vcs = model._jit_prefill_dense(
             model._params, chunk, jnp.asarray(t0, jnp.int32),
             jnp.asarray(n_valid, jnp.int32), kcs, vcs,
             jnp.asarray(0, jnp.int32))
-        t0 += n_valid
     tokens, logits = [], []
     logits.append(np.asarray(last))
     tokens.append(int(sampler(logits[-1], rng)))

@@ -513,9 +513,10 @@ _NT_DIMS = (((1,), (1,)), ((), ()))
 def _page_update(q, kj, vj, valid, m, l, acc, scale=None):
     """One page of online softmax inside the paged kernels: q [R, D]
     against kj/vj [N, D] under valid [R, N]; carry m/l [R, 1], acc
-    [R, D] (fp32). Prefill calls it per head (R = chunk rows, N = page)
-    with float32 operands and q already scaled: the same ops in the
-    same order as _fwd_kernel's block body. Decode calls it once for
+    [R, D] (fp32). Prefill calls it per head (R = N = page: one query
+    tile of the chunk against one page) with float32 operands and q
+    already scaled: the same ops in the same order as _fwd_kernel's
+    block body. Decode calls it once for
     all heads (R = H, N = page*H, `valid` holding the head-diagonal)
     with operands in the pool's dtype and `scale` to apply to the
     float32 product."""
@@ -703,27 +704,39 @@ def paged_flash_decode(q, k_pool, v_pool, block_tables, seq_lens,
     return (res[0], res[1][..., 0]) if need_lse else res[0]
 
 
+def _tile_length(prm, i, page):
+    """Live KV length the query tile `i` of a prefill chunk sees: its
+    own page and no later one, the chunk's live length at most. prm =
+    (t0, L) as _paged_prefill_kernel takes them."""
+    return jnp.minimum(prm[1], prm[0] + (i + 1) * page)
+
+
 def _paged_prefill_kernel(lay_ref, bt_ref, prm_ref, q_ref, k_ref, v_ref,
                           o_ref, acc_ref, m_ref, l_ref, kf_ref, vf_ref,
                           *, page: int, scale: float):
-    """One page program of the chunked-prefill grid, all heads per
-    program: the chunk's C query rows (positions t0..t0+C-1) against
-    the live pages of ONE slot's block table — its own freshly written
-    page, the last live one, included, so in-chunk attention is causal
-    by the q_pos >= k_pos mask. q_ref [H, C, Dh]; k_ref/v_ref
-    [page*H, Dh], rows (token, head): a head's [page, Dh] is every
-    H-th row, read by a strided load from a float32 copy of the page
-    (kf_ref/vf_ref; sublane strides are a 32-bit affair), the float32
-    operands _fwd_kernel's block body takes. prm_ref carries (t0, L) where
-    L = t0 + valid chunk rows; padded chunk rows (q_pos >= L) emit
-    garbage the caller slices off, and their KV rows are masked from
-    every valid query by k_pos < L."""
+    """One (query tile, page) program of the chunked-prefill grid, all
+    heads per program. A chunk is C = n * page query rows of ONE slot at
+    positions t0..t0+C-1; tile i is its i-th page of rows (positions
+    t0 + i*page ..), run against the live pages of the slot's block
+    table up to the tile's own page — freshly written, the last one the
+    tile visits, so in-chunk attention is causal by the page bound and
+    the q_pos >= k_pos mask. Each tile is the program a page-sized chunk
+    at t0 + i*page would be: the same pages in the same order against
+    the same [page, Dh] blocks, the carry re-initialised at its first
+    page. q_ref [H, page, Dh]; k_ref/v_ref [page*H, Dh], rows (token,
+    head): a head's [page, Dh] is every H-th row, read by a strided load
+    from a float32 copy of the page (kf_ref/vf_ref; sublane strides are
+    a 32-bit affair), the float32 operands _fwd_kernel's block body
+    takes. prm_ref carries (t0, L) where L = t0 + valid chunk rows;
+    padded chunk rows (q_pos >= L) emit garbage the caller slices off,
+    and their KV rows are masked from every valid query by k_pos < L."""
     from jax.experimental import pallas as pl
 
-    H, chunk, Dh = q_ref.shape
-    p = pl.program_id(0)
-    t0 = prm_ref[0]
-    L = prm_ref[1]
+    H, tile, Dh = q_ref.shape
+    i = pl.program_id(0)
+    p = pl.program_id(1)
+    t0 = prm_ref[0] + i * page
+    L = _tile_length(prm_ref, i, page)
 
     @pl.when(p == 0)
     def _init():
@@ -731,9 +744,9 @@ def _paged_prefill_kernel(lay_ref, bt_ref, prm_ref, q_ref, k_ref, v_ref,
 
     @pl.when(p * page < L)
     def _visit():
-        q_pos = t0 + jax.lax.broadcasted_iota(jnp.int32, (chunk, page), 0)
+        q_pos = t0 + jax.lax.broadcasted_iota(jnp.int32, (tile, page), 0)
         k_pos = p * page + jax.lax.broadcasted_iota(jnp.int32,
-                                                    (chunk, page), 1)
+                                                    (tile, page), 1)
         valid = (k_pos < L) & (q_pos >= k_pos)
         kf_ref[...] = k_ref[...].astype(jnp.float32)
         vf_ref[...] = v_ref[...].astype(jnp.float32)
@@ -743,7 +756,7 @@ def _paged_prefill_kernel(lay_ref, bt_ref, prm_ref, q_ref, k_ref, v_ref,
                 q_ref[h].astype(jnp.float32) * scale, kf_ref[rows, :],
                 vf_ref[rows, :], valid, m_ref[h], l_ref[h], acc_ref[h])
 
-    @pl.when(p == pl.num_programs(0) - 1)
+    @pl.when(p == pl.num_programs(1) - 1)
     def _finalize():
         l_f = l_ref[...]
         o_ref[...] = (acc_ref[...] / jnp.where(l_f == 0, 1.0, l_f)
@@ -760,20 +773,23 @@ def _paged_prefill_call(layer, block_table, prm, q_heads, k_rows, v_rows,
     page = k_rows.shape[2] // H
     kernel = functools.partial(_paged_prefill_kernel, page=page,
                                scale=1.0 / (Dh ** 0.5))
-    whole = pl.BlockSpec((H, C, Dh), lambda p, lay, bt, prm_: (0, 0, 0))
+    tile = pl.BlockSpec((H, page, Dh),
+                        lambda i, p, lay, bt, prm_: (0, i, 0))
     live = pl.BlockSpec(
         (None, None, page * H, Dh),
-        lambda p, lay, bt, prm_: (
-            lay[0], bt[jnp.minimum(p, _last_live_page(prm_[1], page))],
+        lambda i, p, lay, bt, prm_: (
+            lay[0],
+            bt[jnp.minimum(p, _last_live_page(_tile_length(prm_, i, page),
+                                              page))],
             0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
-        grid=(block_table.shape[0],),
-        in_specs=[whole, live, live],
-        out_specs=whole,
-        scratch_shapes=[pltpu.VMEM((H, C, Dh), jnp.float32),
-                        pltpu.VMEM((H, C, 1), jnp.float32),
-                        pltpu.VMEM((H, C, 1), jnp.float32),
+        grid=(C // page, block_table.shape[0]),
+        in_specs=[tile, live, live],
+        out_specs=tile,
+        scratch_shapes=[pltpu.VMEM((H, page, Dh), jnp.float32),
+                        pltpu.VMEM((H, page, 1), jnp.float32),
+                        pltpu.VMEM((H, page, 1), jnp.float32),
                         pltpu.VMEM((page * H, Dh), jnp.float32),
                         pltpu.VMEM((page * H, Dh), jnp.float32)],
     )
@@ -787,16 +803,23 @@ def _paged_prefill_call(layer, block_table, prm, q_heads, k_rows, v_rows,
 def paged_flash_prefill(q_chunk, k_pool, v_pool, block_table, t0,
                         n_valid, layer=None, interpret=None):
     """Chunked-prefill attention for ONE slot: the prompt chunk's
-    queries (C rows at offset t0, C == page_size) against the slot's
-    block table up to the chunk's own page — which must already be
-    written into the pool (kvcache append, then this kernel; causal
-    in-chunk by construction).
+    queries (C rows at offset t0, C a whole number of pages) against the
+    slot's block table up to each row's own page — the chunk's pages
+    must already be written into the pool (kvcache append, then this
+    kernel; causal in-chunk by construction). One grid step per (query
+    tile of one page, table page): a chunk of n pages gives row for row
+    the bits of the same rows fed as n page-sized chunks.
 
     q_chunk [C, H, Dh]; k_pool/v_pool and `layer` as paged_flash_decode
     takes them; block_table [MP] int32; t0 = chunk offset (multiple of
     page_size); n_valid = live rows in this chunk (< C only for the
     prompt's tail chunk). Returns [C, H, Dh]; rows past n_valid are
     padding garbage the caller slices off."""
+    page = k_pool.shape[-3]
+    if q_chunk.shape[0] % page:
+        raise ValueError(
+            f"a prefill chunk is a whole number of pages, got "
+            f"{q_chunk.shape[0]} rows at page {page}")
     t0 = jnp.asarray(t0, jnp.int32)
     prm = jnp.stack([t0, t0 + jnp.asarray(n_valid, jnp.int32)])
     out = _paged_prefill_call(
@@ -881,14 +904,14 @@ def _paged_kernel_fits(page, H, Dh, itemsize):
     (the MXU contraction and every block's last dim); H and page
     multiples of the dtype's sublane tile (H so that the pool's
     [page*H, Dh] view is the pool's own bytes and a head's rows sit at
-    a whole sublane stride, page so that the chunk's [C, Dh] tiles are
-    whole); and the blocks inside _PAGED_VMEM_BUDGET."""
+    a whole sublane stride, page so that a query tile's [page, Dh] tiles
+    are whole); and the blocks inside _PAGED_VMEM_BUDGET."""
     sub = 32 // itemsize
     if Dh % 128 or H % sub or page % sub:
         return False
     rows = page * H * Dh
     return (4 * rows * itemsize + 2 * rows * 4      # K, V pages; copies
-            + 4 * rows * itemsize                   # q chunk and output
+            + 4 * rows * itemsize                   # q tile and output
             + rows * 4 + 2 * page * H * 128 * 4     # acc; m, l (padded)
             <= _PAGED_VMEM_BUDGET)
 
@@ -904,11 +927,11 @@ def paged_attention_impl(page, H, Dh, dtype):
 def paged_attention(q, k_pools, v_pools, layer, block_tables, lengths,
                     q_starts):
     """The serving step functions' attention over a block table: q
-    [S, R, H, Dh] (R = 1: one decode row per slot; R = page with S = 1:
-    one prefill chunk), k_pools/v_pools the whole pools
-    [L, P, page, H, Dh], layer a Python int, block_tables [S, MP],
-    lengths [S] live KV tokens, q_starts [S] position of q row 0.
-    Returns [S, R, H, Dh].
+    [S, R, H, Dh] (R = 1: one decode row per slot; S = 1 and R a whole
+    number of pages: one prefill chunk of one slot), k_pools/v_pools the
+    whole pools [L, P, page, H, Dh], layer an int (a Python one or a
+    traced one), block_tables [S, MP], lengths [S] live KV tokens,
+    q_starts [S] position of q row 0. Returns [S, R, H, Dh].
 
     On the TPU, at shapes _paged_kernel_fits admits, the pallas kernels
     read the live pages straight from the pool; everywhere else
@@ -922,10 +945,10 @@ def paged_attention(q, k_pools, v_pools, layer, block_tables, lengths,
     if q.shape[1] == 1:
         return paged_flash_decode(q[:, 0], k_pools, v_pools, block_tables,
                                   lengths, layer=layer)[:, None]
-    if q.shape[0] != 1 or q.shape[1] != page:
+    if q.shape[0] != 1:
         raise ValueError(
-            f"paged_attention takes one row per slot or one page-sized "
-            f"chunk of one slot, got q {q.shape} at page {page}")
+            f"paged_attention takes one row per slot or one chunk of one "
+            f"slot, got q {q.shape}")
     return paged_flash_prefill(q[0], k_pools, v_pools, block_tables[0],
                                q_starts[0], lengths[0] - q_starts[0],
                                layer=layer)[None]

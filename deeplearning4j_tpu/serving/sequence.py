@@ -64,6 +64,8 @@ from collections import deque
 
 import numpy as np
 
+from deeplearning4j_tpu.nn.transformer import (PREFILL_CHUNK_PAGES,
+                                               prefill_plan)
 from deeplearning4j_tpu.ops.pallas_attention import paged_pages_visited
 from deeplearning4j_tpu.runtime import telemetry
 from deeplearning4j_tpu.runtime.chaos import \
@@ -795,9 +797,11 @@ class PagedSequenceScheduler:
 
     The carry-slot scheduler above gathers/scatters h/c rows; here the
     per-slot state is KV in a bounded ``PagedKVCache`` instead, and
-    every iteration interleaves at most ONE page-sized prefill chunk
-    (bounded work — a long prompt can never stall the running batch)
-    with one slot-batched decode step over every fully-prefilled slot.
+    every iteration interleaves at most ONE prefill pass of one slot —
+    the next chunk of ``nn.transformer.prefill_plan``, up to
+    ``PREFILL_CHUNK_PAGES[-1]`` KV pages of the prompt (bounded work —
+    a long prompt can never stall the running batch) — with one
+    slot-batched decode step over every fully-prefilled slot.
     Admission, buckets, per-step deadlines, ManualClock/poll()/drain(),
     and the dl4j_seq_* metric families are the same discipline as
     ``SequenceScheduler``; pool exhaustion surfaces as the typed
@@ -818,14 +822,17 @@ class PagedSequenceScheduler:
     ``sequence.step`` (child ``sequence.fetch``) and
     ``sequence.sample``; a request that ends, done or failed, leaves
     one instant ``sequence.request`` with its whole timeline.
+    ``sequence.prefill`` carries the pass: ``chunk`` prompt tokens in a
+    chunk of ``bucket`` tokens (the executable's length).
     ``sequence.step`` and ``sequence.prefill`` say what the dispatcher
     chose for their attention: ``attend`` (``"pallas"`` or
     ``"reference"``, the model's ``attend_impl()``), ``pages_visited``
-    (the live pages of the step's live slots on the kernel path, their
-    whole tables on the reference path: derived by
+    (the live pages of the step's live slots, or of the chunk's query
+    tiles of one page, on the kernel path, their whole tables on the
+    reference path: derived by
     ``ops.pallas_attention.paged_pages_visited`` from the kernels' own
-    rule, not counted on the device) and ``pages_table`` (live slots x
-    table width).
+    rule, not counted on the device) and ``pages_table`` (live slots,
+    or query tiles, x table width).
     """
 
     def __init__(self, model, *, num_pages, slot_buckets=None,
@@ -881,7 +888,7 @@ class PagedSequenceScheduler:
         self._closed = False
         #: (live_decode_slots, bucket) per decode dispatch
         self.occupancy = []
-        #: prompt chunks prefilled (the interleave record)
+        #: prefill passes dispatched (the interleave record)
         self.prefill_chunks = 0
         reg = telemetry.get_registry()
         self._registry = reg
@@ -1079,28 +1086,29 @@ class PagedSequenceScheduler:
         self._end_req(req)
 
     def _prefill_one(self, req, parent=None):
-        """Dispatch ONE page-sized prompt chunk for one slot: allocate
-        the chunk's page, append its K/V, attend causally over the
-        table so far. Completing the prompt registers it for prefix
-        sharing and samples the first token. Returns True on progress;
-        a pool-exhausted or chaos-injected failure fails THIS request
+        """Dispatch ONE prefill pass for one slot, the next of the
+        prompt's ``prefill_plan``: allocate the pages its tokens fill,
+        append their K/V, attend causally over the table so far.
+        Completing the prompt registers it for prefix sharing and
+        samples the first token. Returns True on progress; a
+        pool-exhausted or chaos-injected failure fails THIS request
         only (typed, 429 at the HTTP tier). `parent` is the id of the
         iteration's span."""
         import jax.numpy as jnp
 
         page = self.model.page_size
         T = int(req.tokens.shape[0])
-        t0 = req.prefilled
-        n_valid = min(page, T - t0)
+        t0, n_valid, C = prefill_plan(T, req.prefilled, page, self._mp)[0]
         t0c = self.clock()
         if req.first_chunk_at is None:
             req.first_chunk_at = t0c
         req.chunks += 1
         try:
-            pg = self.cache.alloc(1)[0]
-            req.pages.append(pg)
-            req.block_row[t0 // page] = pg
-            chunk = np.zeros((page,), np.int32)
+            # all of the pass's pages or none
+            pages = self.cache.alloc(self.cache.pages_for(n_valid))
+            req.pages.extend(pages)
+            req.block_row[t0 // page:t0 // page + len(pages)] = pages
+            chunk = np.zeros((C,), np.int32)
             chunk[:n_valid] = req.tokens[t0:t0 + n_valid]
             # chaos seam INSIDE the failure try: an injected raise
             # fails this prefill like an organic dispatch error
@@ -1115,12 +1123,16 @@ class PagedSequenceScheduler:
             return True                     # progress: the slot freed
         finally:
             t1c = self.clock()
+            # what each query tile of one page sees: its own page and
+            # no later one
+            tiles = np.minimum(t0 + n_valid,
+                               t0 + page * np.arange(1, C // page + 1))
             self._registry.add_span(
                 "sequence.prefill", "serving", t0c, t1c - t0c,
                 parent=parent, rid=req.stream_id, model=self.name,
-                chunk=n_valid, attend=self._attend,
-                pages_visited=self._pages_visited(t0 + n_valid),
-                pages_table=self._mp)
+                chunk=n_valid, bucket=C, attend=self._attend,
+                pages_visited=self._pages_visited(tiles),
+                pages_table=len(tiles) * self._mp)
         req.prefilled += n_valid
         req.seq_len = req.prefilled
         self.prefill_chunks += 1
@@ -1254,7 +1266,7 @@ class PagedSequenceScheduler:
 
     def _iterate_locked(self):
         """One iteration: expire -> refill (prefix adoption) -> at most
-        ONE prefill chunk -> one slot-batched decode step. Returns the
+        ONE prefill pass -> one slot-batched decode step. Returns the
         progress count (0 = idle). An iteration that found anything to
         do is one ``sequence.iteration`` span, its parts the children
         (class docstring)."""
@@ -1351,11 +1363,12 @@ class PagedSequenceScheduler:
     @telemetry.phase("warm")
     def warm(self, cache=None):
         """Precompile the decode executable for EVERY slot bucket plus
-        the (bucket-independent) prefill chunk executable, so a serving
-        process generates its first token hot. Returns {bucket: {...},
-        "prefill": {...}} for fresh compiles. Signatures mirror the
-        live dispatch EXACTLY (host-numpy staging arrays + the live
-        pool handles)."""
+        the (bucket-independent) prefill executable of every chunk
+        length ``prefill_plan`` can return, so a serving process
+        generates its first token hot. Returns {bucket: {...},
+        "prefill": {...} (one page), "prefill<n>": {...} (n pages)} for
+        fresh compiles. Signatures mirror the live dispatch EXACTLY
+        (host-numpy staging arrays + the live pool handles)."""
         import jax.numpy as jnp
 
         report = {}
@@ -1369,15 +1382,19 @@ class PagedSequenceScheduler:
             if status is not None:
                 report[int(S)] = {"key": key, "status": status,
                                   "seconds": round(secs, 3)}
-        chunk = np.zeros((self.model.page_size,), np.int32)
         bt = np.zeros((self._mp,), np.int32)
-        key, status, secs = self.model._jit_prefill.warm(
-            self.model._params, chunk, jnp.asarray(0, jnp.int32),
-            jnp.asarray(1, jnp.int32), self.cache.k_pools,
-            self.cache.v_pools, bt, cache=cache)
-        if status is not None:
-            report["prefill"] = {"key": key, "status": status,
-                                 "seconds": round(secs, 3)}
+        for n in PREFILL_CHUNK_PAGES:
+            if n > self._mp:
+                break
+            chunk = np.zeros((n * self.model.page_size,), np.int32)
+            key, status, secs = self.model._jit_prefill.warm(
+                self.model._params, chunk, jnp.asarray(0, jnp.int32),
+                jnp.asarray(1, jnp.int32), self.cache.k_pools,
+                self.cache.v_pools, bt, cache=cache)
+            if status is not None:
+                report["prefill" if n == 1 else f"prefill{n}"] = {
+                    "key": key, "status": status,
+                    "seconds": round(secs, 3)}
         return report
 
     def close(self, drain=True):

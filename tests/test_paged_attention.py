@@ -7,7 +7,9 @@ What must hold (the ISSUE 19 kernel acceptance, as ISSUE 27 left it):
 - the chunked-PREFILL kernel (page-sized prompt chunk attending
   causally over the table so far) is BITWISE the dense flash kernel's
   rows for every chunk — aligned, padded and bf16 grids, with the pool
-  pages physically scattered;
+  pages physically scattered; a chunk of several pages (ISSUE 31: one
+  grid step per query tile of one page and table page) is BITWISE, row
+  for row, the same rows fed as page-sized chunks;
 - the paged DECODE kernel (one query row per slot, K/V read through
   the slot's block table) equals the dense flash kernel on the same
   tokens to a rounding of the output's dtype. It was bitwise until
@@ -91,6 +93,10 @@ def _assert_within_rounding(got, want, dtype, what="", wider=1):
     want = np.asarray(want, np.float64)
     err = np.abs(got - want) / np.maximum(np.abs(want), 0.25)
     assert err.max() <= eps, f"{what}: {err.max():.3e} over {eps:.1e}"
+
+
+def _bits(a):
+    return np.asarray(a).view(np.uint8)
 
 
 GRIDS = [
@@ -217,6 +223,89 @@ class TestPagedPrefillParity:
                 f"chunk {c} diverged from the dense kernel"
 
 
+class TestMultiPagePrefill:
+    """ISSUE 31: a chunk of n whole pages of one slot. Every query tile
+    of one page sees the pages a page-sized chunk at its offset sees, in
+    the same order, against the same blocks."""
+
+    CHUNKS = [
+        # pages in the chunk, valid rows (page 4, the chunk starts at 4)
+        pytest.param(2, 8, id="2-pages-full"),
+        pytest.param(2, 7, id="2-pages-ragged-last"),
+        pytest.param(4, 16, id="4-pages-full"),
+        pytest.param(4, 11, id="4-pages-ragged-and-a-padded-page"),
+    ]
+
+    @staticmethod
+    def _layout(n, n_valid, dtype, seed):
+        """A prompt of t0 + n_valid tokens scattered over the pool, its
+        table widened by the chunk's padded pages and two entries more,
+        all at the null page, which holds NaN: visiting it would leak
+        0 x NaN into a carry."""
+        rng = np.random.default_rng(seed)
+        page, H, D, P = 4, 2, 8, 16
+        t0 = page
+        _, _, kp, vp, bt = _paged_layout(t0 + n_valid, page, P, H, D,
+                                         dtype, rng)
+        wide = np.zeros((1 + n + 2,), np.int32)
+        wide[:bt.shape[0]] = bt
+        assert np.all(wide[bt.shape[0]:] == 0) and 0 not in bt
+        qc = rng.standard_normal((n * page, H, D)).astype(
+            np.float32).astype(dtype)
+        return page, t0, kp, vp, wide, qc
+
+    @pytest.mark.parametrize("dtype", [np.float32, jnp.bfloat16],
+                             ids=["f32", "bf16"])
+    @pytest.mark.parametrize("n,n_valid", CHUNKS)
+    def test_bitwise_the_same_rows_fed_page_by_page(self, n, n_valid,
+                                                    dtype):
+        page, t0, kp, vp, bt, qc = self._layout(n, n_valid, dtype, 10)
+        kp[0] = np.nan
+        vp[0] = np.nan
+        got = np.asarray(pa.paged_flash_prefill(
+            jnp.asarray(qc), jnp.asarray(kp), jnp.asarray(vp), bt, t0,
+            n_valid))
+        assert got.shape == qc.shape
+        assert np.isfinite(got[:n_valid].astype(np.float32)).all()
+        for j in range(-(-n_valid // page)):
+            rows = slice(j * page, min((j + 1) * page, n_valid))
+            want = np.asarray(pa.paged_flash_prefill(
+                jnp.asarray(qc[j * page:(j + 1) * page]), jnp.asarray(kp),
+                jnp.asarray(vp), bt, t0 + j * page,
+                rows.stop - rows.start))[:rows.stop - rows.start]
+            assert np.array_equal(_bits(got[rows]), _bits(want)), \
+                f"query tile {j} is not the page-sized chunk's rows"
+
+    @pytest.mark.parametrize("dtype", [np.float32, jnp.bfloat16],
+                             ids=["f32", "bf16"])
+    @pytest.mark.parametrize("n,n_valid", CHUNKS)
+    def test_within_the_cores_rounding(self, n, n_valid, dtype):
+        """Against ``paged_attend`` on the gathered table with all the
+        chunk's rows at once: bitwise in bf16, a couple ulp in f32, as
+        a page-sized chunk is (TestPagedAttendCore)."""
+        page, t0, kp, vp, bt, qc = self._layout(n, n_valid, dtype, 11)
+        got = np.asarray(pa.paged_flash_prefill(
+            jnp.asarray(qc), jnp.asarray(kp), jnp.asarray(vp), bt, t0,
+            n_valid))[:n_valid]
+        ref = np.asarray(pa.paged_attend(
+            jnp.asarray(qc[None]), jnp.asarray(kp)[bt[None]],
+            jnp.asarray(vp)[bt[None]], jnp.asarray([t0 + n_valid]),
+            jnp.asarray([t0])))[0, :n_valid]
+        if dtype == jnp.bfloat16:
+            assert np.array_equal(_bits(ref), _bits(got))
+        else:
+            err = np.max(np.abs(ref.astype(np.float64)
+                                - got.astype(np.float64)))
+            assert err <= 3e-7, f"core-vs-kernel {err}"
+
+    def test_a_chunk_is_whole_pages(self):
+        page, t0, kp, vp, bt, qc = self._layout(2, 7, np.float32, 12)
+        with pytest.raises(ValueError, match="whole number of pages"):
+            pa.paged_flash_prefill(jnp.asarray(qc[:page + 1]),
+                                   jnp.asarray(kp), jnp.asarray(vp), bt,
+                                   t0, page + 1)
+
+
 # ----------------------------------------------------------------------
 # the portable core (serving step functions)
 # ----------------------------------------------------------------------
@@ -268,10 +357,6 @@ class TestPagedAttendCore:
 # ----------------------------------------------------------------------
 # ISSUE 27: live pages only, the whole pool, the dispatcher
 # ----------------------------------------------------------------------
-
-def _bits(a):
-    return np.asarray(a).view(np.uint8)
-
 
 class TestLivePagesOnly:
     """Table entries past a slot's last live page point at a page full
@@ -443,6 +528,24 @@ class TestDispatcher:
         err = np.max(np.abs(np.asarray(got, np.float64)[0, :3]
                             - np.asarray(want, np.float64)[0, :3]))
         assert err <= 1e-6, f"dispatcher, chunk: {err}"
+        # a chunk of two pages of one slot goes the same way
+        qc2 = jnp.asarray(rng.standard_normal((1, 2 * page, H, Dh)).astype(
+            np.float32))
+        got = pa.paged_attention(qc2, kps, vps, 0, bts[:1],
+                                 jnp.asarray([19]), jnp.asarray([8]))
+        assert called == ([] if on_tpu else ["reference"] * 3)
+        want = real(qc2, kps[0][bts[:1]], vps[0][bts[:1]],
+                    jnp.asarray([19]), jnp.asarray([8]))
+        err = np.max(np.abs(np.asarray(got, np.float64)[0, :11]
+                            - np.asarray(want, np.float64)[0, :11]))
+        assert err <= 1e-6, f"dispatcher, chunk of two pages: {err}"
+        if on_tpu:                  # neither a row a slot nor whole pages
+            with pytest.raises(ValueError, match="whole number of pages"):
+                pa.paged_attention(qc2[:, :page + 1], kps, vps, 0, bts[:1],
+                                   jnp.asarray([19]), jnp.asarray([8]))
+            with pytest.raises(ValueError, match="one chunk of one slot"):
+                pa.paged_attention(jnp.concatenate([qc2, qc2]), kps, vps, 0,
+                                   bts, jnp.asarray(sls), jnp.asarray([8, 0]))
 
 
 class TestServedThroughTheKernels:

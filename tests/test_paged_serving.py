@@ -9,17 +9,23 @@ What must hold (the ISSUE 19 serving acceptance):
   (``dense_serial_trajectory``), ragged prompts, chunked prefill,
   prefix sharing and temperature sampling included (both paths run the
   same ``paged_attend`` core, so parity is structural);
-- scheduling: at most ONE page-sized prefill chunk per iteration
+- scheduling: at most ONE prefill pass of one slot per iteration
   interleaves with the decode batch (a long prompt never stalls
   running generations), deadlines are honored per step and free pages,
   ManualClock + thread-less poll()/drain() is deterministic;
+- the plan (ISSUE 31): ``prefill_plan`` is a pure function that covers
+  a prompt exactly once in chunks of ``PREFILL_CHUNK_PAGES`` pages,
+  largest first but for a single page left behind, never past the
+  block table's end; the scheduler, its warm-up and the dense oracle
+  take the same passes;
 - bounded HBM: pool exhaustion fails the victim request with the typed
   ``KVCacheFullError`` (submit-time when unservable at any load,
   per-slot mid-flight otherwise) while other slots keep generating;
   paged residency at >= 75 % ragged occupancy is <= 0.6x the dense
   twin's reservation (the bench A/B's correctness anchor);
-- compile discipline: ``warm()`` precompiles every slot bucket + the
-  prefill chunk and a whole ragged serve pays ZERO further compiles;
+- compile discipline: ``warm()`` precompiles every slot bucket + every
+  prefill chunk length and a whole ragged serve pays ZERO further
+  compiles;
 - sampling: deterministic per (sampler_seed, stream), streams assigned
   in submit order;
 - the HTTP tier: ``:generate`` accepts ``{"tokens": ...}`` and maps
@@ -36,7 +42,8 @@ import pytest
 import jax
 
 from deeplearning4j_tpu.nn.transformer import (
-    CausalTransformerLM, dense_serial_trajectory,
+    PREFILL_CHUNK_PAGES, CausalTransformerLM, dense_serial_trajectory,
+    prefill_plan,
 )
 from deeplearning4j_tpu.runtime import aot
 from deeplearning4j_tpu.serving import (
@@ -125,24 +132,152 @@ class TestBitwiseVsSerial:
             assert reqs[i].wait(1.0).tolist() == toks
         s.close()
 
-    def test_prefix_adoption_stays_bitwise(self):
+    @pytest.mark.parametrize("n", [13, 59])
+    def test_prefix_adoption_stays_bitwise(self, n):
         """A resubmitted prompt adopts the registered pages (no
-        prefill chunks paid) and still generates bitwise the serial
-        trajectory — shared full pages are immutable and the tail page
-        forks copy-on-write before the first append."""
+        prefill pass paid, whether the first paid one or three) and still
+        generates bitwise the serial trajectory — shared full pages are
+        immutable and the tail page forks copy-on-write before the
+        first append."""
         m = _lm()
         s, _ = _sched(m)
-        p = _prompts((13,), m.vocab, seed=9)[0]
+        p = _prompts((n,), m.vocab, seed=9)[0]
         first = s.submit(p, max_new_tokens=4, wait=False)
         s.drain()
         chunks_before = s.prefill_chunks
+        assert chunks_before == len(prefill_plan(n, 0, 8, 8)) == first.chunks
         again = s.submit(p, max_new_tokens=4, wait=False)
         s.drain()
         assert s.prefill_chunks == chunks_before  # exact adopt: zero
+        assert again.chunks == 0
         assert again.wait(1.0).tolist() == first.wait(1.0).tolist()
         toks, _ = dense_serial_trajectory(
             m, p, 4, greedy_sampler(), stream_rng(0, 1), bucket=4)
         assert again.result.tolist() == toks
+        s.close()
+
+
+# ----------------------------------------------------------------------
+# ISSUE 31: the plan of a prompt's prefill passes
+# ----------------------------------------------------------------------
+
+PAGE, MP = 8, 8          # _lm()'s page and table width (max_context 64)
+#: the issue's lengths: page-1, page, page+1, 2p+3, 4p, 4p+1, 7p, the
+#: whole context
+PLAN_LENGTHS = [7, 8, 9, 19, 32, 33, 56, 64]
+
+
+class TestPrefillPlan:
+    def test_chunk_lengths_are_the_page_and_two_more(self):
+        assert PREFILL_CHUNK_PAGES[0] == 1
+        assert list(PREFILL_CHUNK_PAGES) == sorted(set(PREFILL_CHUNK_PAGES))
+        assert len(PREFILL_CHUNK_PAGES) <= 3    # each is an executable
+
+    @pytest.mark.parametrize("n,want", [
+        (7, [(0, 7, 8)]),
+        (8, [(0, 8, 8)]),
+        (9, [(0, 9, 16)]),
+        (19, [(0, 19, 24)]),
+        (32, [(0, 16, 16), (16, 16, 16)]),
+        (33, [(0, 24, 24), (24, 9, 16)]),
+        (56, [(0, 24, 24), (24, 16, 16), (40, 16, 16)]),
+        (64, [(0, 24, 24), (24, 24, 24), (48, 16, 16)]),
+    ])
+    def test_passes_largest_first_and_no_single_page_behind(self, n, want):
+        assert prefill_plan(n, 0, PAGE, MP) == want
+
+    @pytest.mark.parametrize("n_live,want", [
+        (8, [(8, 24, 24), (32, 16, 16), (48, 16, 16)]),
+        (16, [(16, 24, 24), (40, 24, 24)]),
+        (32, [(32, 16, 16), (48, 16, 16)]),
+        (56, [(56, 8, 8)]),
+        (64, []),
+    ])
+    def test_resumes_behind_adopted_pages_inside_the_table(self, n_live,
+                                                           want):
+        """Behind an adopted prefix the plan is the plan of the pages
+        still to fill, from the first of them: its chunks hold no page
+        without a prompt token, so none runs past the table's end."""
+        assert prefill_plan(64, n_live, PAGE, MP) == want
+
+    @pytest.mark.parametrize("page,mp", [(8, 8), (8, 3), (16, 5), (128, 16)])
+    def test_covers_every_prompt_exactly_once(self, page, mp):
+        lengths = set(PREFILL_CHUNK_PAGES)
+        for n in range(1, page * mp + 1):
+            for n_live in range(0, n, page):
+                plan = prefill_plan(n, n_live, page, mp)
+                at = n_live
+                for t0, n_valid, C in plan:
+                    assert t0 == at and t0 % page == 0
+                    assert C // page in lengths and C % page == 0
+                    assert 0 < n_valid <= C
+                    assert t0 // page + C // page <= mp    # the table's end
+                    at += n_valid
+                assert at == n
+                # only a prompt's last pass is short of its chunk, and
+                # by less than a page; one page alone is a whole prompt
+                assert all(nv == C for _, nv, C in plan[:-1])
+                assert all(C - nv < page for _, nv, C in plan)
+                if n - n_live > page:
+                    assert all(C > page for _, _, C in plan)
+            assert prefill_plan(n, n, page, mp) == []
+
+    def test_resumes_on_a_page_boundary_only(self):
+        with pytest.raises(ValueError, match="page boundary"):
+            prefill_plan(40, 13, PAGE, MP)
+        assert prefill_plan(13, 13, PAGE, MP) == []     # adopted whole
+
+    @pytest.mark.parametrize("n", PLAN_LENGTHS)
+    def test_scheduler_and_oracle_take_the_same_passes(self, n):
+        """The scheduler serves a prompt of every length of the plan's
+        table bitwise as the dense oracle does, in the plan's passes:
+        one `sequence.prefill` a pass, pages allotted a pass at a
+        time."""
+        from deeplearning4j_tpu.runtime import telemetry
+
+        m = _lm()
+        s, _ = _sched(m, prefix_sharing=False)
+        p = _prompts((n,), m.vocab, seed=n)[0]
+        n_new = min(3, m.max_context - n + 1)
+        ring = telemetry.get_registry().trace
+        ring.clear()
+        req = s.submit(p, max_new_tokens=n_new, wait=False)
+        s.drain()
+        got = req.wait(1.0)
+        plan = prefill_plan(n, 0, PAGE, MP)
+        passes = [sp["args"] for sp in ring.spans()
+                  if sp["name"] == "sequence.prefill"]
+        assert [(a["chunk"], a["bucket"]) for a in passes] == \
+            [(n_valid, C) for _, n_valid, C in plan]
+        assert req.chunks == s.prefill_chunks == len(plan)
+        toks, logits = dense_serial_trajectory(
+            m, p, n_new, greedy_sampler(), stream_rng(0, 0), bucket=4)
+        assert got.tolist() == toks
+        assert np.array_equal(req.logits.view(np.uint8),
+                              logits.view(np.uint8))
+        assert s.cache.pages_in_use == 0
+        s.close()
+
+    def test_partial_adoption_plans_from_the_shared_pages(self):
+        """A prompt that a registered one prefixes adopts its two full
+        pages, prefills the rest in the plan's passes from there, and serves
+        the oracle's tokens (its chunks differ from the oracle's, so
+        the logits agree to float32 rounding, not bitwise)."""
+        m = _lm()
+        s, _ = _sched(m)
+        base = _prompts((20,), m.vocab, seed=4)[0]
+        s.submit(base, max_new_tokens=1, wait=False)
+        s.drain()
+        p = base + _prompts((18,), m.vocab, seed=6)[0]
+        before = s.prefill_chunks
+        req = s.submit(p, max_new_tokens=3, wait=False)
+        s.drain()
+        assert prefill_plan(38, 16, PAGE, MP) == [(16, 22, 24)]
+        assert req.chunks == s.prefill_chunks - before == 1
+        toks, logits = dense_serial_trajectory(
+            m, p, 3, greedy_sampler(), stream_rng(0, 1), bucket=4)
+        assert req.wait(1.0).tolist() == toks
+        np.testing.assert_allclose(req.logits, logits, rtol=0, atol=1e-5)
         s.close()
 
 
@@ -152,20 +287,22 @@ class TestBitwiseVsSerial:
 
 class TestScheduling:
     def test_prefill_interleaves_without_stalling_decode(self):
-        """A 4-chunk prompt prefills ONE chunk per iteration while an
-        already-running generation keeps producing a token every
-        iteration — the short request finishes while the long prompt
-        is still mid-prefill (bounded prefill work per step)."""
-        m = _lm(max_context=64, page_size=8)
+        """A 9-page prompt prefills ONE pass per iteration (three
+        pages each) while an already-running generation keeps
+        producing a token every iteration — the short request finishes
+        while the long prompt is still mid-prefill (bounded prefill
+        work per step)."""
+        m = _lm(max_context=128, page_size=8)
         s, _ = _sched(m, slot_buckets=(2,), prefix_sharing=False)
         short = s.submit(_prompts((4,), m.vocab)[0], max_new_tokens=3,
                          wait=False)
         s.poll()   # short: prefill + first decode -> 2 tokens
-        long = s.submit(_prompts((32,), m.vocab, seed=2)[0],
+        long = s.submit(_prompts((72,), m.vocab, seed=2)[0],
                         max_new_tokens=2, wait=False)
-        s.poll()   # long chunk 1 of 4; short token 3 -> done
+        s.poll()   # long pass 1 of 3; short token 3 -> done
         assert short.done and not long.done
-        assert long.prefilled == 8 < 32
+        assert long.prefilled == 24 < 72
+        assert len(prefill_plan(72, 0, 8, 16)) == 3
         s.drain()
         assert long.wait(1.0).shape == (2,)
         s.close()
@@ -264,6 +401,52 @@ class TestBoundedHBM:
         assert s.stats["errors"] == 1 and s.stats["completed"] == 1
         s.close()
 
+    def test_exhaustion_between_passes_returns_the_victims_pages(self):
+        """The second pass of a prompt finds the pool dry: that request
+        alone fails, the three pages of its first pass go back, and the
+        other slot generates to its end."""
+        m = _lm()
+        s, _ = _sched(m, num_pages=7, prefix_sharing=False,
+                      slot_buckets=(2,))          # capacity 6
+        a = s.submit(_prompts((9,), m.vocab)[0], max_new_tokens=6,
+                     wait=False)                  # 2 pages, 14 rows
+        b = s.submit(_prompts((40,), m.vocab, seed=3)[0],
+                     max_new_tokens=1, wait=False)
+        s.poll()                                  # a: its one pass
+        s.poll()                                  # b: 3 pages of 5
+        assert b.prefilled == 24 and s.cache.pages_in_use == 5
+        s.poll()                                  # b: two more, one is there
+        with pytest.raises(KVCacheFullError):
+            b.wait(1.0)
+        assert b.pages == [] and s.cache.pages_in_use == 2
+        s.drain()
+        assert a.wait(1.0).shape == (6,)
+        assert s.stats["errors"] == 1 and s.stats["completed"] == 1
+        assert s.cache.pages_in_use == 0
+        s.close()
+
+    def test_a_pass_takes_all_its_pages_or_none(self):
+        """A pass of three pages with two free takes none of them:
+        the request fails alone and the free list is whole."""
+        m = _lm()
+        s, _ = _sched(m, num_pages=6, prefix_sharing=False,
+                      slot_buckets=(2,))          # capacity 5
+        a = s.submit(_prompts((17,), m.vocab)[0], max_new_tokens=4,
+                     wait=False)                  # 3 pages, 20 rows
+        b = s.submit(_prompts((24,), m.vocab, seed=3)[0],
+                     max_new_tokens=1, wait=False)
+        s.poll()
+        assert s.cache.pages_in_use == 3
+        s.poll()                                  # b wants 3 of the 2 left
+        with pytest.raises(KVCacheFullError):
+            b.wait(1.0)
+        assert b.pages == [] and not np.any(b.block_row)
+        assert s.cache.pages_in_use == 3 and len(s.cache._free) == 2
+        s.drain()
+        assert a.wait(1.0).shape == (4,)
+        assert s.cache.pages_in_use == 0
+        s.close()
+
     def test_residency_le_60pct_of_dense_at_75pct_occupancy(self):
         """The acceptance anchor: with >= 75 % of the bucket's slots
         live at RAGGED lengths, the paged pool's live bytes are
@@ -275,7 +458,7 @@ class TestBoundedHBM:
         lens = (10, 14, 18, 22, 26, 30)     # 6/8 slots = 75 %
         reqs = [s.submit(p, max_new_tokens=24, wait=False)
                 for p in _prompts(lens, m.vocab)]
-        for _ in range(20):                 # past all 18 prefill chunks
+        for _ in range(20):                 # past every prefill pass
             s.poll()
         assert s.active_slots == 6
         assert s.occupancy[-1] == (6, 8)
@@ -297,15 +480,22 @@ class TestBoundedHBM:
 class TestCompileDiscipline:
     def test_warm_then_zero_steady_state_compiles(self, fresh_cache):
         """warm() precompiles one decode executable per slot bucket
-        plus the prefill chunk; a whole ragged serve afterwards —
-        prefill, decode, prefix adoption, finishes — pays ZERO
+        plus one prefill executable per chunk length of the plan; a
+        whole ragged serve afterwards — prefill in chunks of every
+        length, decode, prefix adoption, finishes — pays ZERO
         compiles."""
         m = _lm()
         s, _ = _sched(m, slot_buckets=(2, 4))
-        s.warm()
+        rep = s.warm()
+        assert sorted(k for k in rep if isinstance(k, str)) == sorted(
+            "prefill" if n == 1 else f"prefill{n}"
+            for n in PREFILL_CHUNK_PAGES)
+        lens = (3, 9, 17, 6, 40)
+        assert {C // 8 for n in lens for _, _, C in
+                prefill_plan(n, 0, 8, 8)} == set(PREFILL_CHUNK_PAGES)
         with aot.CompileWatch(fresh_cache) as watch:
             reqs = [s.submit(p, max_new_tokens=5, wait=False)
-                    for p in _prompts((3, 9, 17, 6), m.vocab)]
+                    for p in _prompts(lens, m.vocab)]
             s.drain()
             for r in reqs:
                 r.wait(1.0)

@@ -25,6 +25,7 @@ import jax
 import jax.numpy as jnp
 import pytest
 
+from deeplearning4j_tpu.nn.transformer import PREFILL_CHUNK_PAGES
 from deeplearning4j_tpu.ops import pallas_attention as pa
 
 # chip_smoke.py's kernel-phase shapes (B, H, T, D), bf16
@@ -97,11 +98,12 @@ def _paged_cases(device=None, dtype=jnp.bfloat16):
             return pa.paged_flash_prefill(qc, kp, vp, bt, t0, n_valid,
                                           layer=layer, interpret=False)
 
-        yield (f"paged prefill, {tag}", prefill,
-               (_sds((p["page"], p["H"], p["Dh"]), dtype, device), pool,
-                pool, _sds((p["MP"],), jnp.int32, device),
-                _sds((), jnp.int32, device), _sds((), jnp.int32, device))
-               + layer)
+        for n in PREFILL_CHUNK_PAGES:     # every chunk length kept
+            yield (f"paged prefill of {n} pages, {tag}", prefill,
+                   (_sds((n * p["page"], p["H"], p["Dh"]), dtype, device),
+                    pool, pool, _sds((p["MP"],), jnp.int32, device),
+                    _sds((), jnp.int32, device),
+                    _sds((), jnp.int32, device)) + layer)
 
 
 def _all_cases(device=None):
@@ -159,14 +161,17 @@ def test_dispatch_rule_rejects_unaligned_blocks():
     assert pa._choose_impl(8192, on_tpu=True, kernel_fits=True) == "flash"
 
 
+@pytest.mark.parametrize(
+    "step", ["decode"] + [f"prefill-{n}-pages" for n in PREFILL_CHUNK_PAGES])
 def test_the_benchmarks_step_functions_compile_with_the_kernels(
-        v5e, monkeypatch):
-    """The whole `_decode_paged` and `_prefill_paged` of the benchmark's
-    configuration, from shapes, for the described chip, the dispatcher
-    steered to the kernels from here (this process sees a CPU): 24
-    custom calls each, and temporaries far under a pool's 4 GB — the
-    scatter before each layer's kernel updates the donated pool in
-    place and the kernel's pool operand is no copy."""
+        v5e, monkeypatch, step):
+    """The whole `_decode_paged`, and `_prefill_paged` at every chunk
+    length the scheduler warms, of the benchmark's configuration, from
+    shapes, for the described chip, the dispatcher steered to the
+    kernels from here (this process sees a CPU): 24 custom calls each,
+    and temporaries far under a pool's 4 GB — the page updates before
+    each layer's kernel write the donated pool in place and the
+    kernel's pool operand is no copy."""
     from deeplearning4j_tpu.nn.transformer import CausalTransformerLM
 
     p = PAGED_BENCH
@@ -191,20 +196,19 @@ def test_the_benchmarks_step_functions_compile_with_the_kernels(
     assert model.attend_impl() == "pallas"
     pool = sd(p["L"], p["P"], p["page"], p["H"], p["Dh"])
     i32 = jnp.int32
-    steps = {
-        "_decode_paged": (model._decode_paged, (2, 3), (
+    if step == "decode":
+        fn, donate, args = model._decode_paged, (2, 3), (
             model._params, sd(p["S"], dtype=i32), pool, pool,
-            sd(p["S"], p["MP"], dtype=i32), sd(p["S"], dtype=i32))),
-        "_prefill_paged": (model._prefill_paged, (4, 5), (
-            model._params, sd(p["page"], dtype=i32), sd(dtype=i32),
-            sd(dtype=i32), pool, pool, sd(p["MP"], dtype=i32))),
-    }
+            sd(p["S"], p["MP"], dtype=i32), sd(p["S"], dtype=i32))
+    else:
+        pages = int(step.split("-")[1])
+        fn, donate, args = model._prefill_paged, (4, 5), (
+            model._params, sd(pages * p["page"], dtype=i32), sd(dtype=i32),
+            sd(dtype=i32), pool, pool, sd(p["MP"], dtype=i32))
     with jax.enable_x64(False):
-        for name, (fn, donate, args) in steps.items():
-            compiled = jax.jit(fn, donate_argnums=donate).lower(
-                *args).compile()
-            assert compiled.as_text().count("tpu_custom_call") >= p["L"], \
-                f"{name}: the kernels are not in the compiled step"
-            temp = compiled.memory_analysis().temp_size_in_bytes
-            assert temp < 2 ** 30, \
-                f"{name}: {temp / 1e9:.2f} GB of temporaries (a pool copy?)"
+        compiled = jax.jit(fn, donate_argnums=donate).lower(*args).compile()
+    assert compiled.as_text().count("tpu_custom_call") >= p["L"], \
+        f"{step}: the kernels are not in the compiled step"
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < 2 ** 30, \
+        f"{step}: {temp / 1e9:.2f} GB of temporaries (a pool copy?)"

@@ -1,5 +1,6 @@
 """The per-layer readers that ISSUE 26 adds under perfbench/metrics/ (and
-ISSUE 27's `paged_attend.pages_visited_share`), fed
+ISSUE 27's `paged_attend.pages_visited_share`, ISSUE 31's
+`seq.prefill_tokens_per_pass_mean` and its decode-cell twin), fed
 hand-made spans: each returns the number worked out by hand below, None
 where the ring dropped spans (a truncated window gives no number) and
 None, without raising, where the program left nothing to read (the
@@ -34,6 +35,8 @@ EXPECTED = {
     "fit.sync_wait_ms_mean": 97.0,
     "fit.outside_step_ms_mean": 2.8,
     "paged_attend.pages_visited_share": 100.0 * 129 / 304,
+    "seq.prefill_tokens_per_pass_mean": 200.0,
+    "seq.prefill_tokens_per_pass_mean.decode": 200.0,
 }
 SETUP = ("setup.weights_init_s", "setup.warm_s")
 
@@ -86,11 +89,14 @@ def _fill(reg):
     # 5, 7, 9 ms -> median 7; one of three carries a chunk; pages 300,
     # 319, 310 -> 319. Their steps read 40 of 96, 45 of 96 and 44 of 112
     # pages -> 129 of 304. One iteration before the window counts nowhere.
+    # Two prefill passes in the window: 300 tokens in a chunk of 512,
+    # and 100 in a span without `bucket` (the parent commit's) -> 200.
     _iteration(reg, 5.0, 1.0, 999, [
-        ("sequence.prefill", 0.4),
+        ("sequence.prefill", 0.4, {"chunk": 999, "bucket": 1024}),
         ("sequence.step", 0.1, _pages(16, 16)), ("sequence.sample", 0.5)])
     _iteration(reg, 10.0, 100 * ms, 300, [
-        ("sequence.admit", 1 * ms), ("sequence.prefill", 30 * ms),
+        ("sequence.admit", 1 * ms),
+        ("sequence.prefill", 30 * ms, {"chunk": 300, "bucket": 512}),
         ("sequence.decode_prep", 2 * ms),
         ("sequence.step", 60 * ms, _pages(40, 96)),
         ("sequence.sample", 5 * ms)])
@@ -102,6 +108,7 @@ def _fill(reg):
         ("sequence.admit", 1 * ms), ("sequence.decode_prep", 1 * ms),
         ("sequence.step", 66 * ms, _pages(44, 112)),
         ("sequence.sample", 9 * ms)])
+    reg.add_span("sequence.prefill", "serving", 10.6, 4 * ms, chunk=100)
     # two requests count: time to first token 100 and 250 ms (median 175,
     # 95th 242.5), wait for the first chunk 10 and 50 ms (95th 48),
     # service 90 and 200 ms (median 145), gaps 100, 110 and 80 ms
@@ -168,6 +175,13 @@ def test_pages_visited_share_is_none_where_a_step_lacks_the_args(filled):
     assert _read("paged_attend.pages_visited_share") is None
 
 
+@pytest.mark.parametrize("name", ["seq.prefill_tokens_per_pass_mean",
+                                  "seq.prefill_tokens_per_pass_mean.decode"])
+def test_tokens_per_pass_is_none_where_a_pass_lacks_its_chunk(name, filled):
+    filled.add_span("sequence.prefill", "serving", 10.7, 0.004)
+    assert _read(name) is None
+
+
 def test_idle_with_work_is_none_where_the_top_ten_hide_the_waiting(filled):
     """tracered keeps the ten largest names of idle_gaps: ten of them
     and no sequence.idle cannot be told from no waiting at all; fewer
@@ -218,7 +232,8 @@ def test_manifest_lists_the_new_metrics_with_their_readers():
     by = {m["name"]: m for m in manifest["per_layer"]}
     cells = {"seq.prefill_wait_p95_ms": P, "seq.prefill_service_p50_ms": P,
              "seq.ttft_inside_p50_ms": P, "seq.ttft_inside_p95_ms": P,
-             "prefill.idle_with_work_share": P, "fit.dispatch_ms_mean": R,
+             "prefill.idle_with_work_share": P,
+             "seq.prefill_tokens_per_pass_mean": P, "fit.dispatch_ms_mean": R,
              "fit.sync_wait_ms_mean": R, "fit.outside_step_ms_mean": R}
     for name in EXPECTED:
         assert by[name]["workloads"] == [cells.get(name, D)], name

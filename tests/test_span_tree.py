@@ -23,7 +23,9 @@ import time
 import numpy as np
 import pytest
 
-from deeplearning4j_tpu.nn.transformer import CausalTransformerLM
+from deeplearning4j_tpu.nn.transformer import (PREFILL_CHUNK_PAGES,
+                                               CausalTransformerLM,
+                                               prefill_plan)
 from deeplearning4j_tpu.runtime import telemetry
 from deeplearning4j_tpu.runtime.telemetry import MetricsRegistry
 from deeplearning4j_tpu.serving import (
@@ -188,21 +190,23 @@ class TestSpanRecord:
 # the paged scheduler's tree and the request's timeline
 # ----------------------------------------------------------------------
 class TestPagedSchedulerSpans:
-    def test_one_prompt_three_chunks_four_tokens(self, ring):
-        """20 prompt tokens at a page of 8 are three chunks; the third
-        iteration finishes the prompt and decodes, three more decode."""
+    def test_one_prompt_two_passes_four_tokens(self, ring):
+        """44 prompt tokens at a page of 8 are six pages, which the plan
+        takes in two passes of three; the second iteration finishes the
+        prompt and decodes, two more decode."""
         s = _paged(_lm())
-        req = s.submit(_prompt(20), max_new_tokens=4, wait=False)
+        assert prefill_plan(44, 0, 8, s._mp) == [(0, 24, 24), (24, 20, 24)]
+        req = s.submit(_prompt(44), max_new_tokens=4, wait=False)
         s.drain()
         assert req.wait(1.0).shape == (4,)
         spans = ring.spans()
         by = _by_name(spans)
         its = by["sequence.iteration"]
-        assert len(its) == 5
+        assert len(its) == 4
         assert all(i["parent"] is None and i["rid"] is None for i in its)
-        assert [i["args"]["prefill"] for i in its] == [1, 1, 1, 0, 0]
-        assert [i["args"]["decode_slots"] for i in its] == [0, 0, 1, 1, 1]
-        assert [i["args"]["pages_in_use"] for i in its] == [1, 2, 3, 3, 3]
+        assert [i["args"]["prefill"] for i in its] == [1, 1, 0, 0]
+        assert [i["args"]["decode_slots"] for i in its] == [0, 1, 1, 1]
+        assert [i["args"]["pages_in_use"] for i in its] == [3, 6, 6, 6]
         assert all(i["args"]["active"] == 1 and i["args"]["pending"] == 0
                    for i in its)
         kids = {}
@@ -215,7 +219,6 @@ class TestPagedSchedulerSpans:
         decode = ["sequence.decode_prep", "sequence.step",
                   "sequence.sample"]
         assert tree == [
-            ["sequence.admit", "sequence.prefill"],
             ["sequence.admit", "sequence.prefill"],
             ["sequence.admit", "sequence.prefill",
              "sequence.prefill_finish"] + decode,
@@ -233,12 +236,13 @@ class TestPagedSchedulerSpans:
                 "model": s.name, "slots": 1, "bucket": 2,
                 "attend": "reference", "pages_visited": s._mp,
                 "pages_table": s._mp}
-        assert all(p["args"]["attend"] == "reference"
-                   and p["args"]["pages_visited"] == s._mp
-                   == p["args"]["pages_table"]
-                   for p in by["sequence.prefill"])
-        assert [p["args"]["chunk"] for p in by["sequence.prefill"]] == \
-            [8, 8, 4]
+        # a pass: its tokens, the chunk it ran in, and a whole table
+        # for each of the chunk's query tiles of one page
+        assert [p["args"] for p in by["sequence.prefill"]] == [
+            {"model": s.name, "chunk": n_valid, "bucket": C,
+             "attend": "reference", "pages_visited": C // 8 * s._mp,
+             "pages_table": C // 8 * s._mp}
+            for n_valid, C in ((24, 24), (20, 24))]
         assert by["sequence.admit"][0]["args"] == {"admitted": 1,
                                                    "adopted": 0}
         assert [x["args"]["finished"] for x in by["sequence.sample"]] == \
@@ -247,24 +251,24 @@ class TestPagedSchedulerSpans:
         mine = [sp for sp in spans if sp["rid"] is not None]
         assert {sp["rid"] for sp in mine} == {req.stream_id}
         assert sorted(sp["name"] for sp in mine) == \
-            ["sequence.prefill"] * 3 + ["sequence.prefill_finish",
+            ["sequence.prefill"] * 2 + ["sequence.prefill_finish",
                                         "sequence.request"]
         s.close()
 
     def test_request_timeline(self, ring):
         s = _paged(_lm())
-        req = s.submit(_prompt(20), max_new_tokens=4, wait=False)
+        req = s.submit(_prompt(44), max_new_tokens=4, wait=False)
         s.drain()
         assert (req.enqueued_at <= req.started_at <= req.first_chunk_at
                 < req.first_token_at < req.finished_at)
-        assert len(req.token_times) == 4 and req.chunks == 3
+        assert len(req.token_times) == 4 and req.chunks == 2
         assert req.token_times[0] == req.first_token_at
         assert req.token_times == sorted(req.token_times)
         assert req.token_times[-1] <= req.finished_at
         (ev,) = _by_name(ring.spans())["sequence.request"]
         assert ev["ph"] == "i" and ev["ts"] == req.finished_at
         assert ev["args"] == {
-            "prompt_tokens": 20, "new_tokens": 4, "chunks": 3,
+            "prompt_tokens": 44, "new_tokens": 4, "chunks": 2,
             "enqueued_at": req.enqueued_at, "started_at": req.started_at,
             "first_chunk_at": req.first_chunk_at,
             "first_token_at": req.first_token_at,
@@ -304,8 +308,8 @@ class TestPagedSchedulerSpans:
         s.poll()
         with pytest.raises(DeadlineExceededError):
             late.wait(0.1)
-        cut = s.submit(_prompt(20), max_new_tokens=2, wait=False)
-        s.poll()                            # one chunk in, then closed
+        cut = s.submit(_prompt(44), max_new_tokens=2, wait=False)
+        s.poll()                            # one pass of two, then closed
         s.close(drain=False)
         with pytest.raises(ServingClosedError):
             cut.wait(0.1)
@@ -572,8 +576,9 @@ class TestSetupPhases:
         assert all(after[p] > before[p] for p in before), (before, after)
         by = _by_name(ring.spans())
         (warm,) = by["setup.warm"]
-        compiles = by["aot.compile"]      # a fresh cache: both compile
-        assert len(compiles) == 2
+        # a fresh cache: the decode bucket and every chunk length compile
+        compiles = by["aot.compile"]
+        assert len(compiles) == 1 + len(PREFILL_CHUNK_PAGES)
         assert all(c["parent"] == warm["id"] for c in compiles)
         ring.clear()
         host.close()
