@@ -1422,12 +1422,9 @@ jax.config.update("jax_platforms", "cpu")
 import numpy as np
 from deeplearning4j_tpu.nn import (NeuralNetConfiguration, InputType,
     MultiLayerNetwork, DenseLayer, OutputLayer, Nesterovs)
-from deeplearning4j_tpu.nn.conf.recurrent import LSTM
-from deeplearning4j_tpu.nn.conf.layers import RnnOutputLayer
 from deeplearning4j_tpu.parallel.mesh import build_mesh
 from deeplearning4j_tpu.runtime import aot
-from deeplearning4j_tpu.serving import (ModelHost, FleetRouter,
-    SequenceScheduler, loadgen)
+from deeplearning4j_tpu.serving import ModelHost, FleetRouter, loadgen
 from deeplearning4j_tpu.serving.fleet import (scenario_diurnal_ramp,
     scenario_hot_model_skew, scenario_slow_client_storm)
 
@@ -1533,56 +1530,16 @@ rec["fleet_metrics"] = {
 }
 fleet.close()
 
-# ---- iteration-level vs run-to-completion decode throughput ----
-rconf = (NeuralNetConfiguration.Builder().seed(5)
-         .updater(Nesterovs(0.1, 0.9)).list()
-         .layer(LSTM(nOut=32))
-         .layer(RnnOutputLayer(nOut=16, activation="softmax",
-                               lossFunction="mcxent"))
-         .setInputType(InputType.recurrent(16, 12)).build())
-# mixed-length workload with straggler skew (the regime iteration-
-# level scheduling exists for): mostly short sequences + long
-# stragglers interleaved, so every run-to-completion gang batch pads
-# its short members to a straggler's length while the iteration-level
-# table refills the freed slots mid-sequence
-lens = [24, 2, 2, 2, 2, 2] * 8
-seqs = [rng.randn(t, 16).astype(np.float32) for t in lens]
-ab = {}
-for mode in ("step", "gang"):
-    net = MultiLayerNetwork(rconf).init()
-    sched = SequenceScheduler(net, slot_buckets=(8,), queue_limit=64,
-                              admission=mode, start_thread=False)
-    sched.warm()
-    with aot.CompileWatch() as watch:
-        t0 = time.perf_counter()
-        reqs = [sched.submit(s, wait=False) for s in seqs]
-        sched.drain()
-        wall = time.perf_counter() - t0
-    st = sched.stats
-    ab[mode] = {
-        "wall_s": round(wall, 4),
-        "dispatches": st["dispatches"],
-        "slot_steps": st["slot_steps"],
-        "tokens_per_sec": round(st["slot_steps"] / wall, 1),
-        "mid_sequence_refills": st["refills"],
-        "occupancy": sched.occupancy_summary(),
-        "steady_state_compiles": watch.misses,
-    }
-    sched.close()
-rec["iteration_vs_gang"] = dict(ab, speedup=round(
-    ab["step"]["tokens_per_sec"] / ab["gang"]["tokens_per_sec"], 2))
 print("FLEETREC " + json.dumps(rec), flush=True)
 """
 
 
 def bench_serving_fleet(timeout_s=420):
-    """Multi-host serving fleet + iteration-level sequence batching
-    (serving/fleet.py + serving/sequence.py, docs/SERVING.md): fleet
-    requests/sec + p99 vs a single replica under the same open-loop
-    rate, the three load scenarios (diurnal ramp, hot-model skew,
-    slow-client storm) with per-error-class counts, and the
-    iteration-level vs run-to-completion decode-throughput A/B on a
-    mixed-length recurrent workload. CPU-pinned subprocess BY DESIGN
+    """Multi-host serving fleet (serving/fleet.py, docs/SERVING.md):
+    fleet requests/sec + p99 vs a single replica under the same
+    open-loop rate, and the three load scenarios (diurnal ramp,
+    hot-model skew, slow-client storm) with per-error-class counts.
+    CPU-pinned subprocess BY DESIGN
     (grad_sharing's pattern — never touches the chip): the levers
     measured are host-side scheduling ratios."""
     here = os.path.dirname(os.path.abspath(__file__))
@@ -2482,15 +2439,11 @@ def main():
             "amortization", {}).get("batched_rps"),
         "serving_speedup_vs_serial": configs.get("serving", {}).get(
             "amortization", {}).get("speedup_vs_serial"),
-        # sequence serving + fleet (round 15, ISSUE 15): fleet-level
-        # requests/sec over 3 replicas and the iteration-level vs
-        # run-to-completion decode-throughput ratio — top level so
-        # the record is attributable; None when the CPU-pinned leg
-        # errored
+        # the fleet (round 15, ISSUE 15): fleet-level requests/sec
+        # over 3 replicas — top level so the record is attributable;
+        # None when the CPU-pinned leg errored
         "fleet_rps": configs.get("serving_fleet", {}).get(
             "fleet_vs_single", {}).get("fleet_rps"),
-        "sequence_decode_speedup": configs.get("serving_fleet", {}).get(
-            "iteration_vs_gang", {}).get("speedup"),
         # chaos harness (round 16, ISSUE 16): armed-but-quiet fault
         # seams over the disarmed serving path (gate <= 1.03x) — top
         # level so the record is attributable; None when the

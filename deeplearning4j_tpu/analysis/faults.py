@@ -31,7 +31,8 @@ Codes (stable; suppressions and tests key on them):
          (``handle_GET``/``handle_POST`` — the repo convention, see
          util/httpserve.py), or a function doing disk I/O
          (``open(...)``) from which no ``fault_point()`` call is
-         reachable through same-class/same-module calls. The
+         reachable through same-module calls (``self.m()`` resolves
+         within the class's same-module bases and subclasses). The
          micro-batcher/scheduler queue-dispatch loops are covered as
          spawned-thread targets. A boundary without a seam is a
          failure path the chaos soak can never exercise.
@@ -40,8 +41,10 @@ Codes (stable; suppressions and tests key on them):
          no ``timeout=`` — one wedged peer and the caller blocks
          forever, defeating the serving deadline contract.
 - FLT04  ``fault_point()`` reachable while a lock is held (lexically,
-         or via a one-level same-class call): a ``wedge``/``slow``
-         fault injected there becomes a deadlock-under-lock, so a
+         or via a one-level call within the class's same-module
+         family; a base's lock is its subclasses' too): a
+         ``wedge``/``slow`` fault injected there becomes a
+         deadlock-under-lock, so a
          chaos run would report a hang the production code does not
          have (or worse, mask one it does).
 - FLT05  retry/poll loop with no bound or backoff: ``sleep(0)`` inside
@@ -265,10 +268,42 @@ class _Indexer(ast.NodeVisitor):
         self.fns = []
         self.by_name = {}           # bare name -> [_Fn]
         self.classes = {}           # class name -> {method -> _Fn}
+        self.bases = {}             # class name -> bare base names
         self._scope = []            # scope-name stack
         self._cls = []              # (classname, depth) stack
 
+    def ancestors(self, cls):
+        """`cls` and its same-module bases."""
+        out, todo = [], [cls]
+        while todo:
+            c = todo.pop()
+            if c not in out:
+                out.append(c)
+                todo.extend(self.bases.get(c, ()))
+        return out
+
+    def family(self, cls):
+        """`cls` with its same-module ancestors and descendants: the
+        classes whose methods a ``self.m()`` written in `cls` may run —
+        inherited from a base, or an override the base's code
+        dispatches to."""
+        fam, todo = self.ancestors(cls), [cls]
+        while todo:
+            c = todo.pop()
+            subs = [s for s, bs in self.bases.items()
+                    if c in bs and s not in fam]
+            fam.extend(subs)
+            todo.extend(subs)
+        return fam
+
+    def methods(self, cls, name):
+        """Every definition of method `name` in `cls`'s family."""
+        return [self.classes[c][name] for c in self.family(cls)
+                if name in self.classes.get(c, {})]
+
     def visit_ClassDef(self, node):
+        self.bases[node.name] = [b.id for b in node.bases
+                                 if isinstance(b, ast.Name)]
         self._scope.append(node.name)
         self._cls.append((node.name, len(self._scope)))
         self.generic_visit(node)
@@ -309,7 +344,8 @@ def _resolve(name, from_scope, by_name):
 
 def _reaches_seam(start, idx):
     """True when a fault_point call is reachable from `start` through
-    same-class self.m() calls and same-module bare-name calls."""
+    self.m() calls within the class's same-module family (its bases
+    and subclasses) and same-module bare-name calls."""
     seen, todo = set(), [start]
     while todo:
         fn = todo.pop()
@@ -319,10 +355,8 @@ def _reaches_seam(start, idx):
         if fn.has_seam:
             return True
         if fn.cls:
-            methods = idx.classes.get(fn.cls, {})
             for m in fn.self_calls:
-                if m in methods:
-                    todo.append(methods[m])
+                todo.extend(idx.methods(fn.cls, m))
         for g in fn.calls:
             cand = _resolve(g, fn.scope + (fn.name,), idx.by_name)
             if cand is not None:
@@ -331,15 +365,16 @@ def _reaches_seam(start, idx):
 
 
 class _LockSeamWalker(ast.NodeVisitor):
-    """FLT04: fault_point (direct, or via a one-level same-class call
-    to a seam-bearing method) while a lock is lexically held."""
+    """FLT04: fault_point (direct, or via a one-level call to a
+    seam-bearing method of the class's family) while a lock is
+    lexically held."""
 
-    def __init__(self, cls_name, lock_attrs, module_locks, methods,
+    def __init__(self, cls_name, lock_attrs, module_locks, idx,
                  findings):
         self.cls_name = cls_name
         self.lock_attrs = lock_attrs
         self.module_locks = module_locks
-        self.methods = methods      # method name -> _Fn (same class)
+        self.idx = idx
         self.findings = findings
         self.lock_stack = []
 
@@ -387,9 +422,9 @@ class _LockSeamWalker(ast.NodeVisitor):
                          "this seam's own serialization contract"))
             else:
                 callee = _self_attr(node.func)
-                target = self.methods.get(callee) \
-                    if callee is not None else None
-                if target is not None and target.has_seam:
+                if callee is not None and any(
+                        t.has_seam for t in
+                        self.idx.methods(self.cls_name, callee)):
                     self.findings.append(_Finding(
                         node.lineno, node.col_offset, "FLT04",
                         f"self.{callee}() contains a fault_point and "
@@ -518,13 +553,13 @@ def _lint_tree(tree, findings):
     for fn in idx.fns:
         for kind, name, call in fn.spawns:
             if kind == "method":
-                target = idx.classes.get(fn.cls, {}).get(name) \
-                    if fn.cls else None
+                targets = idx.methods(fn.cls, name) if fn.cls else []
             else:
-                target = _resolve(name, fn.scope + (fn.name,),
-                                  idx.by_name)
-            if target is not None and not _reaches_seam(target, idx):
-                _flag_boundary(target, "thread target")
+                targets = [_resolve(name, fn.scope + (fn.name,),
+                                    idx.by_name)]
+            for target in targets:
+                if target is not None and not _reaches_seam(target, idx):
+                    _flag_boundary(target, "thread target")
         if fn.cls and fn.name in _HTTP_HANDLERS \
                 and not _reaches_seam(fn, idx):
             _flag_boundary(fn, "HTTP handler")
@@ -565,15 +600,18 @@ def _lint_tree(tree, findings):
 
     # FLT04: seams under held locks
     mod_locks = _module_locks(tree)
-    for node in ast.walk(tree):
-        if not isinstance(node, ast.ClassDef):
-            continue
-        lock_attrs = _class_lock_attrs(node)
+    class_nodes = {n.name: n for n in ast.walk(tree)
+                   if isinstance(n, ast.ClassDef)}
+    for node in class_nodes.values():
+        # a lock made in a same-module base is this class's lock too
+        lock_attrs = set()
+        for c in idx.ancestors(node.name):
+            if c in class_nodes:
+                lock_attrs |= _class_lock_attrs(class_nodes[c])
         if not lock_attrs and not mod_locks:
             continue
-        methods = idx.classes.get(node.name, {})
         walker = _LockSeamWalker(node.name, lock_attrs, mod_locks,
-                                 methods, findings)
+                                 idx, findings)
         for m in node.body:
             if isinstance(m, (ast.FunctionDef, ast.AsyncFunctionDef)):
                 for st in m.body:

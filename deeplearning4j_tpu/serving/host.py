@@ -131,42 +131,40 @@ class ServedSequenceModel:
         """Precompile the decode step for every slot bucket."""
         return self.scheduler.warm(cache=cache)
 
-    def submit(self, features, deadline_s=None, extra_steps=0,
-               wait=True, timeout=None):
+    def _submit(self, payload, deadline_s, wait, timeout, **kw):
+        """Both submit paths' way into the scheduler: the host's fault
+        point, a deadline relative to now on the scheduler's clock, and
+        a caller's wait of `timeout`, by default the deadline's span."""
         from deeplearning4j_tpu.runtime.chaos import fault_point
 
+        sched = self.scheduler
+        payload = fault_point("host.submit_sequence", payload)
+        deadline = None if deadline_s is None else \
+            sched.clock() + float(deadline_s)
+        return sched.submit(payload, deadline=deadline, wait=wait,
+                            timeout=deadline_s if timeout is None
+                            else timeout, **kw)
+
+    def submit(self, features, deadline_s=None, extra_steps=0,
+               wait=True, timeout=None):
         if self.paged:
             raise ValueError(
                 f"model {self.name!r} is a paged token model — use "
                 "generate()/submit_tokens() with a token prompt")
-        sched = self.scheduler
-        features = fault_point("host.submit_sequence", features)
-        deadline = None if deadline_s is None else \
-            sched.clock() + float(deadline_s)
-        return sched.submit(features, deadline=deadline,
-                            extra_steps=extra_steps, wait=wait,
-                            timeout=deadline_s if timeout is None
-                            else timeout)
+        return self._submit(features, deadline_s, wait, timeout,
+                            extra_steps=extra_steps)
 
     def submit_tokens(self, tokens, deadline_s=None, max_new_tokens=1,
                       wait=True, timeout=None):
         """Queue one token prompt on the paged scheduler (the
         :generate token path). Same deadline/wait contract as
         submit()."""
-        from deeplearning4j_tpu.runtime.chaos import fault_point
-
         if not self.paged:
             raise ValueError(
                 f"model {self.name!r} serves per-step features, not "
                 "token prompts — use submit()")
-        sched = self.scheduler
-        tokens = fault_point("host.submit_sequence", tokens)
-        deadline = None if deadline_s is None else \
-            sched.clock() + float(deadline_s)
-        return sched.submit(tokens, deadline=deadline,
-                            max_new_tokens=max_new_tokens, wait=wait,
-                            timeout=deadline_s if timeout is None
-                            else timeout)
+        return self._submit(tokens, deadline_s, wait, timeout,
+                            max_new_tokens=max_new_tokens)
 
     def policy(self):
         import jax.numpy as jnp

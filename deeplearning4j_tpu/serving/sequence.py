@@ -1,57 +1,62 @@
-"""Iteration-level continuous batching for stateful (recurrent) models.
+"""Iteration-level continuous batching for stateful models.
 
 The one-shot tier (serving/queue.py) coalesces REQUESTS; sequence
 workloads need the batch re-formed every DECODE STEP — the Orca
-iteration-level scheduling insight, applied to the stack's stateful
-``rnnTimeStep`` path. A run-to-completion (gang) batch pads every
-sequence to the longest in its batch and holds finished slots hostage
-until the stragglers drain; re-batching per step lets an early-exit
-slot be refilled from the queue MID-SEQUENCE, so the device always
-steps a full-as-possible batch of live tokens.
+iteration-level scheduling insight. A batch run to completion pads
+every sequence to its longest and holds finished slots hostage until
+the stragglers drain; re-batching per step lets an early-exit slot be
+refilled from the queue MID-SEQUENCE, so the device always steps a
+full-as-possible batch of live tokens.
 
-Mechanics (``SequenceScheduler``):
+One scheduler, two kinds of slot. ``_SlotScheduler`` (private) is
+everything that does not depend on what a slot holds:
 
-* a **slot table** of active sequences, each carrying its own per-layer
-  hidden/cell state as host arrays. Every iteration the scheduler
-  GATHERS the live carries into one ``[S, H]`` batch per layer/key
-  (zero rows for empty slots), steps the model ONCE via the functional
-  ``MultiLayerNetwork.rnnStepBatched`` (nn/multilayer.py), and
-  SCATTERS the outputs + new carries back per slot. Rows are
-  independent, so one executable per **slot bucket** serves any
-  occupancy — padding can never perturb a live slot, and per-slot
-  output is bitwise what serial ``rnnTimeStep`` produces
-  (tests/test_sequence_serving.py gates it). Known limit, the PR 8
-  precedent: when a sequence's steps SPAN different slot buckets, the
-  bucket change can alter XLA's dot lowering and round 1 ulp apart —
-  within a fixed bucket parity is structural and bitwise; pin
-  ``slot_buckets`` to one size where bitwise reproducibility across
-  occupancy changes matters more than padded-row compute.
+* a bounded FIFO behind ``submit`` (``QueueFullError`` past
+  ``queue_limit`` — backpressure, never a hang) and a **slot table** of
+  at most ``max(slot_buckets)`` live requests; free slots are refilled
+  from the queue at every iteration boundary;
 * slot counts are **bucketed** (``slot_buckets``) through the AOT
   executable cache exactly like the one-shot tier's batch buckets: the
   compile budget is ``len(slot_buckets)``, ``warm()`` precompiles every
   bucket, and a warmed scheduler serves any mix of sequence lengths
-  with ZERO steady-state compiles (CompileWatch-gated).
-* admission: ``submit`` appends to a bounded FIFO (``QueueFullError``
-  past ``queue_limit`` — backpressure, never a hang); free slots are
-  refilled from the queue at every iteration boundary
-  (``admission="step"``). ``admission="gang"`` is the deliberate
-  run-to-completion baseline — refill only when the table drains — so
-  the iteration-level win is measurable as an A/B on the SAME code
-  path (bench serving_fleet, the >=2x tier-1 gate).
-* per-request **deadlines are honored per step**: an expired sequence —
+  with ZERO steady-state compiles (CompileWatch-gated);
+* per-request **deadlines are honored per step**: an expired request —
   queued OR mid-flight — is failed at the next iteration boundary and
-  its slot refilled; the caller side of the contract is
-  ``SequenceRequest.wait`` (the release rules are stated once, on
-  ``queue.InferenceRequest.wait``).
-* the clock is injectable (``queue.ManualClock``) and the scheduler can
-  be driven synchronously via ``poll()``/``drain()`` with
-  ``start_thread=False`` — the same zero-sleep deterministic test seam
-  the MicroBatcher exposes.
+  its slot refilled; the caller side of the contract is the request's
+  ``wait`` (the release rules are stated once, on
+  ``queue.InferenceRequest.wait``);
+* one place where a request ends, done or failed (``_end_req``), the
+  drivers (a background loop, or ``poll()``/``drain()`` with
+  ``start_thread=False`` under an injected ``queue.ManualClock`` — the
+  same zero-sleep deterministic test seam the MicroBatcher exposes),
+  the ``dl4j_seq_*`` series and ``close``.
 
-Generation mode: a request may ask for ``extra_steps`` beyond its
-prompt; the next input row is then ``feedback(last_output_row)`` — the
-host-side closed loop of a char-rnn sampler (greedy argmax one-hot by
-default when the scheduler's ``feedback`` is set).
+The two public classes are what a slot holds and what one iteration
+does with it:
+
+* ``SequenceScheduler`` — recurrent nets. A slot carries its own
+  per-layer hidden/cell state as host arrays. Every iteration GATHERS
+  the live carries into one ``[S, H]`` batch per layer/key (zero rows
+  for empty slots), steps the model ONCE via the functional
+  ``MultiLayerNetwork.rnnStepBatched`` (nn/multilayer.py), and SCATTERS
+  the outputs + new carries back per slot. Rows are independent, so one
+  executable per slot bucket serves any occupancy — padding can never
+  perturb a live slot, and per-slot output is bitwise what serial
+  ``rnnTimeStep`` produces (tests/test_sequence_serving.py gates it).
+  Known limit, the PR 8 precedent: when a sequence's steps SPAN
+  different slot buckets, the bucket change can alter XLA's dot
+  lowering and round 1 ulp apart — within a fixed bucket parity is
+  structural and bitwise; pin ``slot_buckets`` to one size where
+  bitwise reproducibility across occupancy changes matters more than
+  padded-row compute. Generation mode: a request may ask for
+  ``extra_steps`` beyond its prompt; the next input row is then
+  ``feedback(last_output_row)`` — the host-side closed loop of a
+  char-rnn sampler (greedy argmax one-hot by default when the
+  scheduler's ``feedback`` is set).
+* ``PagedSequenceScheduler`` — paged-attention LMs. A slot holds KV
+  pages of a bounded ``PagedKVCache``; an iteration is at most one
+  prefill pass of one slot plus one slot-batched decode step (class
+  docstring).
 
 See docs/SERVING.md "Sequence serving + the fleet".
 """
@@ -78,6 +83,7 @@ from deeplearning4j_tpu.serving.queue import (
     DeadlineExceededError, QueueFullError, ServingClosedError,
     occupancy_summary_from,
 )
+from deeplearning4j_tpu.serving.sampling import greedy_sampler, stream_rng
 
 __all__ = ["SequenceRequest", "SequenceScheduler", "GenerationRequest",
            "PagedSequenceScheduler", "greedy_onehot_feedback"]
@@ -88,105 +94,68 @@ _SCHED_SEQ = itertools.count(1)
 #: default slot-count buckets: one executable per bucket, ever
 DEFAULT_SLOT_BUCKETS = (1, 2, 4, 8)
 
-#: the stats keys the dict view carries
-_STAT_KEYS = ("sequences", "completed", "dispatches", "slot_steps",
-              "expired", "rejected", "errors", "refills")
-
 #: chunked-prefill chaos seam (PagedSequenceScheduler): fires before
 #: each prompt chunk dispatch, so a ChaosPlan can fail/wedge/corrupt a
 #: prefill exactly where production would (runtime/chaos.py)
 PREFILL_SEAM = register_seam("sequence.prefill")
 
 #: the registry families both scheduler classes record into (and
-#: release per-instance series from at close())
-_SEQ_METRIC_FAMILIES = (
-    "dl4j_seq_sequences_total", "dl4j_seq_completed_total",
-    "dl4j_seq_dispatches_total", "dl4j_seq_slot_steps_total",
-    "dl4j_seq_expired_total", "dl4j_seq_rejected_total",
-    "dl4j_seq_errors_total", "dl4j_seq_refills_total",
-    "dl4j_seq_queue_depth", "dl4j_seq_active_slots",
-    "dl4j_seq_queue_wait_seconds", "dl4j_seq_slot_occupancy",
-)
+#: release per-instance series from at close()), one row a family:
+#: key of the instrument set -> (kind, family, help)
+_SEQ_FAMILIES = {
+    "sequences": ("counter", "dl4j_seq_sequences_total",
+                  "sequences accepted into the sequence queue"),
+    "completed": ("counter", "dl4j_seq_completed_total",
+                  "sequences completed (all steps served)"),
+    "dispatches": ("counter", "dl4j_seq_dispatches_total",
+                   "slot-batched decode-step dispatches"),
+    "slot_steps": ("counter", "dl4j_seq_slot_steps_total",
+                   "live slot-steps served (occupancy x dispatches)"),
+    "expired": ("counter", "dl4j_seq_expired_total",
+                "sequences failed by a per-step deadline expiry (504)"),
+    "rejected": ("counter", "dl4j_seq_rejected_total",
+                 "sequences rejected on a full queue (429)"),
+    "errors": ("counter", "dl4j_seq_errors_total",
+               "sequences failed by a dispatch error"),
+    "refills": ("counter", "dl4j_seq_refills_total",
+                "mid-sequence slot refills (admissions while other "
+                "slots were mid-flight)"),
+    "depth": ("gauge", "dl4j_seq_queue_depth",
+              "sequences waiting for a slot"),
+    "active": ("gauge", "dl4j_seq_active_slots",
+               "slots occupied by live sequences"),
+    "wait": ("histogram", "dl4j_seq_queue_wait_seconds",
+             "enqueue-to-first-step wait per sequence"),
+    "occupancy": ("histogram", "dl4j_seq_slot_occupancy",
+                  "live-slots/bucket fill fraction per decode step"),
+}
+
+#: the stats keys the dict view carries: the counters
+_STAT_KEYS = tuple(k for k, row in _SEQ_FAMILIES.items()
+                   if row[0] == "counter")
 
 
 def _seq_metrics(reg, name):
     """The dl4j_seq_* instrument set, labelled for one scheduler
     instance — shared by the carry-slot and KV-slot schedulers so both
     report through the same families (docs/OBSERVABILITY.md)."""
-    lab = {"model": name}
-    return {
-        "sequences": reg.counter(
-            "dl4j_seq_sequences_total",
-            "sequences accepted into the sequence queue",
-            labels=("model",)).labels(**lab),
-        "completed": reg.counter(
-            "dl4j_seq_completed_total",
-            "sequences completed (all steps served)",
-            labels=("model",)).labels(**lab),
-        "dispatches": reg.counter(
-            "dl4j_seq_dispatches_total",
-            "slot-batched decode-step dispatches",
-            labels=("model",)).labels(**lab),
-        "slot_steps": reg.counter(
-            "dl4j_seq_slot_steps_total",
-            "live slot-steps served (occupancy x dispatches)",
-            labels=("model",)).labels(**lab),
-        "expired": reg.counter(
-            "dl4j_seq_expired_total",
-            "sequences failed by a per-step deadline expiry (504)",
-            labels=("model",)).labels(**lab),
-        "rejected": reg.counter(
-            "dl4j_seq_rejected_total",
-            "sequences rejected on a full queue (429)",
-            labels=("model",)).labels(**lab),
-        "errors": reg.counter(
-            "dl4j_seq_errors_total",
-            "sequences failed by a dispatch error",
-            labels=("model",)).labels(**lab),
-        "refills": reg.counter(
-            "dl4j_seq_refills_total",
-            "mid-sequence slot refills (admissions while other "
-            "slots were mid-flight)",
-            labels=("model",)).labels(**lab),
-        "depth": reg.gauge(
-            "dl4j_seq_queue_depth",
-            "sequences waiting for a slot",
-            labels=("model",)).labels(**lab),
-        "active": reg.gauge(
-            "dl4j_seq_active_slots",
-            "slots occupied by live sequences",
-            labels=("model",)).labels(**lab),
-        "wait": reg.histogram(
-            "dl4j_seq_queue_wait_seconds",
-            "enqueue-to-first-step wait per sequence",
-            labels=("model",)).labels(**lab),
-        "occupancy": reg.histogram(
-            "dl4j_seq_slot_occupancy",
-            "live-slots/bucket fill fraction per decode step",
-            labels=("model",),
-            buckets=(0.25, 0.5, 0.75, 1.0)).labels(**lab),
-    }
+    out = {}
+    for key, (kind, family, text) in _SEQ_FAMILIES.items():
+        # a fill fraction is binned by its quartiles
+        kw = {"buckets": (0.25, 0.5, 0.75, 1.0)} \
+            if key == "occupancy" else {}
+        out[key] = getattr(reg, kind)(
+            family, text, labels=("model",), **kw).labels(model=name)
+    return out
 
 
-def _wait_for_work(sched):
-    """Block a scheduler's background loop until something is queued or
-    active (True), or the scheduler is closed with nothing left (False).
-    An idle period — from the first wait that found nothing to the
-    wake-up that finds work, or to close() — is ONE ``sequence.idle``
-    span, not one per 50 ms poll: the device idle for want of demand
-    reads apart from the device idle with work pending."""
-    idle_since = None
-    with sched._cond:
-        while not sched._pending and not sched._active \
-                and not sched._closed:
-            if idle_since is None:
-                idle_since = sched.clock()
-            sched._cond.wait(0.05)
-        work = bool(sched._pending or sched._active)
-    if idle_since is not None:
-        sched._registry.add_span("sequence.idle", "serving", idle_since,
-                                 sched.clock() - idle_since)
-    return work
+def _note_warm(report, name, warmed):
+    """Enter one executable's (key, status, seconds) into a ``warm()``
+    report under `name`; a status of None is not reported."""
+    key, status, secs = warmed
+    if status is not None:
+        report[name] = {"key": key, "status": status,
+                        "seconds": round(secs, 3)}
 
 
 def greedy_onehot_feedback(vocab):
@@ -202,33 +171,21 @@ def greedy_onehot_feedback(vocab):
     return feedback
 
 
-class SequenceRequest:
-    """One sequence: prompt features [T, F] consumed one timestep per
-    scheduler iteration, plus optional generation steps.
+class _SlotRequest:
+    """The waiting half of a request, whatever its slot holds: when it
+    was enqueued, its deadline, when a slot was granted, and the event
+    its caller waits on. ``wait`` follows the serving tier's one
+    release contract — see ``queue.InferenceRequest.wait`` (dispatch
+    failure, per-step deadline expiry, or caller-timeout release while
+    the scheduler is mid-step)."""
 
-    total steps = T + extra_steps; step t consumes ``features[t]`` for
-    t < T and ``feedback(outputs[t-1])`` after. The result is the
-    stacked per-step output [total, O]. ``wait`` follows the serving
-    tier's one release contract — see ``queue.InferenceRequest.wait``
-    (dispatch failure, per-step deadline expiry, or caller-timeout
-    release while the scheduler is mid-step)."""
+    __slots__ = ("enqueued_at", "deadline", "started_at", "result",
+                 "error", "_event")
 
-    __slots__ = ("features", "steps", "extra_steps", "feedback",
-                 "enqueued_at", "deadline", "started_at", "steps_done",
-                 "outputs", "carry", "result", "error", "_event")
-
-    def __init__(self, features, enqueued_at, deadline=None,
-                 extra_steps=0, feedback=None):
-        self.features = features            # [T, F] float32
-        self.steps = int(features.shape[0]) + int(extra_steps)
-        self.extra_steps = int(extra_steps)
-        self.feedback = feedback
+    def __init__(self, enqueued_at, deadline=None):
         self.enqueued_at = float(enqueued_at)
         self.deadline = None if deadline is None else float(deadline)
-        self.started_at = None              # first-step admission time
-        self.steps_done = 0
-        self.outputs = []                   # per-step [O] rows
-        self.carry = None                   # per-layer {key: [H]} rows
+        self.started_at = None              # a slot was granted
         self.result = None
         self.error = None
         self._event = threading.Event()
@@ -236,6 +193,349 @@ class SequenceRequest:
     @property
     def done(self):
         return self._event.is_set()
+
+    def fail(self, exc):
+        self.error = exc
+        self._event.set()
+
+    def wait(self, timeout=None):
+        """Block for the result (``finish`` of the subclass makes it).
+        Release rules are the serving tier's single wait contract —
+        ``queue.InferenceRequest.wait``."""
+        if not self._event.wait(timeout):
+            raise DeadlineExceededError(f"no result within {timeout:.3f}s")
+        if self.error is not None:
+            raise self.error
+        return self.result
+
+
+class _SlotScheduler:
+    """What the two slot schedulers share (module docstring): the
+    queue, the slot table, deadlines, admission, the drivers, the one
+    ending of a request, the ``dl4j_seq_*`` series and the shutdown.
+
+    A subclass validates what is submitted and makes the request
+    (``submit`` -> ``_enqueue``), prepares a slot at admission
+    (``_admit_locked``), does one iteration (``_iterate_locked``),
+    shapes a staging entry (``_new_staging``) and has its ``warm``; one
+    whose slots hold something to give back extends ``_end_req`` and
+    ``close``. Its constructor makes its own state after this one and
+    calls ``_open`` last.
+
+    slot_buckets: slot-count executable buckets; max(slot_buckets) is
+                  the table capacity.
+    queue_limit:  bound on WAITING sequences (QueueFullError past it).
+    clock/start_thread/name: the MicroBatcher test seam — inject
+                  ManualClock and drive ``poll()``/``drain()`` with no
+                  thread for deterministic tests.
+    """
+
+    def __init__(self, model, *, slot_buckets, queue_limit, clock, name):
+        if int(queue_limit) < 1:
+            raise ValueError(f"queue_limit must be >= 1, got {queue_limit}")
+        self.model = model
+        buckets = slot_buckets or DEFAULT_SLOT_BUCKETS
+        self.slot_buckets = tuple(sorted(int(b) for b in buckets))
+        if self.slot_buckets[0] < 1:
+            raise ValueError(f"slot buckets must be >= 1, got {buckets}")
+        self.max_slots = self.slot_buckets[-1]
+        self.queue_limit = int(queue_limit)
+        # one clock for every program span: the registry's, unless a
+        # test injects its own (docs/OBSERVABILITY.md "One clock")
+        self.clock = clock if clock is not None \
+            else telemetry.get_registry().clock
+        self._cond = threading.Condition()
+        # one iteration at a time: the background loop and a concurrent
+        # close(drain=True)/poll() caller must never both snapshot the
+        # slot table and double-step a sequence
+        self._step_lock = threading.Lock()
+        self._pending = deque()
+        self._active = []                   # the slot table
+        self._staging = {}                  # S -> (reused buffers, bytes)
+        #: host bytes served from the staging pool instead of fresh
+        #: np.zeros (the bench decode leg's alloc-reduction record)
+        self.staging_reuse_bytes = 0
+        self._closed = False
+        self.name = str(name) if name else f"seq{next(_SCHED_SEQ)}"
+        #: (live slots, bucket) per decode dispatch — the occupancy
+        #: record
+        self.occupancy = []
+        self._registry = telemetry.get_registry()
+        self._thread = None
+
+    def _open(self, start_thread):
+        """Register this instance's series and start the loop: the last
+        statement of a subclass's constructor, because the loop may run
+        an iteration at once and that needs the subclass's state."""
+        self._m = _seq_metrics(self._registry, self.name)
+        if start_thread:
+            self._thread = threading.Thread(target=self._loop, daemon=True)
+            self._thread.start()
+
+    # -- submit ---------------------------------------------------------
+    def _enqueue(self, make_req, wait, timeout):
+        """The queue's half of ``submit``: `make_req(now)` builds the
+        validated request once the scheduler is known to be open and
+        the queue to have room."""
+        with self._cond:
+            if self._closed:
+                raise ServingClosedError("sequence scheduler is closed")
+            if len(self._pending) >= self.queue_limit:
+                self._m["rejected"].inc()
+                raise QueueFullError(
+                    f"sequence queue full ({len(self._pending)} waiting, "
+                    f"queueLimit={self.queue_limit})")
+            req = make_req(self.clock())
+            self._pending.append(req)
+            self._m["sequences"].inc()
+            self._m["depth"].set(len(self._pending))
+            self._cond.notify()
+        if wait:
+            return req.wait(timeout)
+        return req
+
+    # -- scheduling core (lock held) ------------------------------------
+    def _end_req(self, req, exc=None):
+        """The one place a request ends, done (exc None) or failed:
+        every path of both classes comes through here."""
+        if exc is None:
+            req.finish()
+        else:
+            req.fail(exc)
+
+    def _expire_locked(self, now):
+        """Fail every request — queued or MID-FLIGHT — whose deadline
+        has passed: the per-step deadline contract. A mid-flight expiry
+        frees its slot this same iteration. Returns how many."""
+        keep = deque()
+        n = len(self._pending) + len(self._active)
+        for req in self._pending:
+            if req.deadline is not None and now >= req.deadline:
+                self._m["expired"].inc()
+                self._end_req(req, DeadlineExceededError(
+                    f"deadline passed {now - req.deadline:.3f}s before "
+                    "a slot was granted"))
+            else:
+                keep.append(req)
+        self._pending = keep
+        live = []
+        for req in self._active:
+            if req.deadline is not None and now >= req.deadline:
+                self._m["expired"].inc()
+                self._end_req(req, DeadlineExceededError(
+                    f"deadline passed at {req.progress()} — slot "
+                    "released mid-sequence"))
+            else:
+                live.append(req)
+        self._active = live
+        self._m["depth"].set(len(self._pending))
+        self._m["active"].set(len(self._active))
+        return n - len(keep) - len(live)
+
+    def _refill_locked(self, now):
+        """Admit queued requests into free slots, at every iteration
+        boundary: slots freed by early exit or expiry are re-used
+        MID-SEQUENCE. Returns (how many were admitted, what
+        ``_admit_locked`` handed back for them, for the caller to
+        process OUTSIDE this lock)."""
+        midrun = bool(self._active)
+        admitted, handed = 0, []
+        while self._pending and len(self._active) < self.max_slots:
+            req = self._pending.popleft()
+            req.started_at = now
+            admitted += 1
+            back = self._admit_locked(req, now)
+            self._active.append(req)
+            self._m["wait"].observe(now - req.enqueued_at)
+            if midrun:
+                self._m["refills"].inc()
+            if back is not None:
+                handed.append(back)
+        self._m["depth"].set(len(self._pending))
+        self._m["active"].set(len(self._active))
+        return admitted, handed
+
+    def _fail_active(self, reqs, exc):
+        """Fail mid-flight requests with `exc` and free their slots."""
+        with self._cond:
+            self._m["errors"].inc(len(reqs))
+            for req in reqs:
+                self._end_req(req, exc)
+            self._active = [r for r in self._active if r not in reqs]
+            self._m["active"].set(len(self._active))
+
+    def bucket_for(self, n):
+        """Smallest slot bucket >= n live slots (the executable that
+        serves this iteration)."""
+        for b in self.slot_buckets:
+            if n <= b:
+                return b
+        return self.slot_buckets[-1]
+
+    def _staging_for(self, S):
+        """Per-bucket staging buffers (``_new_staging`` of the subclass
+        shapes them), allocated once and reused every iteration: the
+        dispatch copies them to the device, so host-side reuse can
+        never alias a live step. A fresh np.zeros per array per step
+        was pure allocator churn; the bench decode leg counts what the
+        pool saves as staging_reuse_bytes."""
+        hit = self._staging.get(S)
+        if hit is not None:
+            st, nbytes = hit
+            self.staging_reuse_bytes += nbytes
+            return st
+        import jax
+
+        st = self._new_staging(S)
+        self._staging[S] = (st, sum(
+            a.nbytes for a in jax.tree_util.tree_leaves(st)))
+        return st
+
+    # -- drivers --------------------------------------------------------
+    def _step_once(self):
+        """One scheduler iteration (``_iterate_locked`` of the
+        subclass); returns its progress count (0 = idle). Serialized by
+        the step lock — concurrent drivers (background loop vs a
+        draining close) take turns instead of double-stepping a
+        sequence."""
+        with self._step_lock:
+            return self._iterate_locked()  # fault-ok[FLT04]: the step lock is the scheduler's own serialization contract — a seam firing under it IS the wedged-scheduler fault the harness injects, and waiters are released by deadline expiry (the wait contract), never by this lock
+
+    def poll(self):
+        """One synchronous scheduler iteration (the thread-less test
+        seam): expire, refill, one step. Returns the progress count —
+        0 means idle (nothing queued or active). Deterministic under
+        ManualClock: no sleeps, no background thread."""
+        return self._step_once()
+
+    def drain(self):
+        """Run iterations until the table AND queue are empty (ignores
+        nothing — deadlines still expire per step on the clock)."""
+        while self._step_once():
+            pass
+        return self
+
+    def _wait_for_work(self):
+        """Block the background loop until something is queued or
+        active (True), or the scheduler is closed with nothing left
+        (False). An idle period — from the first wait that found
+        nothing to the wake-up that finds work, or to close() — is ONE
+        ``sequence.idle`` span, not one per 50 ms poll: the device idle
+        for want of demand reads apart from the device idle with work
+        pending."""
+        idle_since = None
+        with self._cond:
+            while not self._pending and not self._active \
+                    and not self._closed:
+                if idle_since is None:
+                    idle_since = self.clock()
+                self._cond.wait(0.05)
+            work = bool(self._pending or self._active)
+        if idle_since is not None:
+            self._registry.add_span("sequence.idle", "serving", idle_since,
+                                    self.clock() - idle_since)
+        return work
+
+    def _loop(self):
+        while self._wait_for_work():
+            try:
+                self._step_once()
+            except Exception as e:
+                # defensive: an unexpected scheduler bug must release
+                # every waiter, never leave them blocked on a dead
+                # thread; the loop stays up for new submits
+                self._fail_all(e)
+
+    def _clear_locked(self, queued_exc, active_exc):
+        """End everything queued and everything in a slot, failed, and
+        leave the table empty."""
+        while self._pending:
+            self._end_req(self._pending.popleft(), queued_exc)
+        for req in self._active:
+            self._end_req(req, active_exc)
+        self._active = []
+        self._m["depth"].set(0)
+        self._m["active"].set(0)
+
+    def _fail_all(self, exc):
+        """Fail every queued + active request with `exc` and clear the
+        table (the scheduler-bug escape hatch)."""
+        with self._cond:
+            n = len(self._pending) + len(self._active)
+            if n:
+                self._m["errors"].inc(n)
+            self._clear_locked(exc, exc)
+
+    # -- introspection / lifecycle --------------------------------------
+    @property
+    def depth(self):
+        """Sequences waiting for a slot."""
+        with self._cond:
+            return len(self._pending)
+
+    @property
+    def active_slots(self):
+        with self._cond:
+            return len(self._active)
+
+    @property
+    def stats(self):
+        """Dict view over the registry counters (dl4j_seq_*)."""
+        return {k: int(self._m[k].value) for k in _STAT_KEYS}
+
+    def occupancy_summary(self):
+        """Mean live-slots/bucket + quartile histogram over every
+        decode step so far (the 'is the table sized right' signal —
+        docs/SERVING.md)."""
+        return occupancy_summary_from(self.occupancy, "mean_live_slots")
+
+    def close(self, drain=True):
+        """Stop accepting. drain=True serves everything already queued
+        or mid-flight to completion; drain=False fails them with
+        ServingClosedError."""
+        with self._cond:
+            self._closed = True
+            if not drain:
+                self._clear_locked(
+                    ServingClosedError("scheduler closed before a slot "
+                                       "was granted"),
+                    ServingClosedError("scheduler closed mid-sequence"))
+            self._cond.notify_all()
+        if drain:
+            self.drain()
+        if self._thread is not None:
+            self._thread.join(timeout=5.0)
+            self._thread = None
+        # release this instance's registry series (MicroBatcher.close
+        # precedent: per-instance series must not accumulate forever)
+        for _, metric, _ in _SEQ_FAMILIES.values():
+            fam = self._registry.get(metric)
+            if fam is not None:
+                fam.remove(model=self.name)
+        return self
+
+
+class SequenceRequest(_SlotRequest):
+    """One sequence: prompt features [T, F] consumed one timestep per
+    scheduler iteration, plus optional generation steps.
+
+    total steps = T + extra_steps; step t consumes ``features[t]`` for
+    t < T and ``feedback(outputs[t-1])`` after. The result is the
+    stacked per-step output [total, O]."""
+
+    __slots__ = ("features", "steps", "extra_steps", "feedback",
+                 "steps_done", "outputs", "carry")
+
+    def __init__(self, features, enqueued_at, deadline=None,
+                 extra_steps=0, feedback=None):
+        super().__init__(enqueued_at, deadline)
+        self.features = features            # [T, F] float32
+        self.steps = int(features.shape[0]) + int(extra_steps)
+        self.extra_steps = int(extra_steps)
+        self.feedback = feedback
+        self.steps_done = 0
+        self.outputs = []                   # per-step [O] rows
+        self.carry = None                   # per-layer {key: [H]} rows
 
     def next_input(self):
         """The feature row this sequence consumes at its next step."""
@@ -249,99 +549,41 @@ class SequenceRequest:
         return np.asarray(self.feedback(self.outputs[-1]),
                           np.float32)
 
-    def finish(self, result):
-        self.result = result
+    def progress(self):
+        """How far the sequence is, for an error's text."""
+        return f"step {self.steps_done}/{self.steps}"
+
+    def finish(self):
+        self.result = np.stack(self.outputs, axis=0)
         self._event.set()
 
-    def fail(self, exc):
-        self.error = exc
-        self._event.set()
 
-    def wait(self, timeout=None):
-        """Block for the stacked [steps, O] output. Release rules are
-        the serving tier's single wait contract —
-        ``queue.InferenceRequest.wait``."""
-        if not self._event.wait(timeout):
-            raise DeadlineExceededError(f"no result within {timeout:.3f}s")
-        if self.error is not None:
-            raise self.error
-        return self.result
-
-
-class SequenceScheduler:
-    """Iteration-level slot scheduler over one recurrent model (module
-    docstring).
+class SequenceScheduler(_SlotScheduler):
+    """Iteration-level slot scheduler over one recurrent model: a slot
+    holds the sequence's h/c carries (module docstring).
 
     model:        an initialized MultiLayerNetwork with >=1 recurrent
                   layer (validated eagerly via ``rnnCarrySpec``).
-    slot_buckets: slot-count executable buckets; max(slot_buckets) is
-                  the table capacity.
-    queue_limit:  bound on WAITING sequences (QueueFullError past it).
-    admission:    "step" (refill free slots every iteration — the
-                  iteration-level discipline) or "gang" (refill only
-                  when the table is empty — the run-to-completion
-                  baseline the >=2x gate measures against).
     feedback:     scheduler-level generation feedback
                   (out_row [O]) -> next input row [F]; a request's own
                   feedback overrides it.
-    clock/start_thread/name: the MicroBatcher test seam — inject
-                  ManualClock and drive ``poll()``/``drain()`` with no
-                  thread for deterministic tests.
+    slot_buckets, queue_limit, clock, start_thread, name: the base's.
     """
 
     def __init__(self, model, *, slot_buckets=None, queue_limit=64,
-                 admission="step", feedback=None, clock=None,
-                 start_thread=True, name=None):
-        if admission not in ("step", "gang"):
-            raise ValueError(
-                f"admission must be 'step' (iteration-level) or 'gang' "
-                f"(run-to-completion baseline), got {admission!r}")
-        if int(queue_limit) < 1:
-            raise ValueError(f"queue_limit must be >= 1, got {queue_limit}")
-        self.model = model
+                 feedback=None, clock=None, start_thread=True, name=None):
         self._spec = model.rnnCarrySpec()   # validates the net, eagerly
+        super().__init__(model, slot_buckets=slot_buckets,
+                         queue_limit=queue_limit, clock=clock, name=name)
         # carries cross the jit boundary UNCAST (unlike x, which
         # _entry casts in-graph): host-side slot state must live in
         # the model's compute dtype or per-step outputs diverge from
         # serial rnnTimeStep on non-f32 policies
         self._carry_dtype = np.dtype(model._compute_dtype)
-        buckets = slot_buckets or DEFAULT_SLOT_BUCKETS
-        self.slot_buckets = tuple(sorted(int(b) for b in buckets))
-        if self.slot_buckets[0] < 1:
-            raise ValueError(f"slot buckets must be >= 1, got {buckets}")
-        self.max_slots = self.slot_buckets[-1]
-        self.queue_limit = int(queue_limit)
-        self.admission = admission
         self.feedback = feedback
-        # one clock for every program span: the registry's, unless a
-        # test injects its own (docs/OBSERVABILITY.md "One clock")
-        self.clock = clock if clock is not None \
-            else telemetry.get_registry().clock
-        it = model.conf.inputType
         #: per-step feature width the submit contract validates
-        self.feature_size = int(it.size)
-        self._cond = threading.Condition()
-        # one iteration at a time: the background loop and a concurrent
-        # close(drain=True)/poll() caller must never both snapshot the
-        # slot table and double-step a sequence
-        self._step_lock = threading.Lock()
-        self._pending = deque()
-        self._active = []                   # the slot table
-        self._staging = {}                  # S -> reused gather buffers
-        #: host bytes served from the staging pool instead of fresh
-        #: np.zeros (the bench decode leg's alloc-reduction record)
-        self.staging_reuse_bytes = 0
-        self._closed = False
-        self.name = str(name) if name else f"seq{next(_SCHED_SEQ)}"
-        #: (active_slots, bucket) per dispatch — the occupancy record
-        self.occupancy = []
-        reg = telemetry.get_registry()
-        self._registry = reg
-        self._m = _seq_metrics(reg, self.name)
-        self._thread = None
-        if start_thread:
-            self._thread = threading.Thread(target=self._loop, daemon=True)
-            self._thread.start()
+        self.feature_size = int(model.conf.inputType.size)
+        self._open(start_thread)
 
     # -- submit ---------------------------------------------------------
     def submit(self, features, deadline=None, extra_steps=0,
@@ -368,107 +610,27 @@ class SequenceScheduler:
             raise ValueError(
                 "extra_steps > 0 needs a feedback fn (request- or "
                 "scheduler-level) to close the generation loop")
-        with self._cond:
-            if self._closed:
-                raise ServingClosedError("sequence scheduler is closed")
-            if len(self._pending) >= self.queue_limit:
-                self._m["rejected"].inc()
-                raise QueueFullError(
-                    f"sequence queue full ({len(self._pending)} waiting, "
-                    f"queueLimit={self.queue_limit})")
-            req = SequenceRequest(features, self.clock(), deadline,
-                                  extra_steps=extra_steps, feedback=fb)
-            self._pending.append(req)
-            self._m["sequences"].inc()
-            self._m["depth"].set(len(self._pending))
-            self._cond.notify()
-        if wait:
-            return req.wait(timeout)
-        return req
+        return self._enqueue(
+            lambda now: SequenceRequest(features, now, deadline,
+                                        extra_steps=extra_steps,
+                                        feedback=fb),
+            wait, timeout)
 
-    # -- scheduling core (lock held) ------------------------------------
-    def _expire_locked(self, now):
-        """Fail every sequence — queued or MID-FLIGHT — whose deadline
-        has passed: the per-step deadline contract. A mid-flight expiry
-        frees its slot this same iteration."""
-        keep = deque()
-        for req in self._pending:
-            if req.deadline is not None and now >= req.deadline:
-                self._m["expired"].inc()
-                req.fail(DeadlineExceededError(
-                    f"deadline passed {now - req.deadline:.3f}s before "
-                    "a slot was granted"))
-            else:
-                keep.append(req)
-        self._pending = keep
-        live = []
-        for req in self._active:
-            if req.deadline is not None and now >= req.deadline:
-                self._m["expired"].inc()
-                req.fail(DeadlineExceededError(
-                    f"deadline passed at step {req.steps_done}/"
-                    f"{req.steps} — slot released mid-sequence"))
-            else:
-                live.append(req)
-        self._active = live
-        self._m["depth"].set(len(self._pending))
-        self._m["active"].set(len(self._active))
+    # -- what a slot holds ----------------------------------------------
+    def _zero_carries(self, *lead):
+        """Per-layer {key: zeros [*lead, H]} in the compute dtype."""
+        return [{k: np.zeros(lead + (int(self.model.layers[li].nOut),),
+                             self._carry_dtype) for k in keys}
+                for li, keys in enumerate(self._spec)]
 
-    def _refill_locked(self, now):
-        """Admit queued sequences into free slots. admission="step"
-        refills at every iteration boundary (slots freed by early exit
-        or expiry are re-used MID-SEQUENCE); "gang" only admits into an
-        empty table — the run-to-completion baseline."""
-        if self.admission == "gang" and self._active:
-            return
-        midrun = any(r.steps_done > 0 for r in self._active)
-        while self._pending and len(self._active) < self.max_slots:
-            req = self._pending.popleft()
-            req.started_at = now
-            req.carry = [{k: np.zeros((self._carry_width(i),),
-                                      self._carry_dtype)
-                          for k in keys}
-                         for i, keys in enumerate(self._spec)]
-            self._active.append(req)
-            self._m["wait"].observe(now - req.enqueued_at)
-            if midrun:
-                self._m["refills"].inc()
-        self._m["depth"].set(len(self._pending))
-        self._m["active"].set(len(self._active))
+    def _admit_locked(self, req, now):
+        req.carry = self._zero_carries()
 
-    def _carry_width(self, layer_idx):
-        return int(getattr(self.model.layers[layer_idx], "nOut"))
-
-    def bucket_for(self, n):
-        """Smallest slot bucket >= n live slots (the executable that
-        serves this iteration)."""
-        for b in self.slot_buckets:
-            if n <= b:
-                return b
-        return self.slot_buckets[-1]
+    def _new_staging(self, S):
+        return (np.zeros((S, self.feature_size), np.float32),
+                self._zero_carries(S))
 
     # -- one iteration (dispatch outside the lock) ----------------------
-    def _staging_for(self, S):
-        """Per-bucket gather/scatter staging buffers, allocated once
-        and reused every iteration (the dispatch copies them to device
-        via jnp.asarray, so host-side reuse can never alias a live
-        step). Before this, _gather paid a fresh np.zeros per column
-        per step — pure allocator churn the bench decode leg now
-        counts as staging_reuse_bytes."""
-        st = self._staging.get(S)
-        if st is None:
-            x = np.zeros((S, self.feature_size), np.float32)
-            carries = [{k: np.zeros((S, self._carry_width(li)),
-                                    self._carry_dtype) for k in keys}
-                       for li, keys in enumerate(self._spec)]
-            st = (x, carries)
-            self._staging[S] = st
-        else:
-            self.staging_reuse_bytes += (
-                st[0].nbytes
-                + sum(c.nbytes for d in st[1] for c in d.values()))
-        return st
-
     def _gather(self, batch, S, rows):
         """Stack the batch's validated next-input rows + carries into
         the fixed [S, ...] bucket signature (zero rows pad the empty
@@ -489,16 +651,10 @@ class SequenceScheduler:
                 col[n:] = 0
         return x, carries
 
-    def _step_once(self):
-        """One scheduler iteration: expire -> refill -> gather ->
-        dispatch ONE slot-batched decode step -> scatter. Returns the
-        number of live slots stepped (0 = idle). Serialized by the
-        step lock — concurrent drivers (background loop vs a draining
-        close) take turns instead of double-stepping a sequence."""
-        with self._step_lock:
-            return self._iterate_locked()  # fault-ok[FLT04]: the step lock is the scheduler's own serialization contract — sequence.step firing under it IS the wedged-scheduler fault the harness injects, and waiters are released by deadline expiry (the wait contract), never by this lock
-
     def _iterate_locked(self):
+        """One iteration: expire -> refill -> gather -> dispatch ONE
+        slot-batched decode step -> scatter. Returns the number of live
+        slots stepped (0 = idle)."""
         # *_locked: called with the STEP lock held (one driver at a
         # time); the condition lock is still taken around each shared-
         # state section below
@@ -524,17 +680,10 @@ class SequenceScheduler:
                         f"model feature size is {self.feature_size}")
                 rows.append(row)
             except Exception as e:
-                bad.append((req, e))
+                bad.append(req)
+                self._fail_active([req], e)
         if bad:
-            failed = {r for r, _ in bad}
-            with self._cond:
-                self._m["errors"].inc(len(bad))
-                for req, e in bad:
-                    req.fail(e)
-                self._active = [r for r in self._active
-                                if r not in failed]
-                self._m["active"].set(len(self._active))
-            batch = [r for r in batch if r not in failed]
+            batch = [r for r in batch if r not in bad]
             if not batch:
                 return len(bad)     # progress: drain must not stall
         S = self.bucket_for(len(batch))
@@ -557,13 +706,7 @@ class SequenceScheduler:
             new_carries = [{k: np.asarray(v) for k, v in d.items()}
                            for d in new_carries]
         except Exception as e:
-            with self._cond:
-                self._m["errors"].inc(len(batch))
-                for req in batch:
-                    req.fail(e)
-                self._active = [r for r in self._active
-                                if r not in batch]
-                self._m["active"].set(len(self._active))
+            self._fail_active(batch, e)
             return 0
         finally:
             self._registry.add_span(
@@ -587,72 +730,8 @@ class SequenceScheduler:
                 self._m["completed"].inc(len(finished))
                 self._m["active"].set(len(self._active))
         for req in finished:        # release waiters outside the lock
-            req.finish(np.stack(req.outputs, axis=0))
+            self._end_req(req)
         return len(batch)
-
-    # -- drivers --------------------------------------------------------
-    def poll(self):
-        """One synchronous scheduler iteration (the thread-less test
-        seam): expire, refill, step the slot batch once. Returns the
-        number of live slots stepped — 0 means idle (nothing queued or
-        active). Deterministic under ManualClock: no sleeps, no
-        background thread."""
-        return self._step_once()
-
-    def drain(self):
-        """Run iterations until the table AND queue are empty (ignores
-        nothing — deadlines still expire per step on the clock)."""
-        while self._step_once():
-            pass
-        return self
-
-    def _loop(self):
-        while _wait_for_work(self):
-            try:
-                self._step_once()
-            except Exception as e:
-                # defensive: an unexpected scheduler bug must release
-                # every waiter, never leave them blocked on a dead
-                # thread; the loop stays up for new submits
-                self._fail_all(e)
-
-    def _fail_all(self, exc):
-        """Fail every queued + active sequence with `exc` and clear
-        the table (the scheduler-bug escape hatch)."""
-        with self._cond:
-            n = len(self._pending) + len(self._active)
-            if n:
-                self._m["errors"].inc(n)
-            while self._pending:
-                self._pending.popleft().fail(exc)
-            for req in self._active:
-                req.fail(exc)
-            self._active = []
-            self._m["depth"].set(0)
-            self._m["active"].set(0)
-
-    # -- introspection / lifecycle --------------------------------------
-    @property
-    def depth(self):
-        """Sequences waiting for a slot."""
-        with self._cond:
-            return len(self._pending)
-
-    @property
-    def active_slots(self):
-        with self._cond:
-            return len(self._active)
-
-    @property
-    def stats(self):
-        """Dict view over the registry counters (dl4j_seq_*)."""
-        return {k: int(self._m[k].value) for k in _STAT_KEYS}
-
-    def occupancy_summary(self):
-        """Mean live-slots/bucket + quartile histogram over every
-        decode step so far (the 'is the table sized right' signal —
-        docs/SERVING.md)."""
-        return occupancy_summary_from(self.occupancy, "mean_live_slots")
 
     @telemetry.phase("warm")
     def warm(self, cache=None):
@@ -668,60 +747,22 @@ class SequenceScheduler:
         report = {}
         for S in self.slot_buckets:
             x = jnp.asarray(np.zeros((S, self.feature_size), np.float32))
-            carries = [{k: np.zeros((S, self._carry_width(li)),
-                                    self._carry_dtype) for k in keys}
-                       for li, keys in enumerate(self._spec)]
-            key, status, secs = self.model._jit_rnn_step.warm(
+            _note_warm(report, int(S), self.model._jit_rnn_step.warm(
                 self.model._params,
                 self.model._strip_carries(self.model._states),
-                carries, x, cache=cache)
-            if status is not None:
-                report[int(S)] = {"key": key, "status": status,
-                                  "seconds": round(secs, 3)}
+                self._zero_carries(S), x, cache=cache))
         return report
 
-    def close(self, drain=True):
-        """Stop accepting. drain=True serves everything already queued
-        or mid-flight to completion; drain=False fails them with
-        ServingClosedError."""
-        with self._cond:
-            self._closed = True
-            if not drain:
-                while self._pending:
-                    self._pending.popleft().fail(
-                        ServingClosedError("scheduler closed before "
-                                           "a slot was granted"))
-                for req in self._active:
-                    req.fail(ServingClosedError(
-                        "scheduler closed mid-sequence"))
-                self._active = []
-                self._m["depth"].set(0)
-                self._m["active"].set(0)
-            self._cond.notify_all()
-        if drain:
-            self.drain()
-        if self._thread is not None:
-            self._thread.join(timeout=5.0)
-            self._thread = None
-        # release this instance's registry series (MicroBatcher.close
-        # precedent: per-instance series must not accumulate forever)
-        reg = self._registry
-        for metric in _SEQ_METRIC_FAMILIES:
-            fam = reg.get(metric)
-            if fam is not None:
-                fam.remove(model=self.name)
-        return self
 
-
-class GenerationRequest:
+class GenerationRequest(_SlotRequest):
     """One token-prompt generation request on the KV-slot path.
 
     The prompt is consumed in page-sized prefill chunks; generation
     then appends one token per decode iteration until ``max_new``
     tokens have been sampled. ``pages``/``block_row``/``seq_len`` are
     the slot's KV state (owned page ids, logical-block -> physical-page
-    row, live KV rows). ``wait`` follows the serving tier's one release
-    contract — see ``queue.InferenceRequest.wait``.
+    row, live KV rows). The result is the sampled token ids [max_new]
+    (int64).
 
     The request's timeline, seconds on the scheduler's clock, always
     set (telemetry on or off) and part of the API: ``enqueued_at``
@@ -733,22 +774,19 @@ class GenerationRequest:
     tokens are the differences of ``token_times``."""
 
     __slots__ = ("tokens", "max_new", "sampler", "rng", "stream_id",
-                 "enqueued_at", "deadline", "started_at",
                  "first_chunk_at", "first_token_at", "token_times",
                  "finished_at", "chunks", "prefilled",
                  "seq_len", "pages", "block_row", "out_tokens",
-                 "logits_rows", "logits", "result", "error", "_event")
+                 "logits_rows", "logits")
 
     def __init__(self, tokens, enqueued_at, deadline=None, max_new=1,
                  sampler=None, rng=None, stream_id=0):
+        super().__init__(enqueued_at, deadline)
         self.tokens = tokens                # [T] int32 prompt
         self.max_new = int(max_new)
         self.sampler = sampler
         self.rng = rng
         self.stream_id = int(stream_id)
-        self.enqueued_at = float(enqueued_at)
-        self.deadline = None if deadline is None else float(deadline)
-        self.started_at = None
         self.first_chunk_at = None
         self.first_token_at = None
         self.token_times = []               # one clock read a token
@@ -761,36 +799,19 @@ class GenerationRequest:
         self.out_tokens = []                # sampled tokens, in order
         self.logits_rows = []               # fp32 [V] per sampled token
         self.logits = None                  # stacked at finish
-        self.result = None
-        self.error = None
-        self._event = threading.Event()
 
-    @property
-    def done(self):
-        return self._event.is_set()
+    def progress(self):
+        """How far the generation is, for an error's text."""
+        return f"{len(self.out_tokens)}/{self.max_new} tokens"
 
-    def finish(self, result):
+    def finish(self):
         self.logits = (np.stack(self.logits_rows, axis=0)
                        if self.logits_rows else None)
-        self.result = result
+        self.result = np.asarray(self.out_tokens, np.int64)
         self._event.set()
 
-    def fail(self, exc):
-        self.error = exc
-        self._event.set()
 
-    def wait(self, timeout=None):
-        """Block for the sampled token ids [max_new] (int64). Release
-        rules are the serving tier's single wait contract —
-        ``queue.InferenceRequest.wait``."""
-        if not self._event.wait(timeout):
-            raise DeadlineExceededError(f"no result within {timeout:.3f}s")
-        if self.error is not None:
-            raise self.error
-        return self.result
-
-
-class PagedSequenceScheduler:
+class PagedSequenceScheduler(_SlotScheduler):
     """Iteration-level KV-slot scheduler over one paged-attention LM
     (``nn.transformer.CausalTransformerLM`` or any ``kind ==
     "paged_lm"`` twin).
@@ -803,7 +824,7 @@ class PagedSequenceScheduler:
     a long prompt can never stall the running batch) — with one
     slot-batched decode step over every fully-prefilled slot.
     Admission, buckets, per-step deadlines, ManualClock/poll()/drain(),
-    and the dl4j_seq_* metric families are the same discipline as
+    and the dl4j_seq_* metric families are the base's, shared with
     ``SequenceScheduler``; pool exhaustion surfaces as the typed
     ``KVCacheFullError`` (429), never a hang. Prefix sharing
     (``prefix_sharing=True``) adopts a registered prompt's pages
@@ -836,38 +857,19 @@ class PagedSequenceScheduler:
     """
 
     def __init__(self, model, *, num_pages, slot_buckets=None,
-                 queue_limit=64, admission="step", sampler=None,
-                 sampler_seed=0, prefix_sharing=True, clock=None,
-                 start_thread=True, name=None):
-        from deeplearning4j_tpu.serving.sampling import greedy_sampler
-
+                 queue_limit=64, sampler=None, sampler_seed=0,
+                 prefix_sharing=True, clock=None, start_thread=True,
+                 name=None):
         if getattr(model, "kind", None) != "paged_lm":
             raise ValueError(
                 "PagedSequenceScheduler needs a paged-LM step twin "
                 f"(kind == 'paged_lm'), got {type(model).__name__}")
-        if admission not in ("step", "gang"):
-            raise ValueError(
-                f"admission must be 'step' (iteration-level) or 'gang' "
-                f"(run-to-completion baseline), got {admission!r}")
-        if int(queue_limit) < 1:
-            raise ValueError(f"queue_limit must be >= 1, got {queue_limit}")
-        self.model = model
+        super().__init__(model, slot_buckets=slot_buckets,
+                         queue_limit=queue_limit, clock=clock, name=name)
         self.vocab = int(model.vocab)
-        buckets = slot_buckets or DEFAULT_SLOT_BUCKETS
-        self.slot_buckets = tuple(sorted(int(b) for b in buckets))
-        if self.slot_buckets[0] < 1:
-            raise ValueError(f"slot buckets must be >= 1, got {buckets}")
-        self.max_slots = self.slot_buckets[-1]
-        self.queue_limit = int(queue_limit)
-        self.admission = admission
         self.sampler = sampler if sampler is not None else greedy_sampler()
         self.sampler_seed = int(sampler_seed)
         self.prefix_sharing = bool(prefix_sharing)
-        # one clock for every program span: the registry's, unless a
-        # test injects its own (docs/OBSERVABILITY.md "One clock")
-        self.clock = clock if clock is not None \
-            else telemetry.get_registry().clock
-        self.name = str(name) if name else f"seq{next(_SCHED_SEQ)}"
         self.cache = PagedKVCache(
             n_layers=model.n_layers, n_heads=model.n_heads,
             head_dim=model.head_dim, page_size=model.page_size,
@@ -876,27 +878,10 @@ class PagedSequenceScheduler:
         self._mp = int(model.max_pages_per_slot)
         impl = getattr(model, "attend_impl", None)
         self._attend = impl() if impl is not None else "reference"
-        self._cond = threading.Condition()
-        self._step_lock = threading.Lock()
-        self._pending = deque()
-        self._active = []                   # the KV-slot table
-        self._staging = {}                  # S -> reused decode buffers
-        #: host bytes served from the staging pool instead of fresh
-        #: np.zeros (the bench decode leg's alloc-reduction record)
-        self.staging_reuse_bytes = 0
         self._stream_ids = itertools.count(0)
-        self._closed = False
-        #: (live_decode_slots, bucket) per decode dispatch
-        self.occupancy = []
         #: prefill passes dispatched (the interleave record)
         self.prefill_chunks = 0
-        reg = telemetry.get_registry()
-        self._registry = reg
-        self._m = _seq_metrics(reg, self.name)
-        self._thread = None
-        if start_thread:
-            self._thread = threading.Thread(target=self._loop, daemon=True)
-            self._thread.start()
+        self._open(start_thread)
 
     # -- submit ---------------------------------------------------------
     def submit(self, tokens, deadline=None, max_new_tokens=1,
@@ -930,42 +915,45 @@ class PagedSequenceScheduler:
                 f"sequence needs {self.cache.pages_for(total)} pages, "
                 f"pool capacity is {self.cache.capacity} — unservable "
                 f"at any load")
-        with self._cond:
-            if self._closed:
-                raise ServingClosedError("sequence scheduler is closed")
-            if len(self._pending) >= self.queue_limit:
-                self._m["rejected"].inc()
-                raise QueueFullError(
-                    f"sequence queue full ({len(self._pending)} waiting, "
-                    f"queueLimit={self.queue_limit})")
+
+        def make_req(now):      # under the queue's lock: ids in submit order
             sid = next(self._stream_ids)
-            from deeplearning4j_tpu.serving.sampling import stream_rng
-            req = GenerationRequest(
-                tokens, self.clock(), deadline, max_new=max_new,
+            return GenerationRequest(
+                tokens, now, deadline, max_new=max_new,
                 sampler=sampler if sampler is not None else self.sampler,
                 rng=stream_rng(self.sampler_seed, sid), stream_id=sid)
-            self._pending.append(req)
-            self._m["sequences"].inc()
-            self._m["depth"].set(len(self._pending))
-            self._cond.notify()
-        if wait:
-            return req.wait(timeout)
-        return req
 
-    # -- scheduling core ------------------------------------------------
-    def _release_req(self, req):
-        """Return a request's pages to the pool (slot teardown)."""
+        return self._enqueue(make_req, wait, timeout)
+
+    # -- what a slot holds ----------------------------------------------
+    def _admit_locked(self, req, now):
+        """A block row for the slot; prefix sharing adopts registered
+        pages copy-on-write here. An exact-prompt adoption may complete
+        the prompt outright — its first token is sampled from the
+        registered logits, which are handed back with the request."""
+        req.block_row = np.zeros((self._mp,), np.int32)
+        if not self.prefix_sharing:
+            return None
+        pages, n_shared, logits = self.cache.match_prefix(req.tokens)
+        if pages:
+            req.pages = list(pages)
+            req.block_row[:len(pages)] = pages
+            req.prefilled = req.seq_len = int(n_shared)
+        if logits is None:
+            return None
+        req.first_chunk_at = now            # adopted whole: no chunk
+        return req, logits
+
+    def _end_req(self, req, exc=None):
+        """A request ends, done (exc None) or failed: its pages go back
+        to the pool, ``finished_at`` is stamped and its timeline left as
+        one instant ``sequence.request`` before its waiter is released.
+        An instant and not a span as long as the request: such a span
+        would cover every device-idle gap of a busy scheduler and take,
+        in an idle-gap attribution, the time that belongs to no span."""
         if req.pages:
             self.cache.release(req.pages)
             req.pages = []
-
-    def _end_req(self, req, exc=None):
-        """The one place a request ends, done (exc None) or failed:
-        stamp ``finished_at``, leave its timeline as one instant
-        ``sequence.request`` and release its waiter. An instant and not
-        a span as long as the request: such a span would cover every
-        device-idle gap of a busy scheduler and take, in an idle-gap
-        attribution, the time that belongs to no span."""
         req.finished_at = now = self.clock()
         self._registry.event(
             "sequence.request", "serving", ts=now, rid=req.stream_id,
@@ -976,92 +964,7 @@ class PagedSequenceScheduler:
             first_token_at=req.first_token_at, finished_at=now,
             token_times=tuple(req.token_times),
             error=None if exc is None else type(exc).__name__)
-        if exc is None:
-            req.finish(np.asarray(req.out_tokens, np.int64))
-        else:
-            req.fail(exc)
-
-    def _expire_locked(self, now):
-        """Fail what is past its deadline, queued or mid-flight;
-        returns how many."""
-        keep = deque()
-        n = len(self._pending) + len(self._active)
-        for req in self._pending:
-            if req.deadline is not None and now >= req.deadline:
-                self._m["expired"].inc()
-                self._end_req(req, DeadlineExceededError(
-                    f"deadline passed {now - req.deadline:.3f}s before "
-                    "a slot was granted"))
-            else:
-                keep.append(req)
-        self._pending = keep
-        live = []
-        for req in self._active:
-            if req.deadline is not None and now >= req.deadline:
-                self._m["expired"].inc()
-                self._release_req(req)
-                self._end_req(req, DeadlineExceededError(
-                    f"deadline passed at {len(req.out_tokens)}/"
-                    f"{req.max_new} tokens — slot released "
-                    "mid-generation"))
-            else:
-                live.append(req)
-        self._active = live
-        self._m["depth"].set(len(self._pending))
-        self._m["active"].set(len(self._active))
-        return n - len(keep) - len(live)
-
-    def _refill_locked(self, now):
-        """Admit queued prompts into free KV slots; prefix sharing
-        adopts registered pages copy-on-write here. An exact-prompt
-        adoption may complete the prompt outright — its first token is
-        sampled from the registered logits. Returns (how many were
-        admitted, those adoptions for the caller to process OUTSIDE
-        this lock)."""
-        adopted_done = []
-        if self.admission == "gang" and self._active:
-            return 0, adopted_done
-        midrun = any(r.seq_len > 0 for r in self._active)
-        admitted = 0
-        while self._pending and len(self._active) < self.max_slots:
-            req = self._pending.popleft()
-            req.started_at = now
-            admitted += 1
-            req.block_row = np.zeros((self._mp,), np.int32)
-            logits = None
-            if self.prefix_sharing:
-                pages, n_shared, logits = self.cache.match_prefix(
-                    req.tokens)
-                if pages:
-                    req.pages = list(pages)
-                    req.block_row[:len(pages)] = pages
-                    req.prefilled = req.seq_len = int(n_shared)
-            self._active.append(req)
-            self._m["wait"].observe(now - req.enqueued_at)
-            if midrun:
-                self._m["refills"].inc()
-            if logits is not None:
-                req.first_chunk_at = now    # adopted whole: no chunk
-                adopted_done.append((req, logits))
-        self._m["depth"].set(len(self._pending))
-        self._m["active"].set(len(self._active))
-        return admitted, adopted_done
-
-    def bucket_for(self, n):
-        """Smallest slot bucket >= n live slots."""
-        for b in self.slot_buckets:
-            if n <= b:
-                return b
-        return self.slot_buckets[-1]
-
-    def _fail_req(self, req, exc):
-        """Fail one mid-flight request and free its slot + pages."""
-        self._release_req(req)
-        with self._cond:
-            self._m["errors"].inc()
-            self._end_req(req, exc)
-            self._active = [r for r in self._active if r is not req]
-            self._m["active"].set(len(self._active))
+        super()._end_req(req, exc)
 
     def _complete_prompt(self, req, last_logits):
         """The prompt is fully in KV: sample the first generated token
@@ -1078,7 +981,6 @@ class PagedSequenceScheduler:
         return False
 
     def _finish_req(self, req):
-        self._release_req(req)
         with self._cond:
             self._active = [r for r in self._active if r is not req]
             self._m["completed"].inc()
@@ -1119,7 +1021,7 @@ class PagedSequenceScheduler:
                 self.cache.v_pools, req.block_row)
             self.cache.k_pools, self.cache.v_pools = kps, vps
         except Exception as e:
-            self._fail_req(req, e)
+            self._fail_active([req], e)
             return True                     # progress: the slot freed
         finally:
             t1c = self.clock()
@@ -1153,18 +1055,11 @@ class PagedSequenceScheduler:
         return paged_pages_visited(self._attend, lengths,
                                    self.model.page_size, self._mp)
 
-    def _staging_for(self, S):
-        """Per-bucket decode staging buffers (tokens, seq lens, block
-        tables), allocated once and reused every iteration — the same
-        alloc-churn fix as the carry path's _gather pool."""
-        st = self._staging.get(S)
-        if st is None:
-            st = (np.zeros((S,), np.int32), np.zeros((S,), np.int32),
-                  np.zeros((S, self._mp), np.int32))
-            self._staging[S] = st
-        else:
-            self.staging_reuse_bytes += sum(a.nbytes for a in st)
-        return st
+    def _new_staging(self, S):
+        """Decode staging of one bucket: tokens, seq lens, block
+        tables."""
+        return (np.zeros((S,), np.int32), np.zeros((S,), np.int32),
+                np.zeros((S, self._mp), np.int32))
 
     def _decode_batch(self, batch, parent=None):
         """One slot-batched decode step over every fully-prefilled
@@ -1192,7 +1087,7 @@ class PagedSequenceScheduler:
                                      for p in req.pages]
                 ready.append(req)
             except Exception as e:
-                self._fail_req(req, e)
+                self._fail_active([req], e)
         if not ready:
             return 0
         S = self.bucket_for(len(ready))
@@ -1225,14 +1120,7 @@ class PagedSequenceScheduler:
                          self.clock() - t_f, parent=step_id,
                          bytes=out.nbytes)
         except Exception as e:
-            with self._cond:
-                self._m["errors"].inc(len(ready))
-                for req in ready:
-                    self._release_req(req)
-                    self._end_req(req, e)
-                self._active = [r for r in self._active
-                                if r not in ready]
-                self._m["active"].set(len(self._active))
+            self._fail_active(ready, e)
             return 0
         finally:
             t_s = self.clock()
@@ -1259,10 +1147,6 @@ class PagedSequenceScheduler:
                      self.clock() - t_s, parent=parent, slots=n,
                      finished=len(finished))
         return n
-
-    def _step_once(self):
-        with self._step_lock:
-            return self._iterate_locked()  # fault-ok[FLT04]: the step lock is the scheduler's own serialization contract — a seam firing under it IS the wedged-scheduler fault the harness injects, and waiters are released by deadline expiry (the wait contract), never by this lock
 
     def _iterate_locked(self):
         """One iteration: expire -> refill (prefix adoption) -> at most
@@ -1305,61 +1189,6 @@ class PagedSequenceScheduler:
                      prefill=int(pre is not None), decode_slots=slots)
         return progress + slots
 
-    # -- drivers --------------------------------------------------------
-    def poll(self):
-        """One synchronous scheduler iteration (the thread-less test
-        seam). Returns the progress count — 0 means idle."""
-        return self._step_once()
-
-    def drain(self):
-        """Run iterations until the table AND queue are empty."""
-        while self._step_once():
-            pass
-        return self
-
-    def _loop(self):
-        while _wait_for_work(self):
-            try:
-                self._step_once()
-            except Exception as e:
-                # defensive: an unexpected scheduler bug must release
-                # every waiter, never leave them blocked on a dead
-                # thread; the loop stays up for new submits
-                self._fail_all(e)
-
-    def _fail_all(self, exc):
-        with self._cond:
-            n = len(self._pending) + len(self._active)
-            if n:
-                self._m["errors"].inc(n)
-            while self._pending:
-                self._end_req(self._pending.popleft(), exc)
-            for req in self._active:
-                self._release_req(req)
-                self._end_req(req, exc)
-            self._active = []
-            self._m["depth"].set(0)
-            self._m["active"].set(0)
-
-    # -- introspection / lifecycle --------------------------------------
-    @property
-    def depth(self):
-        with self._cond:
-            return len(self._pending)
-
-    @property
-    def active_slots(self):
-        with self._cond:
-            return len(self._active)
-
-    @property
-    def stats(self):
-        """Dict view over the registry counters (dl4j_seq_*)."""
-        return {k: int(self._m[k].value) for k in _STAT_KEYS}
-
-    def occupancy_summary(self):
-        return occupancy_summary_from(self.occupancy, "mean_live_slots")
-
     @telemetry.phase("warm")
     def warm(self, cache=None):
         """Precompile the decode executable for EVERY slot bucket plus
@@ -1373,59 +1202,28 @@ class PagedSequenceScheduler:
 
         report = {}
         for S in self.slot_buckets:
-            tok = np.zeros((S,), np.int32)
-            sls = np.zeros((S,), np.int32)
-            bts = np.zeros((S, self._mp), np.int32)
-            key, status, secs = self.model._jit_decode.warm(
+            tok, sls, bts = self._new_staging(S)
+            _note_warm(report, int(S), self.model._jit_decode.warm(
                 self.model._params, tok, self.cache.k_pools,
-                self.cache.v_pools, bts, sls, cache=cache)
-            if status is not None:
-                report[int(S)] = {"key": key, "status": status,
-                                  "seconds": round(secs, 3)}
+                self.cache.v_pools, bts, sls, cache=cache))
         bt = np.zeros((self._mp,), np.int32)
         for n in PREFILL_CHUNK_PAGES:
             if n > self._mp:
                 break
             chunk = np.zeros((n * self.model.page_size,), np.int32)
-            key, status, secs = self.model._jit_prefill.warm(
-                self.model._params, chunk, jnp.asarray(0, jnp.int32),
-                jnp.asarray(1, jnp.int32), self.cache.k_pools,
-                self.cache.v_pools, bt, cache=cache)
-            if status is not None:
-                report["prefill" if n == 1 else f"prefill{n}"] = {
-                    "key": key, "status": status,
-                    "seconds": round(secs, 3)}
+            _note_warm(
+                report, "prefill" if n == 1 else f"prefill{n}",
+                self.model._jit_prefill.warm(
+                    self.model._params, chunk, jnp.asarray(0, jnp.int32),
+                    jnp.asarray(1, jnp.int32), self.cache.k_pools,
+                    self.cache.v_pools, bt, cache=cache))
         return report
 
     def close(self, drain=True):
         """Stop accepting. drain=True serves everything queued or
         mid-flight to completion; drain=False fails them with
-        ServingClosedError and frees their pages."""
-        with self._cond:
-            self._closed = True
-            if not drain:
-                while self._pending:
-                    self._end_req(
-                        self._pending.popleft(),
-                        ServingClosedError("scheduler closed before "
-                                           "a slot was granted"))
-                for req in self._active:
-                    self._release_req(req)
-                    self._end_req(req, ServingClosedError(
-                        "scheduler closed mid-generation"))
-                self._active = []
-                self._m["depth"].set(0)
-                self._m["active"].set(0)
-            self._cond.notify_all()
-        if drain:
-            self.drain()
-        if self._thread is not None:
-            self._thread.join(timeout=5.0)
-            self._thread = None
+        ServingClosedError and frees their pages. Then the pool's
+        prefix registry and series go."""
+        super().close(drain)
         self.cache.close()
-        reg = self._registry
-        for metric in _SEQ_METRIC_FAMILIES:
-            fam = reg.get(metric)
-            if fam is not None:
-                fam.remove(model=self.name)
         return self

@@ -221,6 +221,54 @@ class TestFixturePairs:
         assert "FLT01" not in _codes(lint_fault_source(src, seams=SEAMS))
 
 
+#: a base that owns the thread, the lock and the loop, and a subclass
+#: that owns the step: the shape of serving/sequence.py's slot
+#: schedulers. `{seam}` is the body of the subclass's step.
+_BASE_AND_HOOK = """
+    import threading
+    from deeplearning4j_tpu.runtime.chaos import fault_point
+
+    class Base:
+        def __init__(self):
+            self._lock = threading.Lock()
+            self._thread = threading.Thread(target=self._loop)
+
+        def _loop(self):
+            {loop}
+
+    class Sub(Base):
+        def _step(self):
+            {step}
+"""
+
+
+class TestSameModuleInheritance:
+    """``self.m()`` resolves through the class's same-module bases and
+    subclasses, and a base's lock is its subclasses' lock."""
+
+    @staticmethod
+    def _lint(loop, step):
+        return _codes(lint_fault_source(textwrap.dedent(
+            _BASE_AND_HOOK.format(loop=loop, step=step)), seams=SEAMS))
+
+    def test_seam_in_a_subclass_hook_covers_the_bases_thread(self):
+        assert "FLT02" not in self._lint("self._step()",
+                                         'fault_point("x.y")')
+
+    def test_no_seam_in_any_override_still_trips(self):
+        assert "FLT02" in self._lint("self._step()", "pass")
+
+    def test_hook_with_a_seam_called_under_the_bases_lock_trips(self):
+        loop = "with self._lock:\n                self._step()"
+        assert "FLT04" in self._lint(loop, 'fault_point("x.y")')
+        assert "FLT04" not in self._lint("self._step()",
+                                         'fault_point("x.y")')
+
+    def test_seam_under_the_inherited_lock_in_the_subclass_trips(self):
+        step = 'with self._lock:\n                fault_point("x.y")'
+        assert "FLT04" in self._lint("self._step()", step)
+
+
 class TestSuppressions:
     def test_reasoned_suppression_carries_but_passes(self):
         src = textwrap.dedent("""

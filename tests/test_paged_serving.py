@@ -11,8 +11,10 @@ What must hold (the ISSUE 19 serving acceptance):
   same ``paged_attend`` core, so parity is structural);
 - scheduling: at most ONE prefill pass of one slot per iteration
   interleaves with the decode batch (a long prompt never stalls
-  running generations), deadlines are honored per step and free pages,
-  ManualClock + thread-less poll()/drain() is deterministic;
+  running generations), ManualClock + thread-less poll()/drain() is
+  deterministic (deadlines and close(drain=False) giving the pages
+  back: tests/test_slot_scheduler_contract.py, with the rest of the
+  base's contract);
 - the plan (ISSUE 31): ``prefill_plan`` is a pure function that covers
   a prompt exactly once in chunks of ``PREFILL_CHUNK_PAGES`` pages,
   largest first but for a single page left behind, never past the
@@ -47,8 +49,8 @@ from deeplearning4j_tpu.nn.transformer import (
 )
 from deeplearning4j_tpu.runtime import aot
 from deeplearning4j_tpu.serving import (
-    DeadlineExceededError, KVCacheFullError, ManualClock, ModelHost,
-    PagedSequenceScheduler, ServingClosedError, greedy_sampler,
+    KVCacheFullError, ManualClock, ModelHost, PagedSequenceScheduler,
+    greedy_sampler,
     stream_rng, temperature_sampler,
 )
 
@@ -306,33 +308,6 @@ class TestScheduling:
         s.drain()
         assert long.wait(1.0).shape == (2,)
         s.close()
-
-    def test_deadline_mid_generation_frees_pages(self):
-        m = _lm()
-        s, clk = _sched(m, prefix_sharing=False)
-        req = s.submit(_prompts((9,), m.vocab)[0], max_new_tokens=30,
-                       deadline=5.0, wait=False)
-        s.poll()
-        s.poll()
-        assert s.cache.pages_in_use > 0 and not req.done
-        clk.advance(10.0)
-        s.poll()
-        with pytest.raises(DeadlineExceededError):
-            req.wait(1.0)
-        assert s.cache.pages_in_use == 0
-        assert s.stats["expired"] == 1
-        s.close()
-
-    def test_close_without_drain_fails_and_frees(self):
-        m = _lm()
-        s, _ = _sched(m, prefix_sharing=False)
-        req = s.submit(_prompts((6,), m.vocab)[0], max_new_tokens=20,
-                       wait=False)
-        s.poll()
-        s.close(drain=False)
-        with pytest.raises(ServingClosedError):
-            req.wait(1.0)
-        assert s.cache.pages_in_use == 0
 
     def test_sampling_streams_deterministic_per_seed(self):
         """Same (sampler_seed, submit order) -> identical draws across

@@ -8,15 +8,13 @@ What must hold:
   zero-padded slots included (fixed slot bucket: within one bucket
   parity is structural);
 - scheduling: early-exit slots are refilled from the queue
-  MID-SEQUENCE, per-request deadlines are honored at every STEP
-  boundary (queued or mid-flight), occupancy accounting is exact;
+  MID-SEQUENCE and occupancy accounting is exact (the queue's bound,
+  deadlines queued and mid-flight and both ways to close are the
+  base's: tests/test_slot_scheduler_contract.py holds them for both
+  kinds of slot);
 - compile discipline: ``warm()`` precompiles one executable per slot
   bucket and a whole mixed-length serve pays ZERO further compiles
   (CompileWatch);
-- throughput: iteration-level scheduling beats run-to-completion
-  (gang) batching by >= 2x aggregate decode throughput on a
-  straggler-skewed workload — deterministically in dispatch counts AND
-  in wall clock (the ISSUE 15 acceptance gate);
 - the scheduler exposes the MicroBatcher's deterministic test seam:
   ManualClock + thread-less ``poll()``/``drain()``, zero sleeps.
 """
@@ -30,8 +28,7 @@ import jax
 
 from deeplearning4j_tpu.runtime import aot
 from deeplearning4j_tpu.serving import (
-    DeadlineExceededError, ManualClock, ModelHost, QueueFullError,
-    SequenceScheduler, ServingClosedError, greedy_onehot_feedback,
+    ManualClock, ModelHost, SequenceScheduler, greedy_onehot_feedback,
 )
 
 
@@ -219,55 +216,6 @@ class TestSchedulerDeterministic:
             np.testing.assert_array_equal(r.result, want)
         sched.close()
 
-    def test_deadline_expires_at_step_boundary_and_frees_slot(self):
-        net = _rnn_net()
-        sched, clk = _sched(net, slot_buckets=(1,))
-        doomed = sched.submit(_seqs([6], seed=2)[0], wait=False,
-                              deadline=clk() + 0.5)
-        queued = sched.submit(_seqs([2], seed=3)[0], wait=False)
-        assert sched.poll() == 1          # doomed steps once
-        assert doomed.steps_done == 1 and not doomed.done
-        clk.advance(1.0)                  # deadline passes MID-FLIGHT
-        assert sched.poll() == 1          # expiry freed the slot;
-        #                                   queued was admitted SAME tick
-        assert isinstance(doomed.error, DeadlineExceededError)
-        assert "mid-sequence" in str(doomed.error)
-        sched.drain()
-        assert queued.done and queued.error is None
-        st = sched.stats
-        assert st["expired"] == 1 and st["completed"] == 1
-        sched.close()
-
-    def test_queued_deadline_expires_without_a_slot(self):
-        net = _rnn_net()
-        sched, clk = _sched(net, slot_buckets=(1,))
-        hog = sched.submit(_seqs([4], seed=4)[0], wait=False)
-        doomed = sched.submit(_seqs([1], seed=5)[0], wait=False,
-                              deadline=clk() + 0.5)
-        sched.poll()
-        clk.advance(1.0)
-        sched.drain()
-        assert hog.done and hog.error is None
-        assert isinstance(doomed.error, DeadlineExceededError)
-        assert "before a slot" in str(doomed.error)
-        # the doomed sequence never wasted a dispatch
-        assert sched.stats["slot_steps"] == 4
-        sched.close()
-
-    def test_queue_full_and_close_contracts(self):
-        net = _rnn_net()
-        sched, _ = _sched(net, queue_limit=2)
-        r1 = sched.submit(_seqs([2], seed=6)[0], wait=False)
-        sched.submit(_seqs([2], seed=7)[0], wait=False)
-        with pytest.raises(QueueFullError, match="queueLimit=2"):
-            sched.submit(_seqs([1], seed=8)[0], wait=False)
-        assert sched.stats["rejected"] == 1
-        sched.poll()                       # both admitted, one step in
-        sched.close(drain=False)
-        assert isinstance(r1.error, ServingClosedError)
-        with pytest.raises(ServingClosedError):
-            sched.submit(_seqs([1], seed=9)[0], wait=False)
-
     def test_submit_validation(self):
         net = _rnn_net()
         sched, _ = _sched(net)
@@ -278,8 +226,6 @@ class TestSchedulerDeterministic:
         with pytest.raises(ValueError, match="feedback"):
             sched.submit(np.zeros((2, 4), np.float32), wait=False,
                          extra_steps=3)
-        with pytest.raises(ValueError, match="admission"):
-            SequenceScheduler(net, admission="magic")
         sched.close()
 
     def test_dispatch_failure_fails_live_slots(self):
@@ -382,73 +328,6 @@ class TestCompileDiscipline:
         assert {b: r["status"] for b, r in sched.warm().items()} == \
             {2: "warm", 4: "warm"}
         sched.close()
-
-
-# ----------------------------------------------------------------------
-# the acceptance gate: iteration-level >= 2x run-to-completion
-# ----------------------------------------------------------------------
-
-class TestIterationVsGang:
-    #: straggler-skewed workload (the bench serving_fleet twin): short
-    #: sequences interleaved with long stragglers, so every gang batch
-    #: pads its short members to a straggler's length
-    LENS = [24, 2, 2, 2, 2, 2] * 4
-
-    def _run(self, admission, seqs):
-        net = _rnn_net()
-        sched = SequenceScheduler(net, slot_buckets=(8,),
-                                  queue_limit=64, admission=admission,
-                                  clock=ManualClock(),
-                                  start_thread=False)
-        sched.warm()
-        import time as _time
-
-        t0 = _time.perf_counter()
-        reqs = [sched.submit(s, wait=False) for s in seqs]
-        sched.drain()
-        wall = _time.perf_counter() - t0
-        st = sched.stats
-        assert all(r.done and r.error is None for r in reqs)
-        results = [r.result for r in reqs]
-        sched.close()
-        return st, wall, results
-
-    @pytest.mark.slow  # tier-1 budget (PR 21): 39 s on 8 CPU cores
-    def test_iteration_level_2x_gang_and_bitwise(self):
-        """ISSUE 15 acceptance: >= 2x aggregate decode throughput vs
-        run-to-completion batching on a mixed-length workload, per-slot
-        outputs bitwise equal to serial rnnTimeStep in BOTH modes. The
-        dispatch-count ratio is deterministic; the wall-clock ratio is
-        measured with a retry shield against CI-rig noise."""
-        seqs = _seqs(self.LENS, seed=13)
-        oracle = _serial_oracle(_rnn_net(), seqs)
-        best = 0.0
-        for attempt in range(3):
-            st_step, wall_step, res_step = self._run("step", seqs)
-            st_gang, wall_gang, res_gang = self._run("gang", seqs)
-            # same work, bitwise identical results
-            assert st_step["slot_steps"] == st_gang["slot_steps"] \
-                == sum(self.LENS)
-            for got, want in zip(res_step, oracle):
-                np.testing.assert_array_equal(got, want)
-            for got, want in zip(res_gang, oracle):
-                np.testing.assert_array_equal(got, want)
-            # deterministic half of the gate: iteration-level re-forms
-            # the batch every step, so it needs >= 2x fewer dispatches
-            assert st_gang["dispatches"] \
-                >= 2 * st_step["dispatches"], (st_step, st_gang)
-            assert st_step["refills"] > 0       # the lever that does it
-            assert st_gang["refills"] == 0      # gang never refills
-            tok_step = st_step["slot_steps"] / wall_step
-            tok_gang = st_gang["slot_steps"] / wall_gang
-            best = max(best, tok_step / tok_gang)
-            if best >= 2.0:
-                break
-        assert best >= 2.0, (
-            f"iteration-level sustained only {best:.2f}x "
-            f"run-to-completion decode throughput "
-            f"({st_step['dispatches']} vs {st_gang['dispatches']} "
-            "dispatches)")
 
 
 # ----------------------------------------------------------------------

@@ -17,6 +17,9 @@ What must hold:
   survives ``trace.clear()`` and ``host.close()``.
 """
 
+import glob
+import math
+import os
 import threading
 import time
 
@@ -371,6 +374,65 @@ class TestPagedSchedulerSpans:
         assert cache.take_pages_peak() == 1     # nothing allotted since
         cache.release(got[2:])
         s.close()
+
+
+# ----------------------------------------------------------------------
+# the program's spans, read by the yardstick's readers
+# ----------------------------------------------------------------------
+#: every reader under perfbench/metrics/ that takes a span, an event or a
+#: series of the paged scheduler (the others read the device trace, the
+#: trainers or set-up)
+SCHEDULER_READERS = sorted(
+    os.path.basename(p)[:-3] for p in glob.glob(os.path.join(
+        os.path.dirname(__file__), os.pardir, "perfbench", "metrics",
+        "*.py"))
+    if os.path.basename(p).startswith(("seq.", "kv.pages_in_use_max",
+                                       "paged_attend.")))
+
+
+class TestReadersTakeTheProgramsSpans:
+    """tests/test_perfbench_readers.py feeds the readers hand-made
+    spans; here they get what a served scheduler really left, so that a
+    renamed span, argument or family fails in tier-1 and does not cost a
+    metric on the chip."""
+
+    @pytest.fixture(scope="class")
+    def run(self):
+        from test_perfbench_readers import StubRun
+
+        trace = telemetry.get_registry().trace
+        trace.clear()
+        s = _paged(_lm(), name="join")
+        # two prompts of two passes each, five tokens each: a pass that
+        # is not the last, decode steps with one and two live slots
+        assert [len(prefill_plan(n, 0, 8, s._mp)) for n in (44, 30)] == \
+            [2, 2]
+        reqs = [s.submit(_prompt(n, seed=n), max_new_tokens=5, wait=False)
+                for n in (44, 30)]
+        s.drain()
+        assert all(r.error is None for r in reqs)
+
+        class Run(StubRun):
+            window = {"t0": 0.0, "t1": s.clock()}
+            # as perfbench/kinds/serve_generate.py fills it, before the
+            # scheduler closes and takes its series away
+            counters = {"queue_wait_p50_s": telemetry.get_registry().get(
+                "dl4j_seq_queue_wait_seconds").labels_get(
+                    model=s.name).percentile(50)}
+
+        yield Run()
+        s.close()
+        trace.clear()
+
+    def test_there_is_a_reader_for_each_layer_metric(self):
+        assert len(SCHEDULER_READERS) == 17
+
+    @pytest.mark.parametrize("name", SCHEDULER_READERS)
+    def test_reader_gives_a_finite_number(self, name, run):
+        from perfbench import harness
+
+        value = harness.load_module("metrics", name).read(run)
+        assert value is not None and math.isfinite(value), name
 
 
 # ----------------------------------------------------------------------
