@@ -114,7 +114,9 @@ class CausalTransformerLM:
     page (max_context % page_size == 0) and the unit of a prefill
     chunk, which is PREFILL_CHUNK_PAGES pages long (prefill_plan).
     dtype is the compute/storage dtype (params, KV pools, residual
-    stream); logits always come back fp32 for host-side sampling.
+    stream); logits always come back fp32, and the decode step hands
+    back their argmax beside them, so a greedy request's next token
+    is 4 bytes to fetch and its row can follow later.
     """
 
     #: duck-type marker the serving host dispatches on
@@ -238,11 +240,14 @@ class CausalTransformerLM:
         """One decode token per slot. tokens [S] i32 (last sampled),
         kps/vps [L, P, page, H, Dh] pools, bts [S, MP] block tables,
         sls [S] live KV length per slot (the new token's position).
-        Returns (logits [S, V] fp32, kps', vps'). Padded slots (sl=0,
-        block table all null-page) write their garbage row into the
-        null page — identical values for every padded row, never
-        attended by a live slot — and their logits rows are ignored
-        by the scheduler's scatter."""
+        Returns ((ids [S] i32, logits [S, V] fp32), kps', vps'): ids is
+        the argmax of each logits row, the first of equal maxima as
+        ``np.argmax`` takes it, so the scheduler can step a greedy slot
+        on 4 bytes and fetch the rows behind the next step. Padded
+        slots (sl=0, block table all null-page) write their garbage row
+        into the null page — identical values for every padded row,
+        never attended by a live slot — and their ids and logits rows
+        are ignored by the scheduler's scatter."""
         S = tokens.shape[0]
         h = params["emb"][tokens] + params["pos"][sls]
         pages = bts[jnp.arange(S), sls // self.page_size]
@@ -258,7 +263,9 @@ class CausalTransformerLM:
                                   sls)[:, 0]
             h = h + att.reshape(S, self.d_model) @ lp["wo"]
             h = self._mlp(lp, h)
-        return self._logits(params, h), kps, vps
+        logits = self._logits(params, h)
+        ids = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+        return (ids, logits), kps, vps
 
     def _prefill_paged(self, params, tokens, t0, n_valid, kps, vps, bt):
         """One prompt chunk of n whole pages for ONE slot. tokens

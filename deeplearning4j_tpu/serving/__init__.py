@@ -22,8 +22,10 @@ compilation model of arXiv:1810.09868):
   device-resident pool, per-slot block tables, allocation/free at
   step boundaries, copy-on-write prefix sharing; pool exhaustion is
   the typed ``KVCacheFullError`` (429).
-* ``sampling`` — host-side decode samplers (greedy, temperature/top-k)
-  with deterministic per-(seed, stream) RNG streams.
+* ``sampling`` — decode samplers (greedy, temperature/top-k) with
+  deterministic per-(seed, stream) RNG streams: host callables over a
+  logits row, of which the greedy one marks itself so that the paged
+  scheduler takes the decode step's own argmax in its place.
 * ``host``     — multi-model host: model name -> (network, dtype policy,
   optional weight-only int8, batch buckets), each precompiled at
   registration, with a rolling model swap that warms the new version's

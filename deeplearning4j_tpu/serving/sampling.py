@@ -10,8 +10,15 @@ with temperature sampling exactly as it does with greedy, because the
 serial oracle replays the same stream (tests/test_paged_serving.py).
 
 ``greedy_sampler`` ignores its rng (argmax — the default, mirroring
-``greedy_onehot_feedback`` on the RNN path, which stays). The RNN
-path's one-hot twin of a sampler is ``sampled_onehot_feedback``.
+``greedy_onehot_feedback`` on the RNN path, which stays) and says so
+of itself: the callable it returns carries ``picks_argmax = True``,
+and the paged scheduler steps a request whose sampler is so marked on
+the argmax its decode step computed on the device, without waiting for
+the row (serving/sequence.py). Every other callable — a temperature
+draw, a user's function, a wrapper around the greedy one — is called
+on the host with its float32 row and its rng, as the serial oracle
+calls all of them. The RNN path's one-hot twin of a sampler is
+``sampled_onehot_feedback``.
 """
 
 from __future__ import annotations
@@ -30,11 +37,13 @@ def stream_rng(seed, stream_id):
 
 
 def greedy_sampler():
-    """argmax over the logits row — deterministic, rng unused."""
+    """argmax over the logits row — deterministic, rng unused. Marked
+    ``picks_argmax`` (module docstring)."""
 
     def sample(logits, rng):
         return int(np.argmax(logits))
 
+    sample.picks_argmax = True
     return sample
 
 
@@ -50,12 +59,11 @@ def temperature_sampler(temperature=1.0, top_k=None):
     if top_k is not None and int(top_k) < 1:
         raise ValueError(f"top_k must be >= 1, got {top_k}")
     top_k = None if top_k is None else int(top_k)
+    if temperature == 0:
+        return greedy_sampler()
 
     def sample(logits, rng):
-        z = np.asarray(logits, np.float64)
-        if temperature == 0:
-            return int(np.argmax(z))
-        z = z / temperature
+        z = np.asarray(logits, np.float64) / temperature
         if top_k is not None and top_k < z.shape[0]:
             # keep the k largest; ties break by index like argpartition
             cut = np.argpartition(z, -top_k)[:-top_k]

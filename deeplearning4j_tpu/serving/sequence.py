@@ -762,7 +762,12 @@ class GenerationRequest(_SlotRequest):
     tokens have been sampled. ``pages``/``block_row``/``seq_len`` are
     the slot's KV state (owned page ids, logical-block -> physical-page
     row, live KV rows). The result is the sampled token ids [max_new]
-    (int64).
+    (int64); ``logits`` is the float32 row each was sampled from,
+    [max_new, V], whole once the request is done (the rows of a
+    request still generating arrive a step behind its tokens).
+    ``device_pick`` says the request's sampler marks itself the greedy
+    pick (``sampling.greedy_sampler``), so its decode tokens are the
+    step's own argmax.
 
     The request's timeline, seconds on the scheduler's clock, always
     set (telemetry on or off) and part of the API: ``enqueued_at``
@@ -777,7 +782,7 @@ class GenerationRequest(_SlotRequest):
                  "first_chunk_at", "first_token_at", "token_times",
                  "finished_at", "chunks", "prefilled",
                  "seq_len", "pages", "block_row", "out_tokens",
-                 "logits_rows", "logits")
+                 "device_pick", "_rows", "logits")
 
     def __init__(self, tokens, enqueued_at, deadline=None, max_new=1,
                  sampler=None, rng=None, stream_id=0):
@@ -785,6 +790,7 @@ class GenerationRequest(_SlotRequest):
         self.tokens = tokens                # [T] int32 prompt
         self.max_new = int(max_new)
         self.sampler = sampler
+        self.device_pick = bool(getattr(sampler, "picks_argmax", False))
         self.rng = rng
         self.stream_id = int(stream_id)
         self.first_chunk_at = None
@@ -797,16 +803,23 @@ class GenerationRequest(_SlotRequest):
         self.pages = []                     # owned page ids (in order)
         self.block_row = None               # [MP] int32
         self.out_tokens = []                # sampled tokens, in order
-        self.logits_rows = []               # fp32 [V] per sampled token
-        self.logits = None                  # stacked at finish
+        self._rows = None                   # fp32 [max_new, V], made once
+        self.logits = None                  # the block, at finish
 
     def progress(self):
         """How far the generation is, for an error's text."""
         return f"{len(self.out_tokens)}/{self.max_new} tokens"
 
+    def put_row(self, k, row):
+        """Keep the float32 logits row the k-th token was sampled from,
+        in the request's one block: ``finish`` copies nothing."""
+        if self._rows is None:
+            self._rows = np.empty((self.max_new, row.shape[-1]),
+                                  np.float32)
+        self._rows[k] = row
+
     def finish(self):
-        self.logits = (np.stack(self.logits_rows, axis=0)
-                       if self.logits_rows else None)
+        self.logits = self._rows
         self.result = np.asarray(self.out_tokens, np.int64)
         self._event.set()
 
@@ -830,27 +843,44 @@ class PagedSequenceScheduler(_SlotScheduler):
     (``prefix_sharing=True``) adopts a registered prompt's pages
     copy-on-write at admission.
 
-    Sampling is host-side: ``sampler(logits_row, rng) -> token`` with a
-    per-request ``stream_rng(sampler_seed, stream_id)`` stream, stream
-    ids assigned in submit order — deterministic per (seed, stream), so
-    the bitwise-vs-serial gate holds with temperature sampling too.
+    A request's sampler decides where its decode tokens are picked. One
+    that marks itself the greedy pick (``sampling.greedy_sampler``, the
+    default) takes the argmax ``_decode_paged`` returns beside the
+    logits: a step whose live slots are all such waits for their ids (4
+    bytes a slot) and its rows are copied to the host and into the
+    requests' blocks behind the next step's dispatch, while the device
+    runs it (``_land``). Any
+    other callable is called on the host, ``sampler(logits_row, rng) ->
+    token`` with a per-request ``stream_rng(sampler_seed, stream_id)``
+    stream, stream ids assigned in submit order — deterministic per
+    (seed, stream), so the bitwise-vs-serial gate holds with temperature
+    sampling too; a step with such a slot, or one that ends a request,
+    waits for its rows as well. A prompt's first token is sampled on
+    the host from the prefill's row either way. Every row is kept: a
+    finished request's ``logits`` holds one for each token.
 
     Spans (cat ``serving``, on this scheduler's clock; the tree is in
     docs/OBSERVABILITY.md): every iteration that found work is one
     ``sequence.iteration`` whose children are ``sequence.admit``,
     ``sequence.prefill`` and ``sequence.prefill_finish`` (rid = the
     request's ``stream_id``), ``sequence.decode_prep``,
-    ``sequence.step`` (child ``sequence.fetch``) and
-    ``sequence.sample``; a request that ends, done or failed, leaves
-    one instant ``sequence.request`` with its whole timeline.
+    ``sequence.step`` (child ``sequence.fetch``: the wait for what the
+    step needs now, the ids or the ids and the rows, ``bytes`` says
+    which), ``sequence.land`` (a decode step's ``rows`` written into
+    their requests' blocks: the step before's behind this one's
+    dispatch, inside ``sequence.step``'s interval, or this one's own
+    after its fetch) and ``sequence.sample``; a request that ends, done
+    or failed, leaves one instant ``sequence.request`` with its whole
+    timeline.
     ``sequence.prefill`` carries the pass: ``chunk`` prompt tokens in a
     chunk of ``bucket`` tokens (the executable's length).
-    ``sequence.step`` and ``sequence.prefill`` say what the dispatcher
-    chose for their attention: ``attend`` (``"pallas"`` or
-    ``"reference"``, the model's ``attend_impl()``), ``pages_visited``
-    (the live pages of the step's live slots, or of the chunk's query
-    tiles of one page, on the kernel path, their whole tables on the
-    reference path: derived by
+    ``sequence.step`` carries ``device_picked``, the live slots whose
+    token was the device's. ``sequence.step`` and ``sequence.prefill``
+    say what the dispatcher chose for their attention: ``attend``
+    (``"pallas"`` or ``"reference"``, the model's ``attend_impl()``),
+    ``pages_visited`` (the live pages of the step's live slots, or of
+    the chunk's query tiles of one page, on the kernel path, their
+    whole tables on the reference path: derived by
     ``ops.pallas_attention.paged_pages_visited`` from the kernels' own
     rule, not counted on the device) and ``pages_table`` (live slots,
     or query tiles, x table width).
@@ -881,6 +911,9 @@ class PagedSequenceScheduler(_SlotScheduler):
         self._stream_ids = itertools.count(0)
         #: prefill passes dispatched (the interleave record)
         self.prefill_chunks = 0
+        #: the decode step whose rows are still on their way: (logits
+        #: on the device, [(slot, request, row index)])
+        self._unlanded = None
         self._open(start_thread)
 
     # -- submit ---------------------------------------------------------
@@ -971,7 +1004,7 @@ class PagedSequenceScheduler(_SlotScheduler):
         from its final-position logits. Returns True if that already
         finishes the request (max_new == 1)."""
         row = np.asarray(last_logits, np.float32)
-        req.logits_rows.append(row)
+        req.put_row(0, row)
         req.out_tokens.append(int(req.sampler(row, req.rng)))
         req.first_token_at = self.clock()
         req.token_times.append(req.first_token_at)
@@ -1061,12 +1094,36 @@ class PagedSequenceScheduler(_SlotScheduler):
         return (np.zeros((S,), np.int32), np.zeros((S,), np.int32),
                 np.zeros((S, self._mp), np.int32))
 
+    def _land(self, parent=None):
+        """Bring the rows of the decode step that still owes them (if
+        any) to the host and write them into their requests' blocks; a
+        request that failed or expired since gets none. `parent` is the
+        id of the iteration's span."""
+        if self._unlanded is None:
+            return
+        logits, slots = self._unlanded
+        self._unlanded = None
+        live = [(i, req, k) for i, req, k in slots if not req.done]
+        if not live:
+            return
+        t0 = self.clock()
+        out = np.asarray(logits)
+        for i, req, k in live:
+            req.put_row(k, out[i])
+        self._registry.add_span(
+            "sequence.land", "serving", t0, self.clock() - t0,
+            parent=parent, rows=len(live),
+            bytes=len(live) * out[0].nbytes)
+
     def _decode_batch(self, batch, parent=None):
         """One slot-batched decode step over every fully-prefilled
         slot: per-slot page prep (CoW fork / fresh page at a page
         boundary — a pool-exhausted slot fails alone), padded gather,
-        ONE dispatch, scatter + sample. `parent` is the id of the
-        iteration's span."""
+        ONE dispatch, the step before's rows landed behind it, scatter
+        + sample. The step waits for its ids alone where every live
+        slot takes the device's pick and none ends here; its rows then
+        land behind the next dispatch (class docstring). `parent` is
+        the id of the iteration's span."""
         reg = self._registry
         t_prep = self.clock()
         ready = []
@@ -1100,6 +1157,11 @@ class PagedSequenceScheduler(_SlotScheduler):
         tok[n:] = 0
         sls[n:] = 0
         bts[n:] = 0
+        picked = sum(req.device_pick for req in ready)
+        # a host sampler reads its row now, and a request that ends now
+        # hands all its rows to its waiter
+        rows_now = picked < n or any(
+            len(req.out_tokens) + 1 >= req.max_new for req in ready)
         t0c = self.clock()
         reg.add_span("sequence.decode_prep", "serving", t_prep,
                      t0c - t_prep, parent=parent, slots=n)
@@ -1110,15 +1172,25 @@ class PagedSequenceScheduler(_SlotScheduler):
         self.occupancy.append((n, S))
         try:
             tok = _chaos_fault_point("sequence.step", tok)
-            out, kps, vps = self.model._jit_decode(
+            (ids, logits), kps, vps = self.model._jit_decode(
                 self.model._params, tok, self.cache.k_pools,
                 self.cache.v_pools, bts, sls)
             self.cache.k_pools, self.cache.v_pools = kps, vps
+            # rows queued behind the ids still hold them up (a quarter
+            # of a millisecond on the v5e): a step that goes on without
+            # its rows leaves their copy to the next step's `_land`
+            ids.copy_to_host_async()
+            if rows_now:
+                logits.copy_to_host_async()
+            self._land(parent)          # while the device runs this step
             t_f = self.clock()
-            out = np.asarray(out)       # waits out the step, then copies
+            ids = np.asarray(ids)       # waits out the step
+            waited = ids.nbytes
+            if rows_now:
+                rows = np.asarray(logits)
+                waited += rows.nbytes
             reg.add_span("sequence.fetch", "serving", t_f,
-                         self.clock() - t_f, parent=step_id,
-                         bytes=out.nbytes)
+                         self.clock() - t_f, parent=step_id, bytes=waited)
         except Exception as e:
             self._fail_active(ready, e)
             return 0
@@ -1127,17 +1199,24 @@ class PagedSequenceScheduler(_SlotScheduler):
             reg.add_span(
                 "sequence.step", "serving", t0c, t_s - t0c,
                 parent=parent, span_id=step_id, model=self.name,
-                slots=n, bucket=S, attend=self._attend,
+                slots=n, bucket=S, device_picked=picked,
+                attend=self._attend,
                 pages_visited=self._pages_visited(sls[:n] + 1),
                 pages_table=n * self._mp)
+        live = [(i, req, len(req.out_tokens)) for i, req in enumerate(ready)
+                if not req.done]        # expired between gather + now
+        self._unlanded = (logits, live)
+        if rows_now:
+            self._land(parent)
+            t_s = self.clock()
         finished = []
-        for i, req in enumerate(ready):
-            if req.done:                # expired between gather + now
-                continue
+        for i, req, _ in live:
             req.seq_len += 1
-            row = out[i].astype(np.float32, copy=False)
-            req.logits_rows.append(row)
-            req.out_tokens.append(int(req.sampler(row, req.rng)))
+            if req.device_pick:
+                token = ids[i]
+            else:
+                token = req.sampler(rows[i], req.rng)
+            req.out_tokens.append(int(token))
             req.token_times.append(self.clock())
             if len(req.out_tokens) >= req.max_new:
                 finished.append(req)
@@ -1182,6 +1261,8 @@ class PagedSequenceScheduler(_SlotScheduler):
         decode = [r for r in batch
                   if not r.done and r.prefilled >= r.tokens.shape[0]]
         slots = self._decode_batch(decode, it_id) if decode else 0
+        if not slots:
+            self._land(it_id)   # no dispatch went out ahead of these rows
         reg.add_span("sequence.iteration", "serving", t_it,
                      self.clock() - t_it, span_id=it_id,
                      active=len(batch), pending=pending,
@@ -1225,5 +1306,6 @@ class PagedSequenceScheduler(_SlotScheduler):
         ServingClosedError and frees their pages. Then the pool's
         prefix registry and series go."""
         super().close(drain)
+        self._unlanded = None
         self.cache.close()
         return self

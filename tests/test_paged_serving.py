@@ -30,6 +30,11 @@ What must hold (the ISSUE 19 serving acceptance):
   compiles;
 - sampling: deterministic per (sampler_seed, stream), streams assigned
   in submit order;
+- the device's pick (ISSUE 35): a request whose sampler marks itself the
+  greedy pick steps on the argmax ``_decode_paged`` returns, any other
+  callable on its float32 row on the host, and either way the tokens
+  and the logits are the serial oracle's, every row there when
+  ``wait()`` returns;
 - the HTTP tier: ``:generate`` accepts ``{"tokens": ...}`` and maps
   KVCacheFullError to 429.
 """
@@ -48,9 +53,10 @@ from deeplearning4j_tpu.nn.transformer import (
     prefill_plan,
 )
 from deeplearning4j_tpu.runtime import aot
+from deeplearning4j_tpu.runtime import telemetry
 from deeplearning4j_tpu.serving import (
-    KVCacheFullError, ManualClock, ModelHost, PagedSequenceScheduler,
-    greedy_sampler,
+    DeadlineExceededError, KVCacheFullError, ManualClock, ModelHost,
+    PagedSequenceScheduler, greedy_sampler,
     stream_rng, temperature_sampler,
 )
 
@@ -280,6 +286,201 @@ class TestPrefillPlan:
             m, p, 3, greedy_sampler(), stream_rng(0, 1), bucket=4)
         assert req.wait(1.0).tolist() == toks
         np.testing.assert_allclose(req.logits, logits, rtol=0, atol=1e-5)
+        s.close()
+
+
+# ----------------------------------------------------------------------
+# ISSUE 35: the greedy token picked inside the decode step
+# ----------------------------------------------------------------------
+
+def _argmax_plus_one(row, rng):
+    """What a user's own function may do: unmarked, so called on the
+    host with the row (the tier-1 twin of perfbench/tests/
+    test_faults.py::test_altered_token_is_not_correct)."""
+    return (int(np.argmax(row)) + 1) % row.shape[0]
+
+
+def _steps(ring):
+    return [sp["args"] for sp in ring.spans()
+            if sp["name"] == "sequence.step"]
+
+
+def _bitwise(req, toks, logits):
+    assert req.wait(1.0).tolist() == toks
+    assert req.logits.dtype == np.float32
+    assert np.array_equal(req.logits.view(np.uint8), logits.view(np.uint8))
+
+
+class TestDevicePick:
+    @pytest.fixture
+    def ring(self):
+        trace = telemetry.get_registry().trace
+        trace.clear()
+        yield trace
+        trace.clear()
+
+    def test_samplers_say_whether_they_are_the_greedy_pick(self):
+        assert greedy_sampler().picks_argmax is True
+        assert temperature_sampler(0).picks_argmax is True
+        assert temperature_sampler(0, top_k=3).picks_argmax is True
+        for fn in (temperature_sampler(0.8), temperature_sampler(1e-9),
+                   _argmax_plus_one,
+                   lambda row, rng: greedy_sampler()(row, rng)):
+            assert not getattr(fn, "picks_argmax", False)
+
+    def test_decode_entry_returns_three_with_ids_and_logits_first(self):
+        """`perfbench/tests/test_faults.py` wraps `_jit_decode` and
+        unpacks three: the ids ride with the logits in the first."""
+        m = _lm()
+        s, _ = _sched(m)
+        tok, sls, bts = s._new_staging(4)
+        bts[0, 0], tok[0] = s.cache.alloc(1)[0], 5
+        got = m._jit_decode(m._params, tok, s.cache.k_pools,
+                            s.cache.v_pools, bts, sls)
+        assert len(got) == 3
+        (ids, logits), s.cache.k_pools, s.cache.v_pools = got
+        assert ids.shape == (4,) and ids.dtype == np.int32
+        assert logits.shape == (4, m.vocab) and logits.dtype == np.float32
+        assert np.array_equal(np.asarray(ids),
+                              np.argmax(np.asarray(logits), axis=-1))
+        s.close()
+
+    def test_greedy_by_the_device_is_bitwise_the_serial_oracle(self, ring):
+        """Four greedy requests that end in different iterations (1, 2,
+        5 and 7 new tokens): every decode slot took the device's id,
+        and tokens and logits are the dense oracle's bit for bit."""
+        m = _lm()
+        s, _ = _sched(m, prefix_sharing=False)
+        prompts = _prompts((5, 11, 3, 16), m.vocab)
+        news = (1, 2, 5, 7)
+        reqs = [s.submit(p, max_new_tokens=n, wait=False)
+                for p, n in zip(prompts, news)]
+        assert all(r.device_pick for r in reqs)
+        s.drain()
+        steps = _steps(ring)
+        assert steps and all(a["device_picked"] == a["slots"]
+                             for a in steps)
+        assert sum(a["slots"] for a in steps) == sum(n - 1 for n in news)
+        for i, (p, n) in enumerate(zip(prompts, news)):
+            _bitwise(reqs[i], *dense_serial_trajectory(
+                m, p, n, greedy_sampler(), stream_rng(0, i), bucket=4))
+        assert s._unlanded is None and s.cache.pages_in_use == 0
+        s.close()
+
+    def test_mixed_batch_gives_each_request_what_it_gets_alone(self, ring):
+        """A greedy and a temperature request in one batch: each has
+        the oracle's tokens and logits for its own (seed, stream), the
+        draw from numpy's stream on the host, and `device_picked`
+        counts the greedy slot only."""
+        m = _lm()
+        hot = temperature_sampler(0.8)
+        s, _ = _sched(m, sampler_seed=42, prefix_sharing=False)
+        prompts = _prompts((6, 9, 4), m.vocab, seed=5)
+        samplers = (None, hot, temperature_sampler(0))
+        reqs = [s.submit(p, max_new_tokens=6, sampler=smp, wait=False)
+                for p, smp in zip(prompts, samplers)]
+        assert [r.device_pick for r in reqs] == [True, False, True]
+        s.drain()
+        steps = _steps(ring)
+        # the three are prefilled in turn and end in turn, so steps of
+        # one, two and three live slots; the second request's slot is
+        # never the device's
+        assert [(a["slots"], a["device_picked"]) for a in steps] == \
+            [(1, 1), (2, 1), (3, 2), (3, 2), (3, 2), (2, 1), (1, 1)]
+        for i, (p, smp) in enumerate(zip(prompts, samplers)):
+            _bitwise(reqs[i], *dense_serial_trajectory(
+                m, p, 6, smp or greedy_sampler(), stream_rng(42, i),
+                bucket=4))
+        s.close()
+
+    def test_an_unmarked_callable_is_obeyed(self, ring):
+        m = _lm()
+        s, _ = _sched(m, sampler=_argmax_plus_one)
+        p = _prompts((7,), m.vocab)[0]
+        req = s.submit(p, max_new_tokens=5, wait=False)
+        s.drain()
+        assert all(a["device_picked"] == 0 for a in _steps(ring))
+        _bitwise(req, *dense_serial_trajectory(
+            m, p, 5, _argmax_plus_one, stream_rng(0, 0), bucket=4))
+        greedy, _ = dense_serial_trajectory(
+            m, p, 5, greedy_sampler(), stream_rng(0, 0), bucket=4)
+        assert req.result.tolist() != greedy
+        assert req.result[0] == (np.argmax(req.logits[0]) + 1) % m.vocab
+        s.close()
+
+    @pytest.mark.parametrize("n_new", [1, 2, 9])
+    def test_every_row_is_there_the_instant_wait_returns(self, n_new):
+        """The scheduler's own thread serves; the caller reads `logits`
+        straight after `wait()`: one row a token, none still on its
+        way."""
+        m = _lm()
+        s = PagedSequenceScheduler(m, num_pages=48, slot_buckets=(4,))
+        try:
+            prompts = _prompts((5, 12), m.vocab, seed=n_new)
+            reqs = [s.submit(p, max_new_tokens=n_new, wait=False)
+                    for p in prompts]
+            for i, (req, p) in enumerate(zip(reqs, prompts)):
+                toks = req.wait(30.0)
+                assert req.done and len(req.out_tokens) == n_new
+                assert req.logits.shape == (n_new, m.vocab)
+                _bitwise(req, *dense_serial_trajectory(
+                    m, p, n_new, greedy_sampler(), stream_rng(0, i),
+                    bucket=4))
+                assert toks.tolist() == req.out_tokens
+        finally:
+            s.close()
+
+    def test_expired_mid_generation_leaves_no_landing_pending(self):
+        """A greedy request's deadline passes between two decode steps,
+        with the rows of its last step still on their way: its error is
+        raised, its pages go back, and nothing is left to land."""
+        clk = ManualClock()
+        m = _lm()
+        s, _ = _sched(m, clock=clk, prefix_sharing=False)
+        late = s.submit(_prompts((5,), m.vocab)[0], max_new_tokens=8,
+                        deadline=1.0, wait=False)
+        stays = s.submit(_prompts((6,), m.vocab, seed=2)[0],
+                         max_new_tokens=8, wait=False)
+        for _ in range(3):
+            s.poll()
+        assert len(late.out_tokens) == 4 and s._unlanded is not None
+        clk.advance(2.0)
+        s.poll()
+        with pytest.raises(DeadlineExceededError):
+            late.wait(0.1)
+        assert late.pages == [] and late.logits is None
+        assert len(late.out_tokens) == 4
+        s.drain()
+        assert s._unlanded is None
+        _bitwise(stays, *dense_serial_trajectory(
+            m, _prompts((6,), m.vocab, seed=2)[0], 8, greedy_sampler(),
+            stream_rng(0, 1), bucket=4))
+        assert s.cache.pages_in_use == 0
+        s.close()
+
+    def test_failed_mid_generation_leaves_no_landing_pending(self):
+        """The pool runs dry between two steps for one of two greedy
+        slots: the victim has its typed error and no logits, its pages
+        are back, the other ends with the oracle's rows, and no rows
+        wait for anyone."""
+        m = _lm()
+        s, _ = _sched(m, num_pages=5, prefix_sharing=False,
+                      slot_buckets=(2,))      # capacity 4: two each
+        prompts = _prompts((4, 4), m.vocab)
+        reqs = [s.submit(p, max_new_tokens=14, wait=False)
+                for p in prompts]
+        s.drain()
+        (victim,) = [r for r in reqs if r.error is not None]
+        with pytest.raises(KVCacheFullError):
+            victim.wait(0.1)
+        assert victim.pages == [] and victim.logits is None
+        assert 1 < len(victim.out_tokens) < 14
+        (ok,) = [r for r in reqs if r.error is None]
+        i = reqs.index(ok)
+        _bitwise(ok, *dense_serial_trajectory(
+            m, prompts[i], 14, greedy_sampler(), stream_rng(0, i),
+            bucket=2))
+        assert s._unlanded is None and s.cache.pages_in_use == 0
         s.close()
 
 

@@ -196,7 +196,10 @@ class TestPagedSchedulerSpans:
     def test_one_prompt_two_passes_four_tokens(self, ring):
         """44 prompt tokens at a page of 8 are six pages, which the plan
         takes in two passes of three; the second iteration finishes the
-        prompt and decodes, two more decode."""
+        prompt and decodes, two more decode. The request is greedy, so
+        a step waits for its ids and its rows land behind the next
+        dispatch; the step that ends the request waits for its rows
+        too and lands them itself."""
         s = _paged(_lm())
         assert prefill_plan(44, 0, 8, s._mp) == [(0, 24, 24), (24, 20, 24)]
         req = s.submit(_prompt(44), max_new_tokens=4, wait=False)
@@ -219,26 +222,42 @@ class TestPagedSchedulerSpans:
         tree = [[k["name"] for k in sorted(kids[i["id"]],
                                            key=lambda k: k["ts"])]
                 for i in its]
-        decode = ["sequence.decode_prep", "sequence.step",
-                  "sequence.sample"]
+        step = ["sequence.decode_prep", "sequence.step"]
+        land, sample = ["sequence.land"], ["sequence.sample"]
         assert tree == [
             ["sequence.admit", "sequence.prefill"],
             ["sequence.admit", "sequence.prefill",
-             "sequence.prefill_finish"] + decode,
-            ["sequence.admit"] + decode,
-            ["sequence.admit"] + decode]
+             "sequence.prefill_finish"] + step + sample,
+            ["sequence.admit"] + step + land + sample,
+            ["sequence.admit"] + step + land + land + sample]
         for i in its:
             assert all(_inside(k, i) for k in kids[i["id"]])
-        for step in by["sequence.step"]:
+        ids, row = 2 * 4, 23 * 4    # a bucket's int32 ids; a float32 row
+        steps = by["sequence.step"]
+        for step, waited in zip(steps, (ids, ids, ids + 2 * row)):
             (fetch,) = kids[step["id"]]
             assert fetch["name"] == "sequence.fetch" and _inside(fetch, step)
-            assert fetch["args"]["bytes"] > 0
-            # the accepted readers' args, as before, and what the
-            # attention read: the CPU takes paged_attend, whole tables
+            assert fetch["args"] == {"bytes": waited}
+            # the accepted readers' args, as before, whose token the
+            # slots took, and what the attention read: the CPU takes
+            # paged_attend, whole tables
             assert step["args"] == {
                 "model": s.name, "slots": 1, "bucket": 2,
-                "attend": "reference", "pages_visited": s._mp,
-                "pages_table": s._mp}
+                "device_picked": 1, "attend": "reference",
+                "pages_visited": s._mp, "pages_table": s._mp}
+        # a landing is the iteration's child: the step before's lies
+        # behind this step's dispatch and ahead of its fetch, the
+        # ending step's own after its fetch
+        lands = by["sequence.land"]
+        assert [x["args"] for x in lands] == [{"rows": 1, "bytes": row}] * 3
+        assert [x["parent"] for x in lands] == \
+            [its[2]["id"], its[3]["id"], its[3]["id"]]
+        for x, step in zip(lands[:2], steps[1:]):
+            (fetch,) = kids[step["id"]]
+            assert step["ts"] < x["ts"] and \
+                x["ts"] + x["dur"] <= fetch["ts"]
+        assert lands[2]["ts"] >= steps[2]["ts"] + steps[2]["dur"]
+        assert req.logits.shape == (4, 23) and s._unlanded is None
         # a pass: its tokens, the chunk it ran in, and a whole table
         # for each of the chunk's query tiles of one page
         assert [p["args"] for p in by["sequence.prefill"]] == [
@@ -425,7 +444,7 @@ class TestReadersTakeTheProgramsSpans:
         trace.clear()
 
     def test_there_is_a_reader_for_each_layer_metric(self):
-        assert len(SCHEDULER_READERS) == 17
+        assert len(SCHEDULER_READERS) == 18
 
     @pytest.mark.parametrize("name", SCHEDULER_READERS)
     def test_reader_gives_a_finite_number(self, name, run):
