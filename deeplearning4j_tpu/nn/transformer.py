@@ -116,7 +116,8 @@ class CausalTransformerLM:
     dtype is the compute/storage dtype (params, KV pools, residual
     stream); logits always come back fp32, and the decode step hands
     back their argmax beside them, so a greedy request's next token
-    is 4 bytes to fetch and its row can follow later.
+    is 4 bytes to fetch and its row can follow later, and can take its
+    tokens from an earlier step's ids still on the device.
     """
 
     #: duck-type marker the serving host dispatches on
@@ -236,10 +237,15 @@ class CausalTransformerLM:
                        preferred_element_type=jnp.float32)
 
     # -- paged step functions (pure; jitted via runtime/aot) -------------
-    def _decode_paged(self, params, tokens, kps, vps, bts, sls):
+    def _decode_paged(self, params, tokens, kps, vps, bts, sls, prev_ids,
+                      src):
         """One decode token per slot. tokens [S] i32 (last sampled),
         kps/vps [L, P, page, H, Dh] pools, bts [S, MP] block tables,
         sls [S] live KV length per slot (the new token's position).
+        prev_ids [S] i32 are the ids an earlier step returned and src
+        [S] i32 says where each slot's token comes from: ``prev_ids[j]``
+        for src j >= 0, ``tokens`` for -1, so the scheduler can queue a
+        step on the ids of one whose ids have not reached the host yet.
         Returns ((ids [S] i32, logits [S, V] fp32), kps', vps'): ids is
         the argmax of each logits row, the first of equal maxima as
         ``np.argmax`` takes it, so the scheduler can step a greedy slot
@@ -249,6 +255,7 @@ class CausalTransformerLM:
         never attended by a live slot — and their ids and logits rows
         are ignored by the scheduler's scatter."""
         S = tokens.shape[0]
+        tokens = jnp.where(src >= 0, prev_ids[jnp.maximum(src, 0)], tokens)
         h = params["emb"][tokens] + params["pos"][sls]
         pages = bts[jnp.arange(S), sls // self.page_size]
         offs = sls % self.page_size

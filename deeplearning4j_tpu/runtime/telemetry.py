@@ -379,6 +379,11 @@ _SPAN_KEYS = ("id", "parent", "rid", "name", "cat", "ts", "dur", "ph",
               "pid", "tid", "args")
 
 
+#: spans the ring keeps: a busy paged scheduler leaves about 7.5 spans an
+#: iteration, so a 40 s window at 100 iterations a second is 30 000
+TRACE_CAPACITY = 65536
+
+
 class TraceBuffer:
     """Bounded ring of structured spans. A span reads as one dict:
     {id, parent (the id of the span that caused it, or None), rid (the
@@ -390,7 +395,7 @@ class TraceBuffer:
     the last clear(): a reader that finds it above 0 is looking at the
     newest part of its window only."""
 
-    def __init__(self, capacity=32768):
+    def __init__(self, capacity=TRACE_CAPACITY):
         self.capacity = int(capacity)
         self._lock = threading.Lock()
         self._spans = collections.deque(maxlen=self.capacity)
@@ -435,7 +440,7 @@ class MetricsRegistry:
     spans with explicit timestamps via add_span.
     """
 
-    def __init__(self, clock=None, trace_capacity=32768):
+    def __init__(self, clock=None, trace_capacity=TRACE_CAPACITY):
         self.clock = clock if clock is not None else time.perf_counter
         self._lock = threading.RLock()
         self._instruments = {}

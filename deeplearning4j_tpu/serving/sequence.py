@@ -65,7 +65,7 @@ from __future__ import annotations
 
 import itertools
 import threading
-from collections import deque
+from collections import deque, namedtuple
 
 import numpy as np
 
@@ -98,6 +98,10 @@ DEFAULT_SLOT_BUCKETS = (1, 2, 4, 8)
 #: each prompt chunk dispatch, so a ChaosPlan can fail/wedge/corrupt a
 #: prefill exactly where production would (runtime/chaos.py)
 PREFILL_SEAM = register_seam("sequence.prefill")
+
+#: a decode step dispatched and not yet collected: its ids and logits
+#: on the device, its requests in slot order, its bucket
+_Step = namedtuple("_Step", "ids logits reqs S")
 
 #: the registry families both scheduler classes record into (and
 #: release per-instance series from at close()), one row a family:
@@ -251,7 +255,7 @@ class _SlotScheduler:
         self._step_lock = threading.Lock()
         self._pending = deque()
         self._active = []                   # the slot table
-        self._staging = {}                  # S -> (reused buffers, bytes)
+        self._staging = {}          # (S, half) -> (reused buffers, bytes)
         #: host bytes served from the staging pool instead of fresh
         #: np.zeros (the bench decode leg's alloc-reduction record)
         self.staging_reuse_bytes = 0
@@ -372,14 +376,18 @@ class _SlotScheduler:
                 return b
         return self.slot_buckets[-1]
 
-    def _staging_for(self, S):
+    def _staging_for(self, S, half=0):
         """Per-bucket staging buffers (``_new_staging`` of the subclass
-        shapes them), allocated once and reused every iteration: the
-        dispatch copies them to the device, so host-side reuse can
-        never alias a live step. A fresh np.zeros per array per step
-        was pure allocator churn; the bench decode leg counts what the
-        pool saves as staging_reuse_bytes."""
-        hit = self._staging.get(S)
+        shapes them), allocated once and reused: a fresh np.zeros per
+        array per step was pure allocator churn; the bench decode leg
+        counts what the pool saves as staging_reuse_bytes. A dispatch
+        may read its numpy arguments in place instead of copying them
+        (the CPU backend does), so a set is refilled only once the step
+        that read it has delivered its outputs: the carry scheduler
+        waits for each step before it builds the next, and the paged
+        one, which keeps a step queued ahead of the host, alternates
+        two sets a bucket (`half` 0 or 1)."""
+        hit = self._staging.get((S, half))
         if hit is not None:
             st, nbytes = hit
             self.staging_reuse_bytes += nbytes
@@ -387,7 +395,7 @@ class _SlotScheduler:
         import jax
 
         st = self._new_staging(S)
-        self._staging[S] = (st, sum(
+        self._staging[(S, half)] = (st, sum(
             a.nbytes for a in jax.tree_util.tree_leaves(st)))
         return st
 
@@ -761,10 +769,10 @@ class GenerationRequest(_SlotRequest):
     then appends one token per decode iteration until ``max_new``
     tokens have been sampled. ``pages``/``block_row``/``seq_len`` are
     the slot's KV state (owned page ids, logical-block -> physical-page
-    row, live KV rows). The result is the sampled token ids [max_new]
+    row, KV rows written by every step dispatched so far, a queued one's
+    included). The result is the sampled token ids [max_new]
     (int64); ``logits`` is the float32 row each was sampled from,
-    [max_new, V], whole once the request is done (the rows of a
-    request still generating arrive a step behind its tokens).
+    [max_new, V], whole once the request is done.
     ``device_pick`` says the request's sampler marks itself the greedy
     pick (``sampling.greedy_sampler``), so its decode tokens are the
     step's own argmax.
@@ -846,36 +854,49 @@ class PagedSequenceScheduler(_SlotScheduler):
     A request's sampler decides where its decode tokens are picked. One
     that marks itself the greedy pick (``sampling.greedy_sampler``, the
     default) takes the argmax ``_decode_paged`` returns beside the
-    logits: a step whose live slots are all such waits for their ids (4
-    bytes a slot) and its rows are copied to the host and into the
-    requests' blocks behind the next step's dispatch, while the device
-    runs it (``_land``). Any
-    other callable is called on the host, ``sampler(logits_row, rng) ->
-    token`` with a per-request ``stream_rng(sampler_seed, stream_id)``
-    stream, stream ids assigned in submit order — deterministic per
-    (seed, stream), so the bitwise-vs-serial gate holds with temperature
-    sampling too; a step with such a slot, or one that ends a request,
-    waits for its rows as well. A prompt's first token is sampled on
-    the host from the prefill's row either way. Every row is kept: a
-    finished request's ``logits`` holds one for each token.
+    logits. While every live slot is such, one decode step is kept
+    queued ahead of the host: an iteration dispatches step n+1 on step
+    n's ids while they are still on the device (``_decode_paged``'s
+    ``prev_ids`` and ``src``), then waits for step n's ids and rows,
+    appends the tokens, writes the rows into the requests' blocks and
+    ends the requests whose last token that was, while the device runs
+    step n+1. A request's end is known a step ahead from ``max_new``,
+    so one whose last token is step n's is left out of step n+1; one
+    that expires or fails while a step is queued for it has that step's
+    row dropped. A prompt that completes while a step is queued joins
+    the step after it: its first token is fetched behind that step's
+    dispatch. Where no step is queued (the first decode of a batch),
+    an iteration dispatches step n on the host's tokens and step n+1
+    ahead of it, so every ``poll()`` still yields one token a decoding
+    slot. Any other callable is called on the host,
+    ``sampler(logits_row, rng) -> token`` with a per-request
+    ``stream_rng(sampler_seed, stream_id)`` stream, stream ids assigned
+    in submit order — deterministic per (seed, stream), so the
+    bitwise-vs-serial gate holds with temperature sampling too; a step
+    with such a slot is dispatched, waited for and
+    sampled in one iteration, with nothing queued behind it. A prompt's
+    first token is sampled on the host from the prefill's row either
+    way. Every row is kept: a finished request's ``logits`` holds one
+    for each token, whole in the ``poll()`` of its last token.
 
     Spans (cat ``serving``, on this scheduler's clock; the tree is in
     docs/OBSERVABILITY.md): every iteration that found work is one
     ``sequence.iteration`` whose children are ``sequence.admit``,
     ``sequence.prefill`` and ``sequence.prefill_finish`` (rid = the
-    request's ``stream_id``), ``sequence.decode_prep``,
-    ``sequence.step`` (child ``sequence.fetch``: the wait for what the
-    step needs now, the ids or the ids and the rows, ``bytes`` says
-    which), ``sequence.land`` (a decode step's ``rows`` written into
-    their requests' blocks: the step before's behind this one's
-    dispatch, inside ``sequence.step``'s interval, or this one's own
-    after its fetch) and ``sequence.sample``; a request that ends, done
-    or failed, leaves one instant ``sequence.request`` with its whole
-    timeline.
+    request's ``stream_id``), ``sequence.decode_prep`` and
+    ``sequence.step`` for each decode dispatch, then
+    ``sequence.fetch`` (the wait for the ids and rows of the step the
+    iteration collects, the one dispatched before; ``bytes``),
+    ``sequence.land`` (its ``rows`` written into their requests'
+    blocks) and ``sequence.sample`` (tokens appended, requests ended);
+    a request that ends, done or failed, leaves one instant
+    ``sequence.request`` with its whole timeline.
     ``sequence.prefill`` carries the pass: ``chunk`` prompt tokens in a
     chunk of ``bucket`` tokens (the executable's length).
     ``sequence.step`` carries ``device_picked``, the live slots whose
-    token was the device's. ``sequence.step`` and ``sequence.prefill``
+    token was the device's, and ``ahead``: 1 where the step took tokens
+    from the previous step's ids on the device, dispatched before those
+    ids reached the host. ``sequence.step`` and ``sequence.prefill``
     say what the dispatcher chose for their attention: ``attend``
     (``"pallas"`` or ``"reference"``, the model's ``attend_impl()``),
     ``pages_visited`` (the live pages of the step's live slots, or of
@@ -911,9 +932,10 @@ class PagedSequenceScheduler(_SlotScheduler):
         self._stream_ids = itertools.count(0)
         #: prefill passes dispatched (the interleave record)
         self.prefill_chunks = 0
-        #: the decode step whose rows are still on their way: (logits
-        #: on the device, [(slot, request, row index)])
-        self._unlanded = None
+        #: the decode step dispatched ahead and not yet collected
+        self._ahead = None
+        self._half = 0                      # the staging set last filled
+        self._zero_ids = {}                 # S -> device zeros [S] int32
         self._open(start_thread)
 
     # -- submit ---------------------------------------------------------
@@ -983,7 +1005,11 @@ class PagedSequenceScheduler(_SlotScheduler):
         one instant ``sequence.request`` before its waiter is released.
         An instant and not a span as long as the request: such a span
         would cover every device-idle gap of a busy scheduler and take,
-        in an idle-gap attribution, the time that belongs to no span."""
+        in an idle-gap attribution, the time that belongs to no span.
+        The pages go back at once even while a queued step still writes
+        the request's row into one of them: whoever gets a page next
+        writes it from a step dispatched later, on the same device
+        stream, so after that row."""
         if req.pages:
             self.cache.release(req.pages)
             req.pages = []
@@ -1024,11 +1050,10 @@ class PagedSequenceScheduler(_SlotScheduler):
         """Dispatch ONE prefill pass for one slot, the next of the
         prompt's ``prefill_plan``: allocate the pages its tokens fill,
         append their K/V, attend causally over the table so far.
-        Completing the prompt registers it for prefix sharing and
-        samples the first token. Returns True on progress; a
-        pool-exhausted or chaos-injected failure fails THIS request
-        only (typed, 429 at the HTTP tier). `parent` is the id of the
-        iteration's span."""
+        Returns the pass's logits (on the device) where it completes the
+        prompt, for ``_prefill_finish``, else None; a pool-exhausted or
+        chaos-injected failure fails THIS request only (typed, 429 at
+        the HTTP tier). `parent` is the id of the iteration's span."""
         import jax.numpy as jnp
 
         page = self.model.page_size
@@ -1055,7 +1080,7 @@ class PagedSequenceScheduler(_SlotScheduler):
             self.cache.k_pools, self.cache.v_pools = kps, vps
         except Exception as e:
             self._fail_active([req], e)
-            return True                     # progress: the slot freed
+            return None
         finally:
             t1c = self.clock()
             # what each query tile of one page sees: its own page and
@@ -1071,16 +1096,20 @@ class PagedSequenceScheduler(_SlotScheduler):
         req.prefilled += n_valid
         req.seq_len = req.prefilled
         self.prefill_chunks += 1
-        if req.prefilled >= T:
-            # the fetch waits out the chunk on the device
-            last = np.asarray(logits)
-            if self.prefix_sharing:
-                self.cache.register_prefix(req.tokens, req.pages, last)
-            self._complete_prompt(req, last)
-            self._registry.add_span(
-                "sequence.prefill_finish", "serving", t1c,
-                self.clock() - t1c, parent=parent, rid=req.stream_id)
-        return True
+        return logits if req.prefilled >= T else None
+
+    def _prefill_finish(self, req, logits, parent=None):
+        """The prompt is whole in KV: fetch its last row (which waits
+        out the pass on the device), register the prompt for prefix
+        sharing and sample the first token."""
+        t0 = self.clock()
+        last = np.asarray(logits)
+        if self.prefix_sharing:
+            self.cache.register_prefix(req.tokens, req.pages, last)
+        self._complete_prompt(req, last)
+        self._registry.add_span(
+            "sequence.prefill_finish", "serving", t0, self.clock() - t0,
+            parent=parent, rid=req.stream_id)
 
     def _pages_visited(self, lengths):
         """Pages one step's attention reads for live slots of KV
@@ -1090,44 +1119,46 @@ class PagedSequenceScheduler(_SlotScheduler):
 
     def _new_staging(self, S):
         """Decode staging of one bucket: tokens, seq lens, block
-        tables."""
+        tables, and where each slot's token comes from (``src``)."""
         return (np.zeros((S,), np.int32), np.zeros((S,), np.int32),
-                np.zeros((S, self._mp), np.int32))
+                np.zeros((S, self._mp), np.int32),
+                np.full((S,), -1, np.int32))
 
-    def _land(self, parent=None):
-        """Bring the rows of the decode step that still owes them (if
-        any) to the host and write them into their requests' blocks; a
-        request that failed or expired since gets none. `parent` is the
-        id of the iteration's span."""
-        if self._unlanded is None:
-            return
-        logits, slots = self._unlanded
-        self._unlanded = None
-        live = [(i, req, k) for i, req, k in slots if not req.done]
-        if not live:
-            return
-        t0 = self.clock()
-        out = np.asarray(logits)
-        for i, req, k in live:
-            req.put_row(k, out[i])
-        self._registry.add_span(
-            "sequence.land", "serving", t0, self.clock() - t0,
-            parent=parent, rows=len(live),
-            bytes=len(live) * out[0].nbytes)
+    def _no_ids(self, S):
+        """``prev_ids`` of a step whose tokens all come from the host:
+        zeros on the device, one array a bucket, so that such a step
+        has the signature of one queued on another step's ids."""
+        ids = self._zero_ids.get(S)
+        if ids is None:
+            import jax.numpy as jnp
 
-    def _decode_batch(self, batch, parent=None):
-        """One slot-batched decode step over every fully-prefilled
-        slot: per-slot page prep (CoW fork / fresh page at a page
-        boundary — a pool-exhausted slot fails alone), padded gather,
-        ONE dispatch, the step before's rows landed behind it, scatter
-        + sample. The step waits for its ids alone where every live
-        slot takes the device's pick and none ends here; its rows then
-        land behind the next dispatch (class docstring). `parent` is
-        the id of the iteration's span."""
+            ids = self._zero_ids[S] = jnp.zeros((S,), jnp.int32)
+        return ids
+
+    def _members(self, batch, queued):
+        """The slots of the next decode step, in `batch` order: every
+        request whose first token is sampled and that still owes a
+        token once `queued` (a step not yet collected, or None) has
+        given it its own. No row is computed that nobody wants."""
+        owed = set() if queued is None else set(queued.reqs)
+        return [r for r in batch
+                if not r.done and r.out_tokens
+                and len(r.out_tokens) + (r in owed) < r.max_new]
+
+    def _dispatch(self, members, queued, parent):
+        """Dispatch one slot-batched decode step over `members` and
+        return it (``_Step``), or None where none went out. Each slot's
+        page is prepared first (a fresh page at a page boundary, the
+        copy-on-write fork of a shared one; a pool-exhausted slot fails
+        alone), and its ``seq_len`` counts the row the step writes. A
+        slot of `queued` takes its token from that step's ids on the
+        device, any other its last sampled token from the host; a step
+        queued on another's ids runs at that step's bucket, the shape
+        of the ids. `parent` is the id of the iteration's span."""
         reg = self._registry
         t_prep = self.clock()
         ready = []
-        for req in batch:
+        for req in members:
             try:
                 idx = req.seq_len // self.model.page_size
                 if req.seq_len % self.model.page_size == 0 \
@@ -1146,26 +1177,27 @@ class PagedSequenceScheduler(_SlotScheduler):
             except Exception as e:
                 self._fail_active([req], e)
         if not ready:
-            return 0
-        S = self.bucket_for(len(ready))
-        tok, sls, bts = self._staging_for(S)
+            return None
         n = len(ready)
+        S = self.bucket_for(n) if queued is None else queued.S
+        self._half ^= 1
+        tok, sls, bts, src = self._staging_for(S, self._half)
+        at = {} if queued is None else \
+            {r: i for i, r in enumerate(queued.reqs)}
         for i, req in enumerate(ready):
-            tok[i] = req.out_tokens[-1]
+            src[i] = at.get(req, -1)
+            tok[i] = req.out_tokens[-1] if src[i] < 0 else 0
             sls[i] = req.seq_len
             bts[i] = req.block_row
         tok[n:] = 0
         sls[n:] = 0
         bts[n:] = 0
+        src[n:] = -1
+        prev = self._no_ids(S) if queued is None else queued.ids
         picked = sum(req.device_pick for req in ready)
-        # a host sampler reads its row now, and a request that ends now
-        # hands all its rows to its waiter
-        rows_now = picked < n or any(
-            len(req.out_tokens) + 1 >= req.max_new for req in ready)
         t0c = self.clock()
         reg.add_span("sequence.decode_prep", "serving", t_prep,
                      t0c - t_prep, parent=parent, slots=n)
-        step_id = reg.new_span_id()
         self._m["dispatches"].inc()
         self._m["slot_steps"].inc(n)
         self._m["occupancy"].observe(n / S)
@@ -1174,44 +1206,56 @@ class PagedSequenceScheduler(_SlotScheduler):
             tok = _chaos_fault_point("sequence.step", tok)
             (ids, logits), kps, vps = self.model._jit_decode(
                 self.model._params, tok, self.cache.k_pools,
-                self.cache.v_pools, bts, sls)
+                self.cache.v_pools, bts, sls, prev, src)
             self.cache.k_pools, self.cache.v_pools = kps, vps
-            # rows queued behind the ids still hold them up (a quarter
-            # of a millisecond on the v5e): a step that goes on without
-            # its rows leaves their copy to the next step's `_land`
+            # both copies start as the step ends: the rows land while
+            # the step after runs
             ids.copy_to_host_async()
-            if rows_now:
-                logits.copy_to_host_async()
-            self._land(parent)          # while the device runs this step
-            t_f = self.clock()
-            ids = np.asarray(ids)       # waits out the step
-            waited = ids.nbytes
-            if rows_now:
-                rows = np.asarray(logits)
-                waited += rows.nbytes
-            reg.add_span("sequence.fetch", "serving", t_f,
-                         self.clock() - t_f, parent=step_id, bytes=waited)
+            logits.copy_to_host_async()
         except Exception as e:
             self._fail_active(ready, e)
-            return 0
+            return None
         finally:
-            t_s = self.clock()
             reg.add_span(
-                "sequence.step", "serving", t0c, t_s - t0c,
-                parent=parent, span_id=step_id, model=self.name,
-                slots=n, bucket=S, device_picked=picked,
+                "sequence.step", "serving", t0c, self.clock() - t0c,
+                parent=parent, model=self.name, slots=n, bucket=S,
+                device_picked=picked, ahead=int(np.any(src[:n] >= 0)),
                 attend=self._attend,
                 pages_visited=self._pages_visited(sls[:n] + 1),
                 pages_table=n * self._mp)
-        live = [(i, req, len(req.out_tokens)) for i, req in enumerate(ready)
-                if not req.done]        # expired between gather + now
-        self._unlanded = (logits, live)
-        if rows_now:
-            self._land(parent)
-            t_s = self.clock()
-        finished = []
-        for i, req, _ in live:
+        for req in ready:
             req.seq_len += 1
+        return _Step(ids, logits, ready, S)
+
+    def _collect(self, step, parent):
+        """Wait for a dispatched step's ids and rows, write each live
+        slot's row into its request's block, append its token (the
+        device's pick, or its sampler's draw on the row) and end the
+        requests whose last token it was; a request that expired or
+        failed since the dispatch gets nothing. Returns the step's
+        slots. `parent` is the id of the iteration's span."""
+        reg = self._registry
+        t_f = self.clock()
+        try:
+            ids = np.asarray(step.ids)          # waits out the step
+            rows = np.asarray(step.logits)
+        except Exception as e:
+            self._fail_active([r for r in step.reqs if not r.done], e)
+            return len(step.reqs)
+        t_l = self.clock()
+        reg.add_span("sequence.fetch", "serving", t_f, t_l - t_f,
+                     parent=parent, bytes=ids.nbytes + rows.nbytes)
+        live = [(i, req) for i, req in enumerate(step.reqs)
+                if not req.done]
+        for i, req in live:
+            req.put_row(len(req.out_tokens), rows[i])
+        t_s = self.clock()
+        if live:
+            reg.add_span("sequence.land", "serving", t_l, t_s - t_l,
+                         parent=parent, rows=len(live),
+                         bytes=len(live) * rows[0].nbytes)
+        finished = []
+        for i, req in live:
             if req.device_pick:
                 token = ids[i]
             else:
@@ -1223,13 +1267,45 @@ class PagedSequenceScheduler(_SlotScheduler):
         for req in finished:
             self._finish_req(req)
         reg.add_span("sequence.sample", "serving", t_s,
-                     self.clock() - t_s, parent=parent, slots=n,
-                     finished=len(finished))
-        return n
+                     self.clock() - t_s, parent=parent,
+                     slots=len(step.reqs), finished=len(finished))
+        return len(step.reqs)
+
+    def _decode(self, batch, parent):
+        """The iteration's decode: the step queued by the iteration
+        before, or one dispatched now on the host's tokens, is
+        collected; before that, where every slot of the step after
+        takes the device's pick and it fits the same bucket, the step
+        after is queued on the collected step's ids (class docstring).
+        Returns the collected step's slots (0: none)."""
+        step, self._ahead = self._ahead, None
+        if step is None:
+            members = self._members(batch, None)
+            step = self._dispatch(members, None, parent) if members \
+                else None
+            if step is None:
+                return 0
+        nxt = self._members(batch, step)
+        if nxt and all(r.device_pick for r in nxt) \
+                and self.bucket_for(len(nxt)) == step.S:
+            ahead = self._dispatch(nxt, step, parent)
+        else:
+            ahead = None
+        slots = self._collect(step, parent)
+        self._ahead = ahead
+        return slots
+
+    def _settle(self):
+        """Wait out a queued step whose requests have all ended since
+        (a drain's last poll, ``close``), before the pools it writes
+        can be let go."""
+        step, self._ahead = self._ahead, None
+        if step is not None:
+            step.logits.block_until_ready()
 
     def _iterate_locked(self):
         """One iteration: expire -> refill (prefix adoption) -> at most
-        ONE prefill pass -> one slot-batched decode step. Returns the
+        ONE prefill pass -> the decode (``_decode``). Returns the
         progress count (0 = idle). An iteration that found anything to
         do is one ``sequence.iteration`` span, its parts the children
         (class docstring)."""
@@ -1248,6 +1324,7 @@ class PagedSequenceScheduler(_SlotScheduler):
             batch = list(self._active)
             pending = len(self._pending)
         if not (batch or admitted or expired):
+            self._settle()
             return progress               # an empty poll: no span
         reg.add_span("sequence.admit", "serving", t_it,
                      self.clock() - t_it, parent=it_id,
@@ -1255,14 +1332,18 @@ class PagedSequenceScheduler(_SlotScheduler):
         pre = next((r for r in batch
                     if not r.done and r.prefilled < r.tokens.shape[0]),
                    None)
+        last = None
         if pre is not None:
-            self._prefill_one(pre, it_id)
+            last = self._prefill_one(pre, it_id)
             progress += 1
-        decode = [r for r in batch
-                  if not r.done and r.prefilled >= r.tokens.shape[0]]
-        slots = self._decode_batch(decode, it_id) if decode else 0
-        if not slots:
-            self._land(it_id)   # no dispatch went out ahead of these rows
+            if last is not None and self._ahead is None:
+                # no step queued: the slot joins this iteration's
+                self._prefill_finish(pre, last, it_id)
+                last = None
+        slots = self._decode(batch, it_id)
+        if last is not None:
+            # behind the step queued ahead: the slot joins the next
+            self._prefill_finish(pre, last, it_id)
         reg.add_span("sequence.iteration", "serving", t_it,
                      self.clock() - t_it, span_id=it_id,
                      active=len(batch), pending=pending,
@@ -1278,15 +1359,17 @@ class PagedSequenceScheduler(_SlotScheduler):
         generates its first token hot. Returns {bucket: {...},
         "prefill": {...} (one page), "prefill<n>": {...} (n pages)} for
         fresh compiles. Signatures mirror the live dispatch EXACTLY
-        (host-numpy staging arrays + the live pool handles)."""
+        (host-numpy staging arrays, the live pool handles and the ids
+        on the device)."""
         import jax.numpy as jnp
 
         report = {}
         for S in self.slot_buckets:
-            tok, sls, bts = self._new_staging(S)
+            tok, sls, bts, src = self._new_staging(S)
             _note_warm(report, int(S), self.model._jit_decode.warm(
                 self.model._params, tok, self.cache.k_pools,
-                self.cache.v_pools, bts, sls, cache=cache))
+                self.cache.v_pools, bts, sls, self._no_ids(S), src,
+                cache=cache))
         bt = np.zeros((self._mp,), np.int32)
         for n in PREFILL_CHUNK_PAGES:
             if n > self._mp:
@@ -1303,9 +1386,10 @@ class PagedSequenceScheduler(_SlotScheduler):
     def close(self, drain=True):
         """Stop accepting. drain=True serves everything queued or
         mid-flight to completion; drain=False fails them with
-        ServingClosedError and frees their pages. Then the pool's
-        prefix registry and series go."""
+        ServingClosedError and frees their pages. A step still queued
+        is waited out; then the pool's prefix registry and series
+        go."""
         super().close(drain)
-        self._unlanded = None
+        self._settle()
         self.cache.close()
         return self

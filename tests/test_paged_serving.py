@@ -333,10 +333,10 @@ class TestDevicePick:
         unpacks three: the ids ride with the logits in the first."""
         m = _lm()
         s, _ = _sched(m)
-        tok, sls, bts = s._new_staging(4)
+        tok, sls, bts, src = s._new_staging(4)
         bts[0, 0], tok[0] = s.cache.alloc(1)[0], 5
         got = m._jit_decode(m._params, tok, s.cache.k_pools,
-                            s.cache.v_pools, bts, sls)
+                            s.cache.v_pools, bts, sls, s._no_ids(4), src)
         assert len(got) == 3
         (ids, logits), s.cache.k_pools, s.cache.v_pools = got
         assert ids.shape == (4,) and ids.dtype == np.int32
@@ -360,11 +360,16 @@ class TestDevicePick:
         steps = _steps(ring)
         assert steps and all(a["device_picked"] == a["slots"]
                              for a in steps)
+        # no row nobody wants: a slot-step for each decoded token
         assert sum(a["slots"] for a in steps) == sum(n - 1 for n in news)
+        # the two-token request's one step, and the first of the batch
+        # that follows, are on the host's tokens; every later one is
+        # queued on the ids of the step before
+        assert [a["ahead"] for a in steps] == [0, 0] + [1] * (len(steps) - 2)
         for i, (p, n) in enumerate(zip(prompts, news)):
             _bitwise(reqs[i], *dense_serial_trajectory(
                 m, p, n, greedy_sampler(), stream_rng(0, i), bucket=4))
-        assert s._unlanded is None and s.cache.pages_in_use == 0
+        assert s._ahead is None and s.cache.pages_in_use == 0
         s.close()
 
     def test_mixed_batch_gives_each_request_what_it_gets_alone(self, ring):
@@ -382,11 +387,15 @@ class TestDevicePick:
         assert [r.device_pick for r in reqs] == [True, False, True]
         s.drain()
         steps = _steps(ring)
-        # the three are prefilled in turn and end in turn, so steps of
-        # one, two and three live slots; the second request's slot is
-        # never the device's
-        assert [(a["slots"], a["device_picked"]) for a in steps] == \
-            [(1, 1), (2, 1), (3, 2), (3, 2), (3, 2), (2, 1), (1, 1)]
+        # the three are prefilled in turn: the first decodes alone, its
+        # steps queued ahead, until the second's first token; from then
+        # on every step holds the temperature slot, so none is queued
+        # ahead and all three end together at the last. The second
+        # request's slot is never the device's
+        assert [(a["slots"], a["device_picked"], a["ahead"])
+                for a in steps] == [
+            (1, 1, 0), (1, 1, 1), (1, 1, 1), (3, 2, 0), (3, 2, 0),
+            (2, 1, 0), (2, 1, 0), (2, 1, 0)]
         for i, (p, smp) in enumerate(zip(prompts, samplers)):
             _bitwise(reqs[i], *dense_serial_trajectory(
                 m, p, 6, smp or greedy_sampler(), stream_rng(42, i),
@@ -443,7 +452,7 @@ class TestDevicePick:
                          max_new_tokens=8, wait=False)
         for _ in range(3):
             s.poll()
-        assert len(late.out_tokens) == 4 and s._unlanded is not None
+        assert len(late.out_tokens) == 4 and late in s._ahead.reqs
         clk.advance(2.0)
         s.poll()
         with pytest.raises(DeadlineExceededError):
@@ -451,7 +460,7 @@ class TestDevicePick:
         assert late.pages == [] and late.logits is None
         assert len(late.out_tokens) == 4
         s.drain()
-        assert s._unlanded is None
+        assert s._ahead is None
         _bitwise(stays, *dense_serial_trajectory(
             m, _prompts((6,), m.vocab, seed=2)[0], 8, greedy_sampler(),
             stream_rng(0, 1), bucket=4))
@@ -480,7 +489,185 @@ class TestDevicePick:
         _bitwise(ok, *dense_serial_trajectory(
             m, prompts[i], 14, greedy_sampler(), stream_rng(0, i),
             bucket=2))
-        assert s._unlanded is None and s.cache.pages_in_use == 0
+        assert s._ahead is None and s.cache.pages_in_use == 0
+        s.close()
+
+
+# ----------------------------------------------------------------------
+# one greedy decode step queued ahead of the host
+# ----------------------------------------------------------------------
+
+#: scenario -> (prompt lengths, max_new each, submitted at poll k, a
+#: deadline on one request, sampler of each (None: the greedy default))
+AHEAD_SCENARIOS = {
+    "steady": ((5, 11, 3, 16), (6, 6, 6, 6), (0, 0, 0, 0), None, None),
+    "ends_mid_batch": ((5, 11, 3, 16), (3, 9, 5, 7), (0, 0, 0, 0), None,
+                       None),
+    "joins_running_batch": ((5, 11, 3, 16), (9, 9, 6, 5), (0, 0, 4, 6),
+                            None, None),
+    "deadline_while_queued": ((5, 11, 3, 16), (9, 9, 9, 9), (0, 0, 0, 0),
+                              1, None),
+    "one_temperature_slot": ((5, 11, 3, 16), (6, 6, 6, 6), (0, 0, 0, 0),
+                             None, (None, 0.8, None, None)),
+}
+
+
+class Recorded:
+    """`_jit_decode` that keeps the numpy operands of its last call and
+    a copy of them, and at each call records whether the last call's
+    still equal their copy; `warm` and the rest pass through."""
+
+    def __init__(self, real):
+        self._real, self.last, self.held = real, None, []
+
+    def __call__(self, *args):
+        if self.last is not None:
+            self.held.append(all(np.array_equal(a, b)
+                                 for a, b in zip(*self.last)))
+        arrays = [a for a in args if isinstance(a, np.ndarray)]
+        self.last = (arrays, [a.copy() for a in arrays])
+        return self._real(*args)
+
+    def __getattr__(self, name):
+        return getattr(self._real, name)
+
+
+class TestDispatchAhead:
+    @pytest.fixture
+    def ring(self):
+        trace = telemetry.get_registry().trace
+        trace.clear()
+        yield trace
+        trace.clear()
+
+    @pytest.mark.parametrize("scenario", sorted(AHEAD_SCENARIOS))
+    def test_tokens_and_rows_are_the_serial_oracles(self, scenario, ring):
+        """Greedy requests stepped one step ahead of the host have the
+        dense oracle's tokens and logits bit for bit: in a steady batch,
+        with requests ending mid-batch, with prompts joining a running
+        batch, with a deadline passing while a step is queued for its
+        request, and beside a temperature slot, whose steps are waited
+        for in their own iteration. After every poll a request is done
+        exactly when it has its last token, its rows whole."""
+        lens, news, at, doomed, temps = AHEAD_SCENARIOS[scenario]
+        clk = ManualClock()
+        m = _lm()
+        s, _ = _sched(m, clock=clk, prefix_sharing=False, sampler_seed=7)
+        prompts = _prompts(lens, m.vocab)
+        samplers = [None if t is None else temperature_sampler(t)
+                    for t in (temps or (None,) * len(lens))]
+        reqs = [None] * len(lens)
+        polls = 0
+        while polls == 0 or s.active_slots or s.depth or \
+                None in reqs or s._ahead is not None:
+            for i, k in enumerate(at):
+                if k == polls and reqs[i] is None:
+                    reqs[i] = s.submit(
+                        prompts[i], max_new_tokens=news[i],
+                        sampler=samplers[i], wait=False,
+                        deadline=5.0 if i == doomed else None)
+            if doomed is not None and polls == 6:
+                late = reqs[doomed]
+                assert not late.done and late in s._ahead.reqs
+                clk.advance(10.0)
+            s.poll()
+            polls += 1
+            for r in filter(None, reqs):
+                assert r.done == (r.error is not None
+                                  or len(r.out_tokens) == r.max_new)
+                if r.done and r.error is None:
+                    assert r.logits.shape == (r.max_new, m.vocab)
+            assert polls < 60
+        steps = _steps(ring)
+        assert any(a["ahead"] for a in steps)
+        for a in steps:         # a host sampler's step is never ahead
+            assert a["ahead"] == 0 or a["device_picked"] == a["slots"]
+        for i, r in enumerate(reqs):
+            if i == doomed:
+                with pytest.raises(DeadlineExceededError):
+                    r.wait(0.0)
+                assert r.pages == [] and len(r.out_tokens) < r.max_new
+                continue
+            _bitwise(r, *dense_serial_trajectory(
+                m, prompts[i], news[i], samplers[i] or greedy_sampler(),
+                stream_rng(7, i), bucket=4))
+        assert s.cache.pages_in_use == 0
+        s.close()
+
+    def test_a_failed_step_ahead_fails_its_own_requests_only(self, ring):
+        """The fifth dispatch is queued on the fourth's ids for the
+        second request alone, the first ending at the fourth: it raises
+        at the step seam. The second request fails with it, the first
+        ends with the oracle's tokens and rows, and nothing is left
+        queued or allotted."""
+        from deeplearning4j_tpu.runtime.chaos import ChaosError, ChaosPlan
+
+        m = _lm()
+        s, _ = _sched(m, prefix_sharing=False)
+        prompts = _prompts((5, 6), m.vocab)
+        with ChaosPlan().raise_n("sequence.step", at=4) as plan:
+            a = s.submit(prompts[0], max_new_tokens=5, wait=False)
+            b = s.submit(prompts[1], max_new_tokens=9, wait=False)
+            s.drain()
+        assert plan.fired("sequence.step") == 1
+        fifth = _steps(ring)[4]
+        assert (fifth["slots"], fifth["ahead"]) == (1, 1)
+        with pytest.raises(ChaosError):
+            b.wait(0.0)
+        _bitwise(a, *dense_serial_trajectory(
+            m, prompts[0], 5, greedy_sampler(), stream_rng(0, 0),
+            bucket=4))
+        assert s.stats["errors"] == 1 and s.stats["completed"] == 1
+        assert s._ahead is None and s.cache.pages_in_use == 0
+        s.close()
+
+    @pytest.mark.parametrize("drain", [True, False])
+    def test_close_leaves_no_step_queued(self, drain):
+        m = _lm()
+        s, _ = _sched(m, prefix_sharing=False)
+        reqs = [s.submit(p, max_new_tokens=12, wait=False)
+                for p in _prompts((5, 7), m.vocab)]
+        for _ in range(4):
+            s.poll()
+        assert s._ahead is not None and s.cache.pages_in_use > 0
+        s.close(drain=drain)
+        assert s._ahead is None and s.cache.pages_in_use == 0
+        assert all(r.done for r in reqs)
+        assert all((r.error is None) == drain for r in reqs)
+
+    def test_warm_primes_the_live_dispatch(self, fresh_cache):
+        """The executable table has what `warm()` put there and nothing
+        more once steps on the host's tokens and steps queued on the
+        device's ids have both run."""
+        m = _lm()
+        s, _ = _sched(m, slot_buckets=(2, 4))
+        s.warm()
+        decode, prefill = dict(m._jit_decode._table), \
+            dict(m._jit_prefill._table)
+        assert len(decode) == 2
+        reqs = [s.submit(p, max_new_tokens=6, wait=False)
+                for p in _prompts((3, 9, 17, 6, 40), m.vocab)]
+        s.drain()
+        assert all(r.error is None for r in reqs)
+        assert s.stats["dispatches"] > len(reqs)
+        assert m._jit_decode._table == decode
+        assert m._jit_prefill._table == prefill
+        s.close()
+
+    def test_refilled_staging_leaves_the_queued_steps_inputs(self, ring):
+        """Each dispatch finds the host arrays the step before it was
+        handed as they were at that dispatch: the staging of a step
+        queued ahead is not the staging refilled for the next."""
+        m = _lm()
+        s, _ = _sched(m, prefix_sharing=False)
+        m._jit_decode = rec = Recorded(m._jit_decode)
+        reqs = [s.submit(p, max_new_tokens=8, wait=False)
+                for p in _prompts((5, 11, 3), m.vocab)]
+        s.drain()
+        assert all(r.error is None for r in reqs)
+        assert sum(a["ahead"] for a in _steps(ring)) >= 3
+        assert len(rec.held) == s.stats["dispatches"] - 1
+        assert all(rec.held)
         s.close()
 
 

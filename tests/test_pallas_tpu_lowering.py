@@ -199,7 +199,8 @@ def test_the_benchmarks_step_functions_compile_with_the_kernels(
     if step == "decode":
         fn, donate, args = model._decode_paged, (2, 3), (
             model._params, sd(p["S"], dtype=i32), pool, pool,
-            sd(p["S"], p["MP"], dtype=i32), sd(p["S"], dtype=i32))
+            sd(p["S"], p["MP"], dtype=i32), sd(p["S"], dtype=i32),
+            sd(p["S"], dtype=i32), sd(p["S"], dtype=i32))
     else:
         pages = int(step.split("-")[1])
         fn, donate, args = model._prefill_paged, (4, 5), (

@@ -1,7 +1,7 @@
 """The per-layer readers that ISSUE 26 adds under perfbench/metrics/ (and
 ISSUE 27's `paged_attend.pages_visited_share`, ISSUE 31's
 `seq.prefill_tokens_per_pass_mean` and its decode-cell twin, ISSUE 35's
-`seq.device_sampled_share`), fed
+`seq.device_sampled_share`, and `seq.dispatch_ahead_share`), fed
 hand-made spans: each returns the number worked out by hand below, None
 where the ring dropped spans (a truncated window gives no number) and
 None, without raising, where the program left nothing to read (the
@@ -39,6 +39,7 @@ EXPECTED = {
     "seq.prefill_tokens_per_pass_mean": 200.0,
     "seq.prefill_tokens_per_pass_mean.decode": 200.0,
     "seq.device_sampled_share": 100.0 * 43 / 46,
+    "seq.dispatch_ahead_share": 100.0 * 2 / 3,
 }
 SETUP = ("setup.weights_init_s", "setup.warm_s")
 
@@ -79,9 +80,10 @@ def _request(reg, rid, enq, chunk, tokens, error=None):
               token_times=tuple(tokens), error=error)
 
 
-def _pages(visited, table, picked=16, slots=16):
+def _pages(visited, table, picked=16, slots=16, ahead=1):
     return {"attend": "pallas", "pages_visited": visited,
-            "pages_table": table, "device_picked": picked, "slots": slots}
+            "pages_table": table, "device_picked": picked, "slots": slots,
+            "ahead": ahead}
 
 
 def _fill(reg):
@@ -91,8 +93,9 @@ def _fill(reg):
     # 5, 7, 9 ms -> median 7; one of three carries a chunk; pages 300,
     # 319, 310 -> 319. Their steps read 40 of 96, 45 of 96 and 44 of 112
     # pages -> 129 of 304, and took the device's token in 16 of 16, 15 of
-    # 16 and 12 of 14 live slots -> 43 of 46. One iteration before the
-    # window counts nowhere.
+    # 16 and 12 of 14 live slots -> 43 of 46; the first two were queued
+    # on the ids of the step before and the third was not -> 2 of 3. One
+    # iteration before the window counts nowhere.
     # Two prefill passes in the window: 300 tokens in a chunk of 512,
     # and 100 in a span without `bucket` (the parent commit's) -> 200.
     _iteration(reg, 5.0, 1.0, 999, [
@@ -111,7 +114,7 @@ def _fill(reg):
         ("sequence.sample", 7 * ms)])
     _iteration(reg, 10.4, 80 * ms, 310, [
         ("sequence.admit", 1 * ms), ("sequence.decode_prep", 1 * ms),
-        ("sequence.step", 66 * ms, _pages(44, 112, 12, 14)),
+        ("sequence.step", 66 * ms, _pages(44, 112, 12, 14, 0)),
         ("sequence.sample", 9 * ms)])
     reg.add_span("sequence.prefill", "serving", 10.6, 4 * ms, chunk=100)
     # two requests count: time to first token 100 and 250 ms (median 175,
@@ -173,7 +176,8 @@ def test_reader_gives_none_where_nothing_was_recorded(name):
 
 
 @pytest.mark.parametrize("name", ["paged_attend.pages_visited_share",
-                                  "seq.device_sampled_share"])
+                                  "seq.device_sampled_share",
+                                  "seq.dispatch_ahead_share"])
 def test_step_reader_is_none_where_a_step_lacks_the_args(name, filled):
     """The parent commit's `sequence.step` carries slots and bucket
     only: one such step in the window and the reader gives no number."""

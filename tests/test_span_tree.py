@@ -165,7 +165,7 @@ class TestSpanRecord:
             ["s6", "s7", "s8", "s9"]
         reg.trace.clear()
         assert reg.trace.dropped == 0 and reg.trace.spans() == []
-        assert MetricsRegistry().trace.capacity == 32768
+        assert MetricsRegistry().trace.capacity == 65536
 
     def test_chrome_trace_carries_the_links(self):
         reg = MetricsRegistry()
@@ -197,9 +197,12 @@ class TestPagedSchedulerSpans:
         """44 prompt tokens at a page of 8 are six pages, which the plan
         takes in two passes of three; the second iteration finishes the
         prompt and decodes, two more decode. The request is greedy, so
-        a step waits for its ids and its rows land behind the next
-        dispatch; the step that ends the request waits for its rows
-        too and lands them itself."""
+        the second iteration dispatches a step on the host's token and
+        the next one ahead of it on the device's id before it collects
+        the first; the third queues the fourth token's step before it
+        collects the third's, and the last collects without queueing:
+        its request ends there. Every collect waits for ids and rows
+        and lands the rows itself."""
         s = _paged(_lm())
         assert prefill_plan(44, 0, 8, s._mp) == [(0, 24, 24), (24, 20, 24)]
         req = s.submit(_prompt(44), max_new_tokens=4, wait=False)
@@ -223,41 +226,41 @@ class TestPagedSchedulerSpans:
                                            key=lambda k: k["ts"])]
                 for i in its]
         step = ["sequence.decode_prep", "sequence.step"]
-        land, sample = ["sequence.land"], ["sequence.sample"]
+        collect = ["sequence.fetch", "sequence.land", "sequence.sample"]
         assert tree == [
             ["sequence.admit", "sequence.prefill"],
             ["sequence.admit", "sequence.prefill",
-             "sequence.prefill_finish"] + step + sample,
-            ["sequence.admit"] + step + land + sample,
-            ["sequence.admit"] + step + land + land + sample]
+             "sequence.prefill_finish"] + step + step + collect,
+            ["sequence.admit"] + step + collect,
+            ["sequence.admit"] + collect]
         for i in its:
             assert all(_inside(k, i) for k in kids[i["id"]])
         ids, row = 2 * 4, 23 * 4    # a bucket's int32 ids; a float32 row
         steps = by["sequence.step"]
-        for step, waited in zip(steps, (ids, ids, ids + 2 * row)):
-            (fetch,) = kids[step["id"]]
-            assert fetch["name"] == "sequence.fetch" and _inside(fetch, step)
-            assert fetch["args"] == {"bytes": waited}
+        for step, ahead in zip(steps, (0, 1, 1)):
+            assert "sequence.step" not in {k["name"] for k in
+                                           kids.get(step["id"], ())}
             # the accepted readers' args, as before, whose token the
-            # slots took, and what the attention read: the CPU takes
+            # slots took, whether it was the step before's id on the
+            # device, and what the attention read: the CPU takes
             # paged_attend, whole tables
             assert step["args"] == {
                 "model": s.name, "slots": 1, "bucket": 2,
-                "device_picked": 1, "attend": "reference",
+                "device_picked": 1, "ahead": ahead, "attend": "reference",
                 "pages_visited": s._mp, "pages_table": s._mp}
-        # a landing is the iteration's child: the step before's lies
-        # behind this step's dispatch and ahead of its fetch, the
-        # ending step's own after its fetch
+        # a collect waits for a bucket's ids and rows, behind the
+        # dispatch of the step queued after it where there is one
+        fetches = by["sequence.fetch"]
+        assert [f["args"] for f in fetches] == [{"bytes": ids + 2 * row}] * 3
+        assert [f["parent"] for f in fetches] == [i["id"] for i in its[1:]]
+        for f, step in zip(fetches, steps[1:]):
+            assert f["ts"] >= step["ts"] + step["dur"]
         lands = by["sequence.land"]
         assert [x["args"] for x in lands] == [{"rows": 1, "bytes": row}] * 3
-        assert [x["parent"] for x in lands] == \
-            [its[2]["id"], its[3]["id"], its[3]["id"]]
-        for x, step in zip(lands[:2], steps[1:]):
-            (fetch,) = kids[step["id"]]
-            assert step["ts"] < x["ts"] and \
-                x["ts"] + x["dur"] <= fetch["ts"]
-        assert lands[2]["ts"] >= steps[2]["ts"] + steps[2]["dur"]
-        assert req.logits.shape == (4, 23) and s._unlanded is None
+        assert [x["parent"] for x in lands] == [i["id"] for i in its[1:]]
+        for x, f in zip(lands, fetches):
+            assert x["ts"] >= f["ts"] + f["dur"]
+        assert req.logits.shape == (4, 23) and s._ahead is None
         # a pass: its tokens, the chunk it ran in, and a whole table
         # for each of the chunk's query tiles of one page
         assert [p["args"] for p in by["sequence.prefill"]] == [
@@ -444,7 +447,7 @@ class TestReadersTakeTheProgramsSpans:
         trace.clear()
 
     def test_there_is_a_reader_for_each_layer_metric(self):
-        assert len(SCHEDULER_READERS) == 18
+        assert len(SCHEDULER_READERS) == 19
 
     @pytest.mark.parametrize("name", SCHEDULER_READERS)
     def test_reader_gives_a_finite_number(self, name, run):
