@@ -24,14 +24,21 @@ folded queries ``[.., R]`` and returns the latent outputs ``[.., rank]``.
 
 * ``latent_flash_decode``: the pallas block-table kernel, one query row
   per head per slot. Its grid walks a slot's pages in groups of
-  ``PAGES_PER_BLOCK`` (one pool operand per page of a group, each with
-  its own index map), so a program moves a couple of megabytes; past
-  the slot's last live page the index maps stand still and nothing is
-  fetched. The carry (acc, m, l) lives in VMEM across the page axis.
+  ``PAGES_PER_BLOCK`` (one pool operand per page of a group), and the
+  group is the unit of the online softmax: a program joins its pages
+  into one [D, group x page] block, scores all of its keys in one
+  product, masks those at or past the slot's length, takes one max, one
+  exp, one sum and one rescale of the float32 carry (acc, m, l, in VMEM
+  across the group axis), and adds the group's output in one product.
+  The operands read a table made before the call: a slot's page while
+  it holds live rows, and past the slot's last live page the page the
+  operand held in the group before, so that it stands still and nothing
+  is fetched; a group past the last live row does nothing.
 * ``latent_attend``: its twin on the pool, online softmax over the live
   pages of the longest slot, any number of query rows per slot, each
-  with its own position (causal mask): page by page (the kernel's
-  order) for decode off the TPU, ``PAGES_PER_BLOCK`` pages a step for a
+  with its own position (causal mask), a group of pages a step: the
+  kernel's group for decode off the TPU (its order, so the two agree
+  bit for bit in interpret mode), ``PAGES_PER_BLOCK`` pages for a
   prefill chunk on every backend. What the kernel is checked against.
 * ``latent_attention``: the step functions' one entry; the kernel where
   ``latent_kernel_fits`` admits the shapes on the TPU and the rows are
@@ -55,30 +62,32 @@ __all__ = ["PAGES_PER_BLOCK", "latent_attend", "latent_attention",
 
 _NEG_INF = -1e30
 
-#: pages one program of the decode kernel reads: a page of 128 latent
-#: rows of 576 bfloat16 is 147 KB, a fifth of a microsecond of HBM at
-#: the v5e's 819 GB/s against about a third of a microsecond a grid
-#: step costs. On the chip at the GLM cell's shapes 16 took 1.75 ms a
-#: layer and 8 took 1.80 (PERF.md, section 6): a page's two products with 20
-#: query rows, each loading the page into the MXU, take about a
-#: microsecond, more than its bytes
+#: pages one program of the decode kernel reads, and the unit of its
+#: online softmax: 16 pages of 128 latent rows of 576 bfloat16 are 2,048
+#: keys and 2.4 MB. On a v5e at the GLM cell's shapes (PERF.md, section
+#: 6) a layer's work without its fetches took 0.89 ms page by page (a
+#: max, exp, sum and carry rescale and two products, under a branch, for
+#: each page's 0.18 us of HBM) and 0.31 ms a group at a time; with them
+#: 1.23 and 0.66 ms, against 0.34 ms for the bytes. What is left is the
+#: fetch of sixteen operands a grid step and the step's own cost
 PAGES_PER_BLOCK = 16
 
-#: VMEM the decode kernel's blocks may take (double-buffered pages,
-#: query and output blocks, the float32 carry)
+#: VMEM the decode kernel's blocks may take (latent_kernel_fits counts
+#: them)
 _VMEM_BUDGET = 10 * 2 ** 20
 
 
 def latent_attend(q, pool, layer, block_tables, lengths, q_pos, rank,
-                  scale, pages_per_step=1):
+                  scale, pages_per_step):
     """Latent attention on the pool, every backend: q [S, R, D] folded
     queries (D = rank + rope width) at positions q_pos [S, R], pool
     [L, P, D, page], layer an int or a traced int, block_tables [S, MP],
     lengths [S] live rows per slot. Row r of slot s sees the rows t with
     t < lengths[s] and t <= q_pos[s, r]. Walks the pages up to the
     longest slot's last live one, `pages_per_step` pages a step of the
-    online softmax: one is the decode kernel's order, more pass over the
-    float32 carry fewer times (a prefill chunk's is [C x H, rank]).
+    online softmax: the decode kernel's group gives its order, and more
+    pass over the float32 carry fewer times (a prefill chunk's is
+    [C x H, rank]).
     Returns [S, R, rank] in q's dtype; a row that sees nothing is exact
     zeros."""
     S, R, _ = q.shape
@@ -118,16 +127,19 @@ def latent_attend(q, pool, layer, block_tables, lengths, q_pos, rank,
     return (acc / jnp.where(l == 0, 1.0, l)).astype(q.dtype)
 
 
-def _decode_kernel(lay_ref, bt_ref, sl_ref, q_ref, *refs, page, rank,
+def _decode_kernel(lay_ref, tbl_ref, sl_ref, q_ref, *refs, page, rank,
                    group, scale):
     """One (slot, group of pages) program: q_ref [H, D] the slot's folded
     queries, refs[:group] the group's pages [D, page], then the output
-    [H, rank] and the carry acc [H, rank], m, l [H, 1] in VMEM."""
+    [H, rank] and the carry acc [H, rank], m, l [H, 1] in VMEM. A group
+    holding a live row is one step of the online softmax over its group
+    x page keys; the others do nothing."""
     from jax.experimental import pallas as pl
 
     pages = refs[:group]
     o_ref, acc_ref, m_ref, l_ref = refs[group:]
     g = pl.program_id(1)
+    start = g * group * page
     length = sl_ref[pl.program_id(0)]
 
     @pl.when(g == 0)
@@ -136,31 +148,50 @@ def _decode_kernel(lay_ref, bt_ref, sl_ref, q_ref, *refs, page, rank,
         m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
 
-    for i in range(group):
-        start = (g * group + i) * page
-
-        @pl.when(start < length)
-        def _visit(i=i, start=start):
-            rows = pages[i][...]
-            s = jnp.dot(q_ref[...], rows,
-                        preferred_element_type=jnp.float32) * scale
-            k_pos = start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-            s = jnp.where(k_pos < length, s, _NEG_INF)
-            m, l, acc = m_ref[...], l_ref[...], acc_ref[...]
-            m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
-            corr = jnp.exp(m - m_new)
-            p = jnp.where(k_pos < length, jnp.exp(s - m_new), 0.0)
-            m_ref[...] = m_new
-            l_ref[...] = l * corr + jnp.sum(p, axis=1, keepdims=True)
-            acc_ref[...] = acc * corr + jax.lax.dot_general(
-                p.astype(rows.dtype), rows[:rank], (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32)
+    @pl.when(start < length)
+    def _visit():
+        # pages past the slot's last live one hold another live page of
+        # the slot (finite rows), masked here like the rows past length
+        rows = jnp.concatenate([p[...] for p in pages], axis=1)
+        s = jnp.dot(q_ref[...], rows,
+                    preferred_element_type=jnp.float32) * scale
+        k_pos = start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        s = jnp.where(k_pos < length, s, _NEG_INF)
+        m = m_ref[...]
+        m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
+        corr = jnp.exp(m - m_new)
+        p = jnp.where(k_pos < length, jnp.exp(s - m_new), 0.0)
+        m_ref[...] = m_new
+        l_ref[...] = l_ref[...] * corr + jnp.sum(p, axis=1, keepdims=True)
+        acc_ref[...] = acc_ref[...] * corr + jax.lax.dot_general(
+            p.astype(rows.dtype), rows[:rank], (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)
 
     @pl.when(g == pl.num_programs(1) - 1)
     def _finalize():
         l_f = l_ref[...]
         o_ref[...] = (acc_ref[...] / jnp.where(l_f == 0, 1.0, l_f)
                       ).astype(o_ref.dtype)
+
+
+def _decode_group(MP):
+    """Pages a program of the decode kernel takes for tables MP wide."""
+    return min(PAGES_PER_BLOCK, MP)
+
+
+def _operand_pages(block_tables, seq_lens, page, group):
+    """[S, n x group] (n groups cover the table): the pool page operand i
+    reads at grid step (s, g), at [s, g x group + i]. The slot's page
+    g x group + i while it is live; past the slot's last live page the
+    latest live page of the slot at a position i modulo group (the page
+    the operand held one group before: it stands still, nothing is
+    fetched), or the last live page where the slot has none there."""
+    n = -(-block_tables.shape[1] // group)
+    last = jnp.maximum((seq_lens + page - 1) // page - 1, 0)[:, None]
+    j = jnp.arange(n * group)[None]
+    pos = j - group * ((jnp.maximum(j - last, 0) + group - 1) // group)
+    pos = jnp.where(pos < 0, last, pos)
+    return jnp.take_along_axis(block_tables, pos, axis=1)
 
 
 @functools.partial(jax.jit, static_argnames=("rank", "scale", "interpret"))
@@ -173,21 +204,20 @@ def _latent_decode_attend(layer, block_tables, seq_lens, q, pool, *, rank,
 
     S, H, D = q.shape
     page = pool.shape[3]
-    MP = block_tables.shape[1]
-    group = min(PAGES_PER_BLOCK, MP)
+    group = _decode_group(block_tables.shape[1])
+    table = _operand_pages(block_tables, seq_lens, page, group)
     kernel = functools.partial(_decode_kernel, page=page, rank=rank,
                                group=group, scale=scale)
 
     def page_spec(i):
-        def index(s, g, lay, bt, sl):
-            last = jnp.maximum((sl[s] + page - 1) // page - 1, 0)
-            return (lay[0], bt[s, jnp.minimum(g * group + i, last)], 0, 0)
-        return pl.BlockSpec((None, None, D, page), index)
+        return pl.BlockSpec(
+            (None, None, D, page),
+            lambda s, g, lay, tbl, sl: (lay[0], tbl[s, g * group + i], 0, 0))
 
-    row = lambda s, g, lay, bt, sl: (s, 0, 0)
+    row = lambda s, g, lay, tbl, sl: (s, 0, 0)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
-        grid=(S, -(-MP // group)),
+        grid=(S, table.shape[1] // group),
         in_specs=[pl.BlockSpec((None, H, D), row)]
         + [page_spec(i) for i in range(group)],
         out_specs=pl.BlockSpec((None, H, rank), row),
@@ -197,8 +227,7 @@ def _latent_decode_attend(layer, block_tables, seq_lens, q, pool, *, rank,
     return pl.pallas_call(
         kernel, grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((S, H, rank), q.dtype),
-        interpret=interpret)(layer, block_tables, seq_lens, q,
-                             *([pool] * group))
+        interpret=interpret)(layer, table, seq_lens, q, *([pool] * group))
 
 
 def latent_flash_decode(q, pool, layer, block_tables, seq_lens, rank,
@@ -217,12 +246,14 @@ def latent_flash_decode(q, pool, layer, block_tables, seq_lens, rank,
 def latent_kernel_fits(page, H, D, rank, itemsize):
     """Shape rule for the decode kernel on the TPU: the page a multiple
     of the dtype's sublane tile, and the blocks (a group of pages
-    double-buffered, the query and output blocks, the carry) inside
-    _VMEM_BUDGET."""
+    double-buffered and joined, the query and output blocks, the carry,
+    the group's float32 scores and probabilities and the rounded
+    probabilities) inside _VMEM_BUDGET."""
     if page % (32 // itemsize):
         return False
-    need = (2 * PAGES_PER_BLOCK * page * D * itemsize
-            + 2 * H * (D + rank) * itemsize + H * (rank + 256) * 4)
+    keys = PAGES_PER_BLOCK * page
+    need = (3 * keys * D * itemsize + 2 * H * (D + rank) * itemsize
+            + H * (rank + 256) * 4 + H * keys * (8 + itemsize))
     return need <= _VMEM_BUDGET
 
 
@@ -240,10 +271,10 @@ def latent_attention(q, pool, layer, block_tables, lengths, q_pos, rank,
     """The step functions' latent attention: q [S, R, D] folded queries
     at positions q_pos [S, R]. One row per head at the slot's last
     position (decode: q_pos None) takes the kernel where
-    latent_attention_impl says so, else latent_attend page by page in
-    the kernel's order; rows at their own positions (a prefill chunk)
-    take latent_attend, PAGES_PER_BLOCK pages a step. Returns [S, R,
-    rank]."""
+    latent_attention_impl says so, else latent_attend a group of the
+    kernel's a step, in its order; rows at their own positions (a
+    prefill chunk) take latent_attend, PAGES_PER_BLOCK pages a step.
+    Returns [S, R, rank]."""
     D, page = pool.shape[2], pool.shape[3]
     if q_pos is None and latent_attention_impl(
             page, q.shape[1], D, rank, pool.dtype) == "pallas":
@@ -253,6 +284,7 @@ def latent_attention(q, pool, layer, block_tables, lengths, q_pos, rank,
         q_pos = jnp.broadcast_to((jnp.asarray(lengths) - 1)[:, None],
                                  q.shape[:2])
         return latent_attend(q, pool, layer, block_tables, lengths, q_pos,
-                             rank, scale)
+                             rank, scale,
+                             _decode_group(jnp.shape(block_tables)[1]))
     return latent_attend(q, pool, layer, block_tables, lengths, q_pos,
                          rank, scale, PAGES_PER_BLOCK)

@@ -197,31 +197,52 @@ class TestExperts:
 
 
 class TestLatentKernel:
-    @pytest.mark.parametrize("lengths", [(70, 190, 17), (70, 0, 17),
-                                         (1, 128, 129)])
+    @pytest.mark.parametrize("lengths", [
+        (70, 190, 17), (70, 0, 17), (1, 128, 129),
+        (256, 257, 17),     # a length on a group's edge, one row past it
+        (272, 513, 48),     # last groups holding a single live page
+        (640, 0, 300)])     # every entry of the table; a padded slot
     def test_kernel_is_its_twin_on_the_pool(self, lengths):
-        """Interpret mode: one to twelve live pages a slot, a padded slot
-        (exact zeros), a length on a group's edge."""
+        """Interpret mode, tables of 40 pages of 16 rows, three groups of
+        16 a slot: one to forty live pages, groups whose pages are live
+        in part, a padded slot (exact zeros); the twin takes the kernel's
+        group a step and agrees bit for bit."""
         rng = np.random.default_rng(0)
-        L, P, D, page, H, rank, MP = 2, 40, 48, 16, 4, 32, 12
+        L, P, D, page, H, rank, MP = 2, 121, 48, 16, 4, 32, 40
         pool = jnp.asarray(rng.standard_normal((L, P, D, page)),
                            jnp.bfloat16)
         q = jnp.asarray(rng.standard_normal((3, H, D)), jnp.bfloat16)
-        bts = np.zeros((3, MP), np.int32)
-        bts[0, :5] = [3, 9, 1, 20, 7]
-        bts[1, :12] = np.arange(21, 33)
-        bts[2, :9] = [5, 6, 2, 4, 8, 10, 11, 12, 13]
+        bts = rng.permutation(np.arange(1, P)).reshape(3, MP).astype(np.int32)
         sls = np.asarray(lengths, np.int32)
         got = la.latent_flash_decode(q, pool, 1, bts, sls, rank, 0.2,
                                      interpret=True)
         want = la.latent_attention(q, pool, 1, bts, sls, None, rank, 0.2)
         assert np.array_equal(np.asarray(got, np.float32),
                               np.asarray(want, np.float32))
-        if lengths[1] == 0:
-            assert not np.any(np.asarray(got[1], np.float32))
+        for s in np.flatnonzero(sls == 0):
+            assert not np.any(np.asarray(got[s], np.float32))
+
+    def test_operands_stand_still_past_the_last_live_page(self):
+        """The operand table: a live page where the slot holds one; past
+        the slot's last live page, the page the operand read one grid
+        step before, or in the slot's first group (where it held another
+        slot's page) the slot's last live page."""
+        page, group = 16, 4
+        bts = np.arange(100, 100 + 3 * 10).reshape(3, 10).astype(np.int32)
+        lens = np.asarray([5 * page + 3, 2 * page, 0], np.int32)
+        table = np.asarray(la._operand_pages(jnp.asarray(bts),
+                                             jnp.asarray(lens), page, group))
+        assert table.shape == (3, 12)
+        assert table[0].tolist() == [100, 101, 102, 103, 104, 105, 102,
+                                     103, 104, 105, 102, 103]
+        assert table[1].tolist() == [110, 111, 111, 111] * 3
+        assert table[2].tolist() == [120] * 12
 
     def test_fits_rule(self):
+        """The GLM cell's shapes fit with the group's scores counted;
+        128 heads' scores over a group's 2,048 keys do not."""
         assert la.latent_kernel_fits(128, 20, 576, 512, 2)
+        assert not la.latent_kernel_fits(128, 128, 576, 512, 2)
         assert not la.latent_kernel_fits(8, 20, 576, 512, 2)
         assert la.latent_attention_impl(128, 20, 576, 512,
                                         jnp.bfloat16) == "reference"
