@@ -1742,9 +1742,7 @@ rec["residency"] = {
     "occupancy": sched.occupancy_summary()}
 rec["paged"] = {
     "tokens": n_tok, "wall_s": round(paged_s, 3),
-    "decode_tokens_per_s": round(n_tok / paged_s, 1),
-    "prefill_chunks": int(sched.prefill_chunks),
-    "staging_reuse_bytes": int(sched.staging_reuse_bytes)}
+    "decode_tokens_per_s": round(n_tok / paged_s, 1)}
 sched.close()
 
 # ---- dense twin: same prompts through the dense-slab serial path

@@ -51,6 +51,8 @@ import time
 import jax
 import numpy as np
 
+from deeplearning4j_tpu.runtime import telemetry
+
 __all__ = [
     "ExecutableCache", "CachedJit", "cached_jit", "compile_lowered",
     "enable", "disable", "session_cache", "ambient_fingerprint",
@@ -78,8 +80,6 @@ def _tm():
     top of the per-cache ``stats``/``seconds`` dicts the CLI reports."""
     global _TM
     if _TM is None:
-        from deeplearning4j_tpu.runtime import telemetry
-
         reg = telemetry.get_registry()
         _TM = {
             "reg": reg,
@@ -471,6 +471,10 @@ class CachedJit:
 
     # -- dispatch --------------------------------------------------------
     def _entry_for(self, args, cache):
+        """(table entry, served): the entry is (compiled, key), (None,
+        None) where the call is not cacheable, or (_BAD_ENTRY, None);
+        `served` says the signature was in the table already, so
+        that nothing was compiled or looked up in the cache for it."""
         sig = abstract_signature(args)
         while True:
             with self._lock:
@@ -479,12 +483,12 @@ class CachedJit:
                 if ent is None:
                     fp = self._base_fp_locked()
                     if fp is None:
-                        return None, None
+                        return (None, None), False
                     marker = threading.Event()
                     self._table[sig] = marker   # we own this compile
                     break
                 if not isinstance(ent, threading.Event):
-                    return ent
+                    return ent, True
                 in_flight = ent
             # another thread is compiling THIS signature: wait outside
             # the lock, then re-read (its entry, or ownership if it
@@ -508,7 +512,7 @@ class CachedJit:
             with self._lock:
                 if self._table.get(sig) is marker:
                     self._table[sig] = ent
-            return ent
+            return ent, False
         except BaseException:
             with self._lock:
                 if self._table.get(sig) is marker:
@@ -518,14 +522,24 @@ class CachedJit:
             marker.set()   # wake waiters either way; they re-read
 
     def __call__(self, *args, **kwargs):
+        """Dispatch one call. A call served from the signature table
+        records two spans on the registry's clock, both with ``entry``:
+        ``aot.sign`` (the signature and the table lookup) and then
+        ``aot.call`` (the executable's call, until it returns its
+        futures). A first-seen signature records its ``aot.compile``
+        where it pays one, and neither; with telemetry off no clock is
+        read."""
         cache = self._cache()
         if cache is None or kwargs:
             return self._fallback(*args, **kwargs)
-        ent, _key = self._entry_for(args, cache)
+        reg = telemetry.get_registry() if telemetry.enabled() else None
+        t0 = reg.clock() if reg is not None else None
+        (ent, _key), served = self._entry_for(args, cache)
         if ent is None or ent is _BAD_ENTRY:
             return self._fallback(*args)
+        t1 = reg.clock() if reg is not None else None
         try:
-            return ent(*args)
+            out = ent(*args)
         except TypeError:
             # aval disagreement the signature didn't capture —
             # blacklist the entry so the plain jit owns this call
@@ -533,6 +547,14 @@ class CachedJit:
             with self._lock:
                 self._table[abstract_signature(args)] = (_BAD_ENTRY, None)
             return self._fallback(*args)
+        if reg is not None and served:
+            t2 = reg.clock()
+            parent = reg.current_span_id()
+            reg.add_span("aot.sign", "compile", t0, t1 - t0,
+                         parent=parent, entry=self._entry)
+            reg.add_span("aot.call", "compile", t1, t2 - t1,
+                         parent=parent, entry=self._entry)
+        return out
 
     def warm(self, *args, cache=None):
         """Populate the cache + dispatch table for this signature
@@ -545,7 +567,7 @@ class CachedJit:
         if c is None:
             c = self.pin_cache(enable())._cache()
         before = dict(c.stats)
-        ent, key = self._entry_for(args, c)
+        (ent, key), _ = self._entry_for(args, c)
         if ent is None or ent is _BAD_ENTRY:
             return None, None, 0.0
         status = "cold" if c.stats["misses"] > before["misses"] else "warm"

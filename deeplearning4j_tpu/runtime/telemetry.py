@@ -379,9 +379,12 @@ _SPAN_KEYS = ("id", "parent", "rid", "name", "cat", "ts", "dur", "ph",
               "pid", "tid", "args")
 
 
-#: spans the ring keeps: a busy paged scheduler leaves about 7.5 spans an
-#: iteration, so a 40 s window at 100 iterations a second is 30 000
-TRACE_CAPACITY = 65536
+#: spans the ring keeps: a busy paged scheduler with a decode step queued
+#: ahead leaves about 10.5 spans an iteration (the AOT layer's two of a
+#: dispatch and the fetch's wait included), so a 40 s window at 120
+#: iterations a second is about 51 000, and the ring holds two and a
+#: half times that, about 45 MB at some 350 bytes a span
+TRACE_CAPACITY = 131072
 
 
 class TraceBuffer:

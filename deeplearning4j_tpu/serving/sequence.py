@@ -267,10 +267,7 @@ class _SlotScheduler:
         self._step_lock = threading.Lock()
         self._pending = deque()
         self._active = []                   # the slot table
-        self._staging = {}          # (S, half) -> (reused buffers, bytes)
-        #: host bytes served from the staging pool instead of fresh
-        #: np.zeros (the bench decode leg's alloc-reduction record)
-        self.staging_reuse_bytes = 0
+        self._staging = {}          # (S, half) -> reused buffers
         self._closed = False
         self.name = str(name) if name else f"seq{next(_SCHED_SEQ)}"
         #: (live slots, bucket) per decode dispatch — the occupancy
@@ -391,24 +388,16 @@ class _SlotScheduler:
     def _staging_for(self, S, half=0):
         """Per-bucket staging buffers (``_new_staging`` of the subclass
         shapes them), allocated once and reused: a fresh np.zeros per
-        array per step was pure allocator churn; the bench decode leg
-        counts what the pool saves as staging_reuse_bytes. A dispatch
+        array per step was pure allocator churn. A dispatch
         may read its numpy arguments in place instead of copying them
         (the CPU backend does), so a set is refilled only once the step
         that read it has delivered its outputs: the carry scheduler
         waits for each step before it builds the next, and the paged
         one, which keeps a step queued ahead of the host, alternates
         two sets a bucket (`half` 0 or 1)."""
-        hit = self._staging.get((S, half))
-        if hit is not None:
-            st, nbytes = hit
-            self.staging_reuse_bytes += nbytes
-            return st
-        import jax
-
-        st = self._new_staging(S)
-        self._staging[(S, half)] = (st, sum(
-            a.nbytes for a in jax.tree_util.tree_leaves(st)))
+        st = self._staging.get((S, half))
+        if st is None:
+            st = self._staging[(S, half)] = self._new_staging(S)
         return st
 
     # -- drivers --------------------------------------------------------
@@ -805,17 +794,23 @@ class GenerationRequest(_SlotRequest):
     whole), ``first_token_at``, ``token_times`` (one per sampled token,
     the first included) and ``finished_at`` (done or failed). Time to
     first token is ``first_token_at - enqueued_at``; the gaps between
-    tokens are the differences of ``token_times``."""
+    tokens are the differences of ``token_times``. ``clock`` is the
+    scheduler's, which ``wait`` reads for the waiter's wake-up."""
 
     __slots__ = ("tokens", "max_new", "sampler", "rng", "stream_id",
                  "first_chunk_at", "first_token_at", "token_times",
                  "finished_at", "chunks", "prefilled",
                  "seq_len", "pages", "block_row", "out_tokens",
-                 "device_pick", "want_logits", "_rows", "logits")
+                 "device_pick", "want_logits", "_rows", "logits",
+                 "_clock", "_woken")
 
     def __init__(self, tokens, enqueued_at, deadline=None, max_new=1,
-                 sampler=None, rng=None, stream_id=0, want_logits=True):
+                 sampler=None, rng=None, stream_id=0, want_logits=True,
+                 clock=None):
         super().__init__(enqueued_at, deadline)
+        self._clock = clock if clock is not None \
+            else telemetry.get_registry().clock
+        self._woken = False
         self.tokens = tokens                # [T] int32 prompt
         self.max_new = int(max_new)
         self.want_logits = bool(want_logits)
@@ -855,6 +850,20 @@ class GenerationRequest(_SlotRequest):
         self.logits = self._rows
         self.result = np.asarray(self.out_tokens, np.int64)
         self._event.set()
+
+    def wait(self, timeout=None):
+        """The base's ``wait``. Its first return with a result records
+        ``sequence.wake`` on the waiter's thread: from ``finished_at``,
+        when the scheduler released the waiter, to this return."""
+        out = super().wait(timeout)
+        if self._woken:
+            return out
+        self._woken = True
+        if telemetry.enabled():
+            telemetry.get_registry().add_span(
+                "sequence.wake", "serving", self.finished_at,
+                self._clock() - self.finished_at, rid=self.stream_id)
+        return out
 
 
 class PagedSequenceScheduler(_SlotScheduler):
@@ -913,16 +922,18 @@ class PagedSequenceScheduler(_SlotScheduler):
     docs/OBSERVABILITY.md): every iteration that found work is one
     ``sequence.iteration`` whose children are ``sequence.admit``,
     ``sequence.prefill`` and ``sequence.prefill_finish`` (rid = the
-    request's ``stream_id``), ``sequence.decode_prep`` and
+    request's ``stream_id``; the finish's own children, with the same
+    rid, are ``_prefill_finish``'s parts), ``sequence.decode_prep`` and
     ``sequence.step`` for each decode dispatch, then
-    ``sequence.fetch`` (the wait for the ids, the rows where the step
-    fetches them and the expert counts where the model has experts, of
-    the step the iteration collects, the one dispatched before;
-    ``bytes``),
+    ``sequence.fetch`` (the wait for the ids, its child
+    ``sequence.fetch_wait``, then the rows where the step fetches them
+    and the expert counts where the model has experts, of the step the
+    iteration collects, the one dispatched before; ``bytes``),
     ``sequence.land`` (its ``rows`` written into their requests'
     blocks) and ``sequence.sample`` (tokens appended, requests ended);
     a request that ends, done or failed, leaves one instant
-    ``sequence.request`` with its whole timeline.
+    ``sequence.request`` with its whole timeline, and its waiter a
+    ``sequence.wake`` (``GenerationRequest.wait``).
     ``sequence.prefill`` carries the pass: ``chunk`` prompt tokens in a
     chunk of ``bucket`` tokens (the executable's length).
     ``sequence.step`` carries ``device_picked``, the live slots whose
@@ -968,8 +979,6 @@ class PagedSequenceScheduler(_SlotScheduler):
         impl = getattr(model, "attend_impl", None)
         self._attend = impl() if impl is not None else "reference"
         self._stream_ids = itertools.count(0)
-        #: prefill passes dispatched (the interleave record)
-        self.prefill_chunks = 0
         #: the decode step dispatched ahead and not yet collected
         self._ahead = None
         self._half = 0                      # the staging set last filled
@@ -1021,7 +1030,7 @@ class PagedSequenceScheduler(_SlotScheduler):
                 tokens, now, deadline, max_new=max_new,
                 sampler=sampler if sampler is not None else self.sampler,
                 rng=stream_rng(self.sampler_seed, sid), stream_id=sid,
-                want_logits=logits)
+                want_logits=logits, clock=self.clock)
 
         return self._enqueue(make_req, wait, timeout)
 
@@ -1070,19 +1079,16 @@ class PagedSequenceScheduler(_SlotScheduler):
             error=None if exc is None else type(exc).__name__)
         super()._end_req(req, exc)
 
-    def _complete_prompt(self, req, last_logits):
+    def _first_token(self, req, last_logits):
         """The prompt is fully in KV: sample the first generated token
-        from its final-position logits. Returns True if that already
-        finishes the request (max_new == 1)."""
+        from its final-position logits. Returns True if that is the
+        request's last (max_new == 1)."""
         row = np.asarray(last_logits, np.float32)
         req.put_row(0, row)
         req.out_tokens.append(int(req.sampler(row, req.rng)))
         req.first_token_at = self.clock()
         req.token_times.append(req.first_token_at)
-        if len(req.out_tokens) >= req.max_new:
-            self._finish_req(req)
-            return True
-        return False
+        return len(req.out_tokens) >= req.max_new
 
     def _finish_req(self, req):
         with self._cond:
@@ -1140,21 +1146,36 @@ class PagedSequenceScheduler(_SlotScheduler):
                 pages_table=len(tiles) * self._mp)
         req.prefilled += n_valid
         req.seq_len = req.prefilled
-        self.prefill_chunks += 1
         return logits if req.prefilled >= T else None
 
     def _prefill_finish(self, req, logits, parent=None):
-        """The prompt is whole in KV: fetch its last row (which waits
-        out the pass on the device), register the prompt for prefix
-        sharing and sample the first token."""
-        t0 = self.clock()
+        """The prompt is whole in KV: wait out the pass on the device,
+        fetch its last row, register the prompt for prefix sharing,
+        sample the first token and, where that was the last, end the
+        request. One ``sequence.prefill_finish`` span whose children,
+        in that order, are ``sequence.prefill_wait``,
+        ``sequence.prefill_copy``, ``sequence.prefix_register`` (prefix
+        sharing on), ``sequence.first_token`` and
+        ``sequence.request_end`` (``max_new`` 1)."""
+        reg, rid = self._registry, req.stream_id
+        fid = reg.new_span_id()
+        marks = [("sequence.prefill_wait", self.clock())]
+        logits.block_until_ready()
+        marks.append(("sequence.prefill_copy", self.clock()))
         last = np.asarray(logits)
         if self.prefix_sharing:
+            marks.append(("sequence.prefix_register", self.clock()))
             self.cache.register_prefix(req.tokens, req.pages, last)
-        self._complete_prompt(req, last)
-        self._registry.add_span(
-            "sequence.prefill_finish", "serving", t0, self.clock() - t0,
-            parent=parent, rid=req.stream_id)
+        marks.append(("sequence.first_token", self.clock()))
+        if self._first_token(req, last):
+            marks.append(("sequence.request_end", self.clock()))
+            self._finish_req(req)
+        marks.append((None, self.clock()))
+        for (name, t), (_, t1) in zip(marks, marks[1:]):
+            reg.add_span(name, "serving", t, t1 - t, parent=fid, rid=rid)
+        t0, t_end = marks[0][1], marks[-1][1]
+        reg.add_span("sequence.prefill_finish", "serving", t0, t_end - t0,
+                     parent=parent, rid=rid, span_id=fid)
 
     def _pages_visited(self, lengths):
         """Pages one step's attention reads for live slots of KV
@@ -1309,7 +1330,10 @@ class PagedSequenceScheduler(_SlotScheduler):
         reg = self._registry
         t_f = self.clock()
         try:
-            # the ids wait out the step
+            # the ids wait out the step (``sequence.fetch_wait``); the
+            # copies, queued at the dispatch, are the rest of the fetch
+            step.ids.block_until_ready()
+            t_w = self.clock()
             ids, rows, counts = (None if a is None else np.asarray(a)
                                  for a in (step.ids, step.rows,
                                            step.counts))
@@ -1319,8 +1343,11 @@ class PagedSequenceScheduler(_SlotScheduler):
             return len(step.reqs)
         t_l = self.clock()
         self._record_step(step.span, counts)
+        fid = reg.new_span_id()
+        reg.add_span("sequence.fetch_wait", "serving", t_f, t_w - t_f,
+                     parent=fid)
         reg.add_span("sequence.fetch", "serving", t_f, t_l - t_f,
-                     parent=parent, bytes=sum(
+                     parent=parent, span_id=fid, bytes=sum(
                          a.nbytes for a in (ids, rows, counts)
                          if a is not None))
         live = [(i, req) for i, req in enumerate(step.reqs)
@@ -1401,7 +1428,8 @@ class PagedSequenceScheduler(_SlotScheduler):
             admitted, adopted = self._refill_locked(now)
         progress = 0
         for req, logits in adopted:       # exact-prefix admissions
-            self._complete_prompt(req, logits)
+            if self._first_token(req, logits):
+                self._finish_req(req)
             progress += 1
         with self._cond:
             batch = list(self._active)

@@ -96,6 +96,11 @@ def _prompts(lens, vocab, seed=11):
     return [rng.integers(0, vocab, size=n).tolist() for n in lens]
 
 
+def _passes(ring):
+    """Prefill passes dispatched: one ``sequence.prefill`` span each."""
+    return sum(sp["name"] == "sequence.prefill" for sp in ring.spans())
+
+
 # ----------------------------------------------------------------------
 # bitwise parity vs the serial dense trajectory
 # ----------------------------------------------------------------------
@@ -150,13 +155,15 @@ class TestBitwiseVsSerial:
         m = _lm()
         s, _ = _sched(m)
         p = _prompts((n,), m.vocab, seed=9)[0]
+        ring = telemetry.get_registry().trace
+        ring.clear()
         first = s.submit(p, max_new_tokens=4, wait=False)
         s.drain()
-        chunks_before = s.prefill_chunks
-        assert chunks_before == len(prefill_plan(n, 0, 8, 8)) == first.chunks
+        assert _passes(ring) == len(prefill_plan(n, 0, 8, 8)) == first.chunks
+        ring.clear()
         again = s.submit(p, max_new_tokens=4, wait=False)
         s.drain()
-        assert s.prefill_chunks == chunks_before  # exact adopt: zero
+        assert _passes(ring) == 0           # exact adopt: no pass
         assert again.chunks == 0
         assert again.wait(1.0).tolist() == first.wait(1.0).tolist()
         toks, _ = dense_serial_trajectory(
@@ -257,7 +264,7 @@ class TestPrefillPlan:
                   if sp["name"] == "sequence.prefill"]
         assert [(a["chunk"], a["bucket"]) for a in passes] == \
             [(n_valid, C) for _, n_valid, C in plan]
-        assert req.chunks == s.prefill_chunks == len(plan)
+        assert req.chunks == len(passes) == len(plan)
         toks, logits = dense_serial_trajectory(
             m, p, n_new, greedy_sampler(), stream_rng(0, 0), bucket=4)
         assert got.tolist() == toks
@@ -277,11 +284,12 @@ class TestPrefillPlan:
         s.submit(base, max_new_tokens=1, wait=False)
         s.drain()
         p = base + _prompts((18,), m.vocab, seed=6)[0]
-        before = s.prefill_chunks
+        ring = telemetry.get_registry().trace
+        ring.clear()
         req = s.submit(p, max_new_tokens=3, wait=False)
         s.drain()
         assert prefill_plan(38, 16, PAGE, MP) == [(16, 22, 24)]
-        assert req.chunks == s.prefill_chunks - before == 1
+        assert req.chunks == _passes(ring) == 1
         toks, logits = dense_serial_trajectory(
             m, p, 3, greedy_sampler(), stream_rng(0, 1), bucket=4)
         assert req.wait(1.0).tolist() == toks
@@ -721,14 +729,21 @@ class TestScheduling:
 
     def test_staging_buffers_reused_across_iterations(self):
         """Decode staging (tokens/lens/block tables) is allocated once
-        per bucket and reused every iteration — the alloc-churn
-        counter the bench decode leg records."""
+        per bucket and half and reused every iteration: a second
+        request's steps fill the same objects as the first's."""
         m = _lm()
         s, _ = _sched(m)
         s.submit(_prompts((4,), m.vocab)[0], max_new_tokens=8,
                  wait=False)
         s.drain()
-        assert s.staging_reuse_bytes > 0
+        first = dict(s._staging)
+        assert len(first) == 2              # both halves of the bucket
+        s.submit(_prompts((6,), m.vocab, seed=1)[0], max_new_tokens=8,
+                 wait=False)
+        s.drain()
+        assert s._staging.keys() == first.keys()
+        assert all(s._staging[k] is first[k] for k in first)
+        assert all(s._staging_for(*k) is first[k] for k in first)
         s.close()
 
 

@@ -1,13 +1,16 @@
 """The per-layer readers that ISSUE 26 adds under perfbench/metrics/ (and
 ISSUE 27's `paged_attend.pages_visited_share`, ISSUE 31's
 `seq.prefill_tokens_per_pass_mean` and its decode-cell twin, ISSUE 35's
-`seq.device_sampled_share`, and `seq.dispatch_ahead_share`), fed
+`seq.device_sampled_share`, `seq.dispatch_ahead_share`, and the five
+that split the host's time around a prompt's first token), fed
 hand-made spans: each returns the number worked out by hand below, None
 where the ring dropped spans (a truncated window gives no number) and
 None, without raising, where the program left nothing to read (the
 parent commit's case). Tier-1 does not collect perfbench/tests/, so the
 readers of the program's spans are checked here, beside the spans.
 """
+
+import threading
 
 import pytest
 
@@ -41,7 +44,17 @@ EXPECTED = {
     "seq.prefill_tokens_per_pass_mean.decode": 200.0,
     "seq.device_sampled_share": 100.0 * 43 / 46,
     "seq.dispatch_ahead_share": 100.0 * 2 / 3,
+    "aot.prefill_sign_ms_p50": 0.75,
+    "aot.prefill_call_ms_p50": 1.5,
+    "seq.first_token_wait_ms_p50": 3.0,
+    "seq.first_token_host_ms_p50": 2.0,
+    "seq.wake_ms_p50": 0.5,
 }
+#: what the parent commit's program leaves of a prompt's passes: the
+#: passes and their finish, none of the spans inside them
+FIRST_TOKEN = ("aot.prefill_sign_ms_p50", "aot.prefill_call_ms_p50",
+               "seq.first_token_wait_ms_p50", "seq.first_token_host_ms_p50",
+               "seq.wake_ms_p50")
 SETUP = ("setup.weights_init_s", "setup.warm_s")
 
 
@@ -128,6 +141,7 @@ def _fill(reg):
     _request(reg, 3, 9.0, 9.5, [10.5, 30.0])
     _request(reg, 4, 13.0, 13.5, [14.0, 20.0], error="ServingClosedError")
     _request(reg, 5, 49.0, 49.5, [49.9, 51.0])
+    _first_token_spans(reg)
     # two steps: dispatch 2 and 4 ms (mean 3), sync 98 and 96 (mean 97),
     # outside the step 1 + 0.5 + 0.3 and 3 + 0.5 + 0.3 ms (mean 2.8)
     for t, prep, disp in ((20.0, 1 * ms, 2 * ms), (20.2, 3 * ms, 4 * ms)):
@@ -141,6 +155,43 @@ def _fill(reg):
                      span_id=step)
         reg.add_span("train.listeners", "train", t + prep + 100 * ms,
                      0.5 * ms)
+
+
+def _first_token_spans(reg):
+    """Around the window's two prefill passes (10.001 for 30 ms and 10.6
+    for 4 ms): signs of 1 and 0.5 ms (median 0.75) and calls of 2 and
+    1 ms (median 1.5) inside them; a sign inside no pass, one in the
+    first pass's time on another thread and one in the pass before the
+    window count nowhere. Two finishes of 4 and 6 ms whose waits are 1
+    and 5 ms (median 3) leave 3 and 1 ms to the host (median 2); a
+    finish without a wait (the parent commit's) and one before the
+    window count for neither. Wake-ups of requests 1 and 2, 0.4 and
+    0.6 ms (median 0.5); those of 3 (enqueued before the window) and 4
+    (failed) do not count."""
+    ms = 1e-3
+    for ts, sign, call in ((10.002, 1 * ms, 2 * ms),
+                           (10.6005, 0.5 * ms, 1 * ms),
+                           (5.1, 5 * ms, 5 * ms)):
+        reg.add_span("aot.sign", "compile", ts, sign, entry="paged_prefill")
+        reg.add_span("aot.call", "compile", ts + sign, call,
+                     entry="paged_prefill")
+    reg.add_span("aot.sign", "compile", 10.2, 9 * ms, entry="paged_decode")
+    other = threading.Thread(target=reg.add_span, args=(
+        "aot.sign", "compile", 10.010, 7 * ms))
+    other.start()
+    other.join()
+    for ts, dur, wait in ((10.035, 4 * ms, 1 * ms),
+                          (10.61, 6 * ms, 5 * ms),
+                          (5.5, 0.1, 0.05)):
+        fid = reg.new_span_id()
+        reg.add_span("sequence.prefill_wait", "serving", ts, wait,
+                     parent=fid)
+        reg.add_span("sequence.prefill_finish", "serving", ts, dur,
+                     span_id=fid)
+    reg.add_span("sequence.prefill_finish", "serving", 10.7, 10 * ms)
+    for rid, dur in ((1, 0.4 * ms), (2, 0.6 * ms), (3, 50 * ms),
+                     (4, 50 * ms)):
+        reg.add_span("sequence.wake", "serving", 11.0 + rid, dur, rid=rid)
 
 
 @pytest.fixture
@@ -192,6 +243,21 @@ def test_step_reader_is_none_where_a_step_lacks_the_args(name, filled):
 def test_tokens_per_pass_is_none_where_a_pass_lacks_its_chunk(name, filled):
     filled.add_span("sequence.prefill", "serving", 10.7, 0.004)
     assert _read(name) is None
+
+
+@pytest.mark.parametrize("name", FIRST_TOKEN)
+def test_first_token_reader_is_none_on_the_parents_spans(name):
+    """The parent commit leaves passes, finishes and requests but no
+    span inside them and no wake-up: no number, and no error."""
+    reg = telemetry.get_registry()
+    reg.trace.clear()
+    reg.add_span("sequence.prefill", "serving", 10.0, 0.03, chunk=300)
+    reg.add_span("sequence.prefill_finish", "serving", 10.03, 0.004)
+    _request(reg, 1, 11.0, 11.010, [11.100])
+    try:
+        assert _read(name) is None
+    finally:
+        reg.trace.clear()
 
 
 def test_idle_with_work_is_none_where_the_top_ten_hide_the_waiting(filled):
@@ -246,6 +312,7 @@ def test_manifest_lists_the_new_metrics_with_their_readers():
              "seq.ttft_inside_p50_ms": P, "seq.ttft_inside_p95_ms": P,
              "prefill.idle_with_work_share": P,
              "seq.prefill_tokens_per_pass_mean": P, "fit.dispatch_ms_mean": R,
+             **{name: P for name in FIRST_TOKEN},
              "fit.sync_wait_ms_mean": R, "fit.outside_step_ms_mean": R}
     # the readers of any paged LM list the GLM-4.7-Flash cell too
     glm = {"seq.iteration_ms_p50", "seq.dispatch_ahead_share"}

@@ -327,6 +327,8 @@ def test_counts_agree_with_the_occupancy_record(kind):
     summary = s.occupancy_summary()
     assert summary["dispatches"] == len(s.occupancy)
     assert 0 < summary["mean_occupancy"] <= 1
-    assert s.staging_reuse_bytes > 0    # one staging entry a bucket
+    # one staging set a bucket (and half), the same object each time
+    assert s._staging and all(s._staging_for(*k) is st
+                              for k, st in list(s._staging.items()))
     assert kind.whole(s)
     s.close()

@@ -9,6 +9,10 @@ What must hold:
   tree of docs/OBSERVABILITY.md, and for every request that ends, done
   or failed, one instant ``sequence.request`` with its timeline; the
   request's timestamps are set with telemetry off too;
+- a prompt's last pass splits its ``sequence.prefill_finish`` into its
+  parts, a decode collect its ``sequence.fetch`` into the wait and the
+  copies, a waiter records its wake-up, and a ``CachedJit`` call served
+  from its table its signature and its executable's call;
 - an idle scheduler loop is ONE ``sequence.idle`` per idle period;
 - both schedulers default to the registry's clock;
 - ``fit(iterator)`` records one of each trainer span a step, children
@@ -23,13 +27,14 @@ import os
 import threading
 import time
 
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from deeplearning4j_tpu.nn.transformer import (PREFILL_CHUNK_PAGES,
                                                CausalTransformerLM,
                                                prefill_plan)
-from deeplearning4j_tpu.runtime import telemetry
+from deeplearning4j_tpu.runtime import aot, telemetry
 from deeplearning4j_tpu.runtime.telemetry import MetricsRegistry
 from deeplearning4j_tpu.serving import (
     DeadlineExceededError, KVCacheFullError, ManualClock, ModelHost,
@@ -165,7 +170,7 @@ class TestSpanRecord:
             ["s6", "s7", "s8", "s9"]
         reg.trace.clear()
         assert reg.trace.dropped == 0 and reg.trace.spans() == []
-        assert MetricsRegistry().trace.capacity == 65536
+        assert MetricsRegistry().trace.capacity == 131072
 
     def test_chrome_trace_carries_the_links(self):
         reg = MetricsRegistry()
@@ -277,9 +282,11 @@ class TestPagedSchedulerSpans:
         # one rid on every span that belongs to the request
         mine = [sp for sp in spans if sp["rid"] is not None]
         assert {sp["rid"] for sp in mine} == {req.stream_id}
-        assert sorted(sp["name"] for sp in mine) == \
-            ["sequence.prefill"] * 2 + ["sequence.prefill_finish",
-                                        "sequence.request"]
+        assert sorted(sp["name"] for sp in mine) == sorted(
+            ["sequence.prefill"] * 2 + [
+                "sequence.prefill_finish", "sequence.prefill_wait",
+                "sequence.prefill_copy", "sequence.first_token",
+                "sequence.request", "sequence.wake"])
         s.close()
 
     def test_request_timeline(self, ring):
@@ -401,6 +408,177 @@ class TestPagedSchedulerSpans:
 
 
 # ----------------------------------------------------------------------
+# the host's time around a prompt's first token, and a decode's fetch
+# ----------------------------------------------------------------------
+FINISH_PARTS = ["sequence.prefill_wait", "sequence.prefill_copy",
+                "sequence.prefix_register", "sequence.first_token",
+                "sequence.request_end"]
+
+
+class TestFirstTokenSpans:
+    @pytest.mark.parametrize("sharing,max_new,n", [
+        (True, 1, 20), (False, 1, 20), (True, 3, 20), (False, 3, 44)])
+    def test_last_pass_finish_has_its_parts_in_order(self, ring, sharing,
+                                                      max_new, n):
+        """Only a prompt's last pass (one of one at 20 tokens, the second
+        of two at 44) has a finish: its children follow one another from
+        its start to its end, under its id and with the request's rid;
+        the registry's part only where prefix sharing is on, the
+        request's end only where the first token is the last."""
+        s = _paged(_lm(), prefix_sharing=sharing)
+        req = s.submit(_prompt(n), max_new_tokens=max_new, wait=False)
+        s.drain()
+        spans = ring.spans()
+        by = _by_name(spans)
+        assert len(by["sequence.prefill"]) == req.chunks == \
+            len(prefill_plan(n, 0, 8, s._mp))
+        (fin,) = by["sequence.prefill_finish"]
+        (it,) = [i for i in by["sequence.iteration"]
+                 if i["id"] == fin["parent"]]
+        assert _inside(fin, it) and fin["rid"] == req.stream_id
+        kids = sorted((sp for sp in spans if sp["parent"] == fin["id"]),
+                      key=lambda sp: sp["ts"])
+        assert [k["name"] for k in kids] == [
+            n for n in FINISH_PARTS
+            if (sharing or n != "sequence.prefix_register")
+            and (max_new == 1 or n != "sequence.request_end")]
+        assert all(k["rid"] == req.stream_id and k["args"] == {}
+                   for k in kids)
+        assert kids[0]["ts"] == fin["ts"]
+        for a, b in zip(kids, kids[1:]):
+            assert a["ts"] + a["dur"] == pytest.approx(b["ts"], abs=1e-12)
+        assert kids[-1]["ts"] + kids[-1]["dur"] == pytest.approx(
+            fin["ts"] + fin["dur"], abs=1e-12)
+        first = next(k for k in kids if k["name"] == "sequence.first_token")
+        assert first["ts"] < req.first_token_at < first["ts"] + first["dur"]
+        if max_new == 1:
+            assert kids[-1]["ts"] < req.finished_at \
+                < kids[-1]["ts"] + kids[-1]["dur"]
+        s.close()
+
+    def test_wake_is_recorded_on_the_waiters_thread(self, ring):
+        """From ``finished_at`` to the wait's return, on the waiter's
+        thread, with the request's rid; a second wait records none."""
+        s = _paged(_lm(), clock=telemetry.get_registry().clock)
+        req = s.submit(_prompt(12), max_new_tokens=2, wait=False)
+        seen = {}
+
+        def waiter():
+            seen["tid"] = threading.get_ident()
+            seen["out"] = req.wait(5.0)
+            seen["at"] = telemetry.get_registry().clock()
+
+        t = threading.Thread(target=waiter)
+        t.start()
+        s.drain()
+        t.join(5.0)
+        assert seen["out"].shape == (2,)
+        req.wait(1.0)
+        (wake,) = _by_name(ring.spans())["sequence.wake"]
+        assert wake["tid"] == seen["tid"] != threading.get_ident()
+        assert (wake["rid"], wake["parent"]) == (req.stream_id, None)
+        assert wake["ts"] == req.finished_at
+        assert 0 <= wake["dur"] <= seen["at"] - req.finished_at
+        s.close()
+
+    def test_no_wake_for_a_failed_request_or_with_telemetry_off(self, ring):
+        clk = ManualClock()
+        s = _paged(_lm(), clock=clk)
+        late = s.submit(_prompt(4), max_new_tokens=2, deadline=1.0,
+                        wait=False)
+        clk.advance(2.0)
+        s.poll()
+        with pytest.raises(DeadlineExceededError):
+            late.wait(0.1)
+        req = s.submit(_prompt(4), max_new_tokens=2, wait=False)
+        s.drain()
+        telemetry.set_enabled(False)
+        try:
+            req.wait(1.0)
+        finally:
+            telemetry.set_enabled(True)
+        req.wait(1.0)                   # not the first return: nothing
+        assert "sequence.wake" not in _by_name(ring.spans())
+        s.close()
+
+    def test_fetch_wait_opens_each_fetch(self, ring):
+        """Each decode collect's wait for the ids is the first part of
+        its ``sequence.fetch``; the copies are the rest."""
+        s = _paged(_lm())
+        s.submit(_prompt(44), max_new_tokens=4, wait=False)
+        s.drain()
+        by = _by_name(ring.spans())
+        fetches, waits = by["sequence.fetch"], by["sequence.fetch_wait"]
+        assert len(fetches) == 3
+        assert [w["parent"] for w in waits] == [f["id"] for f in fetches]
+        for w, f in zip(waits, fetches):
+            assert w["ts"] == f["ts"] and w["dur"] < f["dur"]
+            assert _inside(w, f) and w["rid"] is None and w["args"] == {}
+        s.close()
+
+
+class TestDispatchSpans:
+    @pytest.fixture
+    def double(self):
+        f = aot.cached_jit(lambda x: x * 2.0, entry="double",
+                           fingerprint="span-tree-double")
+        return f.pin_cache(aot.ExecutableCache()), jnp.ones((4,), jnp.float32)
+
+    def test_one_sign_and_one_call_per_table_served_call(self, ring,
+                                                         double):
+        f, x = double
+        f(x)                                # first seen: a compile
+        by = _by_name(ring.spans())
+        assert len(by["aot.compile"]) == 1
+        assert "aot.sign" not in by and "aot.call" not in by
+        ring.clear()
+        f(x)
+        f(x)
+        by = _by_name(ring.spans())
+        assert "aot.compile" not in by
+        signs, calls = by["aot.sign"], by["aot.call"]
+        assert len(signs) == len(calls) == 2
+        for sg, cl in zip(signs, calls):
+            assert sg["args"] == cl["args"] == {"entry": "double"}
+            assert sg["cat"] == cl["cat"] == "compile"
+            assert sg["tid"] == cl["tid"] == threading.get_ident()
+            assert sg["parent"] is None and cl["parent"] is None
+            assert cl["ts"] == pytest.approx(sg["ts"] + sg["dur"],
+                                             abs=1e-12)
+
+    def test_a_call_inside_a_span_block_is_its_child(self, ring, double):
+        f, x = double
+        f(x)
+        reg = telemetry.get_registry()
+        ring.clear()
+        with reg.span("outer"):
+            f(x)
+        by = _by_name(ring.spans())
+        (outer,) = by["outer"]
+        for name in ("aot.sign", "aot.call"):
+            (sp,) = by[name]
+            assert sp["parent"] == outer["id"] and _inside(sp, outer)
+
+    def test_telemetry_off_records_nothing_and_reads_no_clock(
+            self, ring, double, monkeypatch):
+        f, x = double
+        f(x)
+        reg = telemetry.get_registry()
+        reads = []
+        real = reg.clock
+        monkeypatch.setattr(reg, "clock",
+                            lambda: reads.append(1) or real())
+        ring.clear()
+        telemetry.set_enabled(False)
+        try:
+            out = f(x)
+        finally:
+            telemetry.set_enabled(True)
+        assert np.asarray(out).tolist() == [2.0] * 4
+        assert reads == [] and ring.spans() == []
+
+
+# ----------------------------------------------------------------------
 # the program's spans, read by the yardstick's readers
 # ----------------------------------------------------------------------
 #: every reader under perfbench/metrics/ that takes a span, an event or a
@@ -411,7 +589,7 @@ SCHEDULER_READERS = sorted(
         os.path.dirname(__file__), os.pardir, "perfbench", "metrics",
         "*.py"))
     if os.path.basename(p).startswith(("seq.", "kv.pages_in_use_max",
-                                       "paged_attend.")))
+                                       "paged_attend.", "aot.prefill_")))
 
 
 class TestReadersTakeTheProgramsSpans:
@@ -424,9 +602,13 @@ class TestReadersTakeTheProgramsSpans:
     def run(self):
         from test_perfbench_readers import StubRun
 
-        trace = telemetry.get_registry().trace
-        trace.clear()
-        s = _paged(_lm(), name="join")
+        reg = telemetry.get_registry()
+        reg.trace.clear()
+        # on the registry's clock, which the AOT layer's spans read, so
+        # that a pass holds its executable's call; the suite's session
+        # cache serves a pass whose chunk length it has seen
+        s = _paged(_lm(), name="join", clock=reg.clock)
+        t0 = s.clock()
         # two prompts of two passes each, five tokens each: a pass that
         # is not the last, decode steps with one and two live slots
         assert [len(prefill_plan(n, 0, 8, s._mp)) for n in (44, 30)] == \
@@ -434,10 +616,10 @@ class TestReadersTakeTheProgramsSpans:
         reqs = [s.submit(_prompt(n, seed=n), max_new_tokens=5, wait=False)
                 for n in (44, 30)]
         s.drain()
-        assert all(r.error is None for r in reqs)
+        assert all(r.wait(1.0).shape == (5,) for r in reqs)
 
         class Run(StubRun):
-            window = {"t0": 0.0, "t1": s.clock()}
+            window = {"t0": t0, "t1": s.clock()}
             # as perfbench/kinds/serve_generate.py fills it, before the
             # scheduler closes and takes its series away
             counters = {"queue_wait_p50_s": telemetry.get_registry().get(
@@ -446,10 +628,10 @@ class TestReadersTakeTheProgramsSpans:
 
         yield Run()
         s.close()
-        trace.clear()
+        reg.trace.clear()
 
     def test_there_is_a_reader_for_each_layer_metric(self):
-        assert len(SCHEDULER_READERS) == 19
+        assert len(SCHEDULER_READERS) == 24
 
     @pytest.mark.parametrize("name", SCHEDULER_READERS)
     def test_reader_gives_a_finite_number(self, name, run):
